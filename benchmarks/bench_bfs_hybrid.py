@@ -10,6 +10,7 @@ from repro.core.bfs import bfs, bfs_reference
 from repro.graphs import make_suite
 
 import numpy as np
+from repro.caches import use_compile_cache
 
 
 def bench(scale: float = 0.15, runs: int = 3, quiet: bool = False):
@@ -42,6 +43,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.15)
     args = ap.parse_args()
+    use_compile_cache()
     print("graph,topdown_ms,bottomup_ms,hybrid_ms,hybrid_vs_best_pure,trace")
     bench(args.scale)
 

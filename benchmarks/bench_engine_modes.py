@@ -15,11 +15,12 @@ path is tracked from PR 1 onward.
   PYTHONPATH=src python -m benchmarks.bench_engine_modes --scale 0.05
 
 ``--dist`` times the sharded Pipe (core.distributed.color_distributed)
-across shard counts on simulated host-platform devices and writes
-``BENCH_dist.json`` with the per-shard-count scaling. When the current
-process has too few devices it re-execs itself with
-``--xla_force_host_platform_device_count`` (XLA fixes the device count at
-import, so the flag can't be applied in-process).
+across shard counts and writes ``BENCH_dist.json`` with the
+per-shard-count scaling. Under ``JAX_PLATFORMS=cpu`` it re-execs itself
+with ``--xla_force_host_platform_device_count`` when the environment
+forces too few host devices (XLA fixes the count when the backend starts,
+so the flag can't be applied in-process); on an accelerator it runs in
+this process and stops when there are too few devices.
 
   PYTHONPATH=src python -m benchmarks.bench_engine_modes --dist --shards 1,2,8
 
@@ -41,7 +42,7 @@ the inverse permutation before checking).
 
   PYTHONPATH=src python -m benchmarks.bench_engine_modes --layouts
 
-``--kernels`` runs the one-launch kernel leg (DESIGN.md §10) and writes
+``--kernels`` runs the fused-kernel leg (DESIGN.md §10) and writes
 ``BENCH_kernels.json``: per layout kind the launches/iteration counters,
 engine seconds + n_colors, the autotuner's chosen tile config, and the
 fused+compact vs separate-compact speedup (geomean is the acceptance
@@ -73,12 +74,14 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import subprocess
 import sys
 
 import numpy as np
 
 from benchmarks.common import csv_row, geomean
+from repro.caches import use_compile_cache
 from repro.core import color, color_outlined_hybrid, verify_coloring
 from repro.graphs import make_suite
 
@@ -773,7 +776,7 @@ def bench_stream(count: int = 20, max_nodes: int = 4_000, lanes: int = 4,
 def bench_kernels(scale: float = 0.02, rows: int = 2048, runs: int = 5,
                   quiet: bool = False,
                   out_path: str | None = "BENCH_kernels.json") -> dict:
-    """One-launch kernel leg (DESIGN.md §10) -> ``BENCH_kernels.json``.
+    """Fused-kernel leg (DESIGN.md §10) -> ``BENCH_kernels.json``.
 
     Per layout kind: launches/iteration from the trace-time counters
     (fused vs two-phase), end-to-end engine seconds + n_colors on a
@@ -797,8 +800,9 @@ def bench_kernels(scale: float = 0.02, rows: int = 2048, runs: int = 5,
     from repro.kernels.fused_compact import fused_compact_pallas
     from repro.kernels.fused_step import fused_step_pallas
     from repro.graphs import make_graph
+    from repro.kernels.ops import interpret as interpret_mode
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     window = 128
 
     def synth_case(hub: bool):
@@ -1039,28 +1043,43 @@ def bench_obs(scale: float = 0.02, runs: int = 5, quiet: bool = False,
     return report
 
 
-def _reexec_with_devices(argv: list[str], n_devices: int) -> int:
-    """Re-exec this module with forced host-platform devices (XLA binds the
-    device count at first import, so it cannot be changed in-process).
+def _forced_cpu_devices() -> int:
+    """Host-platform devices ``XLA_FLAGS`` forces (1 when it forces none)."""
+    m = re.search(r"--xla_force_host_platform_device_count=(\d+)",
+                  os.environ.get("XLA_FLAGS", ""))
+    return int(m.group(1)) if m else 1
 
-    One hop only: if the marker env var is already set, the forced flag did
-    not raise the device count (e.g. a non-CPU default backend with fewer
-    devices) — fail with bench_dist's clear assertion instead of looping.
+
+def _needs_reexec(n_devices: int) -> bool:
+    """Decided from the environment alone, before anything touches JAX.
+
+    Forced host devices exist only on the CPU platform, and XLA binds
+    their count when the backend starts, so under ``JAX_PLATFORMS=cpu``
+    a run that needs more of them re-execs itself with the flag raised.
+    On an accelerator the run stays in this process: a child would find
+    the chip held by its parent.
     """
-    if os.environ.get("_BENCH_DIST_REEXEC") == "1":
-        raise SystemExit(
-            f"re-exec with --xla_force_host_platform_device_count="
-            f"{n_devices} did not yield enough devices (non-CPU backend?); "
-            f"run on a host with >= {n_devices} devices or pass a smaller "
-            f"--shards list")
+    return (os.environ.get("JAX_PLATFORMS") == "cpu"
+            and _forced_cpu_devices() < n_devices)
+
+
+def _reexec_with_devices(argv: list[str], n_devices: int) -> int:
     env = dict(os.environ)
-    env["_BENCH_DIST_REEXEC"] = "1"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={n_devices}"
                         ).strip()
     return subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_engine_modes", *argv],
         env=env).returncode
+
+
+def _require_devices(n_devices: int) -> None:
+    import jax
+    if jax.device_count() < n_devices:
+        raise SystemExit(
+            f"needs {n_devices} devices, found {jax.device_count()} on "
+            f"{jax.default_backend()}; run under JAX_PLATFORMS=cpu (forced "
+            f"host devices) or pass a smaller --shards list")
 
 
 def main() -> None:
@@ -1087,7 +1106,7 @@ def main() -> None:
                          "-> BENCH_serve.json")
     ap.add_argument("--serve-out", default="BENCH_serve.json")
     ap.add_argument("--kernels", action="store_true",
-                    help="one-launch fused+compact kernel leg "
+                    help="fused+compact kernel leg "
                          "-> BENCH_kernels.json")
     ap.add_argument("--kernels-out", default="BENCH_kernels.json")
     ap.add_argument("--stream", action="store_true",
@@ -1106,6 +1125,7 @@ def main() -> None:
                          "algos matrix when combined with --algos)")
     args = ap.parse_args()
     shards = tuple(int(s) for s in args.shards.split(","))
+    use_compile_cache()
 
     if args.obs:
         o_scale, o_runs = (0.01, 3) if args.smoke else (args.scale,
@@ -1142,35 +1162,37 @@ def main() -> None:
         bench_layouts(scale=l_scale, runs=l_runs, out_path=args.layouts_out)
         return
     if args.algos:
-        import jax
         a_scale, a_runs = (0.01, 1) if args.smoke else (args.scale,
                                                         args.runs)
-        if jax.device_count() < args.algos_shards:
+        if _needs_reexec(args.algos_shards):
             sys.exit(_reexec_with_devices(
                 ["--algos", "--scale", str(a_scale), "--runs", str(a_runs),
                  "--algos-shards", str(args.algos_shards),
                  "--algos-out", args.algos_out], args.algos_shards))
+        _require_devices(args.algos_shards)
         print(csv_row("graph", "algo", "host", "outlined", "dist-hybrid"))
         bench_algos(shards=args.algos_shards, scale=a_scale, runs=a_runs,
                     out_path=args.algos_out)
         return
     if args.smoke:
-        import jax
+        reexec = _needs_reexec(max(shards))
         bench(scale=0.01, runs=1, out_path=None)
-        if jax.device_count() < max(shards):
+        if reexec:
+            # only under JAX_PLATFORMS=cpu, where no chip is held
             sys.exit(_reexec_with_devices(
                 ["--dist", "--shards", args.shards, "--scale", "0.01",
                  "--runs", "1", "--dist-out", args.dist_out],
                 max(shards)))
+        _require_devices(max(shards))
         bench_dist(shards, scale=0.01, runs=1, out_path=args.dist_out)
         return
     if args.dist:
-        import jax
-        if jax.device_count() < max(shards):
+        if _needs_reexec(max(shards)):
             sys.exit(_reexec_with_devices(
                 ["--dist", "--shards", args.shards, "--scale",
                  str(args.scale), "--runs", str(args.runs),
                  "--dist-out", args.dist_out], max(shards)))
+        _require_devices(max(shards))
         print(csv_row("graph", "host_loop",
                       *(f"shards_{s}" for s in shards)))
         bench_dist(shards, scale=args.scale, runs=args.runs,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.core.worklist import (Worklist, bucket_capacities, compact_items,
                                  compact_mask, full_worklist, pick_bucket)
+from repro.caches import use_compile_cache
 
 
 def bench(n: int = 1 << 20, count: int = 4096, runs: int = 3,
@@ -110,6 +111,7 @@ def main():
     ap.add_argument("--count", type=int, default=4096)
     ap.add_argument("--runs", type=int, default=3)
     args = ap.parse_args()
+    use_compile_cache()
     bench(args.n, args.count, args.runs)
 
 

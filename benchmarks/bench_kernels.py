@@ -15,6 +15,7 @@ from benchmarks.common import csv_row, time_fn
 import jax
 
 from repro.kernels import ref
+from repro.caches import use_compile_cache
 
 
 def bench(r: int = 65536, k: int = 32, w: int = 128, quiet=False):
@@ -50,6 +51,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=65536)
     args = ap.parse_args()
+    use_compile_cache()
     print("kernel,us_per_call,derived")
     bench(args.rows)
 

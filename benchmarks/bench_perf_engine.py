@@ -18,6 +18,7 @@ import argparse
 from benchmarks.common import csv_row, geomean
 from repro.core import color, ipgc, verify_coloring
 from repro.graphs import make_suite
+from repro.caches import use_compile_cache
 
 
 def _time(g, runs=3, **kw):
@@ -71,6 +72,7 @@ def main():
     ap.add_argument("--scale", type=float, default=0.15)
     ap.add_argument("--runs", type=int, default=3)
     args = ap.parse_args()
+    use_compile_cache()
     bench(args.scale, args.runs)
 
 

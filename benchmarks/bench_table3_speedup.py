@@ -12,6 +12,7 @@ import argparse
 from benchmarks.common import csv_row, geomean
 from repro.core import color, jpl_color, vb_color, verify_coloring
 from repro.graphs import make_suite
+from repro.caches import use_compile_cache
 
 
 def bench(scale: float = 0.1, runs: int = 3, names=None, quiet=False):
@@ -59,6 +60,7 @@ def main():
     ap.add_argument("--scale", type=float, default=0.1)
     ap.add_argument("--runs", type=int, default=3)
     args = ap.parse_args()
+    use_compile_cache()
     print("graph,plain_ms,topology_ms,hybrid_ms,vb_ms,jpl_ms,speedup")
     bench(args.scale, args.runs)
 

@@ -13,6 +13,7 @@ import numpy as np
 from benchmarks.common import csv_row
 from repro.core import color, jpl_color
 from repro.graphs import make_graph, SUITE_SPECS
+from repro.caches import use_compile_cache
 
 
 def bench(scale: float = 0.1, seeds=(0, 1, 2), quiet=False):
@@ -34,6 +35,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.1)
     args = ap.parse_args()
+    use_compile_cache()
     print("graph,hybrid_colors,jpl_cusparse_colors,ratio")
     bench(args.scale)
 

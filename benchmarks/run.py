@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import argparse
 
+from repro.caches import use_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -14,6 +16,7 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="smaller graphs / fewer repeats")
     args = ap.parse_args()
+    use_compile_cache()
     scale = 0.03 if args.fast else args.scale
 
     print("name,us_per_call,derived")

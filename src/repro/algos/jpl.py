@@ -205,7 +205,6 @@ def make_jpl_dist_steps(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
     ``jpl_dense_step``/``jpl_sparse_step`` on the partitioned graph."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.distributed import (_exchange_colors, _publish_packed,
@@ -315,9 +314,9 @@ def make_jpl_dist_steps(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
 
     def _wrap(local_fn, in_specs, sparse: bool):
         def run(colors, rnd, wl: Worklist, *, bcap: int):
-            fn = shard_map(partial(local_fn, bcap=bcap), mesh=mesh,
-                           in_specs=in_specs, out_specs=out,
-                           check_rep=False)
+            fn = jax.shard_map(partial(local_fn, bcap=bcap), mesh=mesh,
+                               in_specs=in_specs, out_specs=out,
+                               check_vma=False)
             args = (colors, rnd, wl.mask) + ((wl.items,) if sparse else ())
             outs = fn(*args, isb if bnd else jnp.zeros((n,), bool),
                       ig_local.ell_idx, ig_local.hub_slot,

@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import ipgc
 from repro.core.engine import ColoringResult
@@ -302,12 +301,12 @@ def make_dist_dense_step(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
         return colors_out, new_base, still, items_l.astype(jnp.int32), count
 
     na = node_axes
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P(na), P(na), P(na, None), P(na), P(na),
                   P(), P(), P(), P(), P(), P()),
         out_specs=(P(), P(na), P(na), P(na), P()),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def step(colors, base, wl: Worklist):
@@ -411,9 +410,9 @@ def _make_dense_boundary_step(ig_local: ipgc.IPGCGraph, mesh,
 
     @partial(jax.jit, static_argnames=("bcap",))
     def step(views, base, wl: Worklist, *, bcap: int):
-        fn = shard_map(partial(local_step, bcap=bcap), mesh=mesh,
-                       in_specs=in_specs, out_specs=out_specs,
-                       check_rep=False)
+        fn = jax.shard_map(partial(local_step, bcap=bcap), mesh=mesh,
+                           in_specs=in_specs, out_specs=out_specs,
+                           check_vma=False)
         views2, base2, mask, items, count, xstats = fn(
             views, base, wl.mask, isb, ig_local.ell_idx, ig_local.degrees,
             ig_local.hub_slot, ig_local.priority, ig_local.tail_src,
@@ -537,12 +536,12 @@ def make_dist_sparse_step(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
         return colors_out, base2, mask2, new_items, count
 
     na = node_axes
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P(na), P(na), P(na), P(na, None), P(na), P(na),
                   P(), P(), P(), P(), P(), P()),
         out_specs=(P(), P(na), P(na), P(na), P()),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def step(colors, base, wl: Worklist):
@@ -655,9 +654,9 @@ def _make_sparse_boundary_step(ig_local: ipgc.IPGCGraph, mesh,
 
     @partial(jax.jit, static_argnames=("bcap",))
     def step(views, base, wl: Worklist, *, bcap: int):
-        fn = shard_map(partial(local_step, bcap=bcap), mesh=mesh,
-                       in_specs=in_specs, out_specs=out_specs,
-                       check_rep=False)
+        fn = jax.shard_map(partial(local_step, bcap=bcap), mesh=mesh,
+                           in_specs=in_specs, out_specs=out_specs,
+                           check_vma=False)
         views2, base2, mask, items, count, xstats = fn(
             views, base, wl.mask, wl.items, isb, ig_local.ell_idx,
             ig_local.degrees, ig_local.hub_slot, ig_local.priority,
@@ -680,9 +679,9 @@ def make_dist_resize(mesh, node_axes: tuple, n_global: int):
 
     @partial(jax.jit, static_argnames=("capacity",))
     def resize(wl: Worklist, capacity: int) -> Worklist:
-        fn = shard_map(lambda il: resize_block(il, capacity, n_global),
-                       mesh=mesh, in_specs=P(na), out_specs=P(na),
-                       check_rep=False)
+        fn = jax.shard_map(lambda il: resize_block(il, capacity, n_global),
+                           mesh=mesh, in_specs=P(na), out_specs=P(na),
+                           check_vma=False)
         return Worklist(mask=wl.mask, items=fn(wl.items), count=wl.count)
 
     return resize

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.graphs.csr import Graph, NO_COLOR, PAD_COLOR
 from repro.core.worklist import Worklist, compact_items, compact_mask
+from repro.kernels import csr_segment as kcsr
 from repro.obs.metrics import default_registry
 
 
@@ -71,8 +72,19 @@ class IPGCGraph:
     # layout dispatch (static) + csr-segment edge arrays (None elsewhere)
     layout_kind: str = dataclasses.field(default="ell-tail",
                                          metadata=dict(static=True))
-    edge_src: jax.Array | None = None   # i32[Ep] clipped, pad lanes -> 0
-    edge_dst: jax.Array | None = None   # i32[Ep], pad = N (sentinel slot)
+    edge_src: jax.Array | None = None   # i32[Ep] ascending, pad -> N-1
+    # i32[Ep] destination (pad = N, the sentinel slot), with the sign bit
+    # (csr_segment.WINS_BIT) set where it wins the (priority, id)
+    # tie-break against the source — static, so no step gathers priorities
+    edge_dst: jax.Array | None = None
+    # segment index of the tail (hub h owns tail entries
+    # [tail_start[h], tail_start[h+1])); None = derive from tail_slot
+    tail_start: jax.Array | None = None  # i32[n_hub+1]
+    # static bound for the packed sparse passes: seg_bound[k] is the most
+    # entries (csr edges / tail entries) any 2^k rows own; () = no bound,
+    # sparse steps sweep every entry
+    seg_bound: tuple = dataclasses.field(default=(),
+                                         metadata=dict(static=True))
 
 
 def prepare(g: Graph, *, priority: str = "hash", plan=None) -> IPGCGraph:
@@ -101,14 +113,22 @@ def prepare(g: Graph, *, priority: str = "hash", plan=None) -> IPGCGraph:
     pr = np.asarray(a.priority) if priority == "hash" else np.arange(n, dtype=np.int32)
     prio = np.concatenate([pr, np.full(1, -1, np.int32)])
     edge_src = edge_dst = None
+    real = tail_src[tail_valid]                     # sorted by source row
+    tail_start = np.searchsorted(hub_slot[real],
+                                 np.arange(n_hub + 1)).astype(np.int32)
     if kind == "csr-segment":
         e = int(np.asarray(a.row_ptr)[-1])
         ep = max(-(-max(e, 1) // 8) * 8, 8)
-        es = np.zeros(ep, dtype=np.int32)           # pad lanes inert (ec<0)
-        ed = np.full(ep, n, dtype=np.int32)
+        es = np.full(ep, max(n - 1, 0), dtype=np.int32)  # pad lanes inert
+        ed = np.full(ep, n, dtype=np.int32)              # (ec < 0)
         es[:e] = np.repeat(np.arange(n, dtype=np.int32), deg)
         ed[:e] = np.asarray(a.col_idx)
+        wins = (prio[ed] > prio[es]) | ((prio[ed] == prio[es]) & (ed > es))
+        ed[wins] |= np.int32(kcsr.WINS_BIT)
         edge_src, edge_dst = jnp.asarray(es), jnp.asarray(ed)
+        owned = deg
+    else:
+        owned = np.bincount(real, minlength=n)
     return IPGCGraph(
         n_nodes=n,
         ell_width=a.ell_width,
@@ -125,7 +145,19 @@ def prepare(g: Graph, *, priority: str = "hash", plan=None) -> IPGCGraph:
         layout_kind=kind,
         edge_src=edge_src,
         edge_dst=edge_dst,
+        tail_start=jnp.asarray(tail_start),
+        seg_bound=_top_sums(owned),
     )
+
+
+def _top_sums(owned: np.ndarray) -> tuple:
+    """``out[k]`` = the most entries any ``2^k`` rows own, for
+    ``2^k < 2 * len(owned)`` (the packed sparse passes' static bound)."""
+    top = np.cumsum(np.sort(np.asarray(owned, np.int64))[::-1])
+    if top.size == 0:
+        return ()
+    ks = range(max(top.size - 1, 0).bit_length() + 1)
+    return tuple(int(top[min(2 ** k, top.size) - 1]) for k in ks)
 
 
 def pad_prepared(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
@@ -134,7 +166,7 @@ def pad_prepared(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
     batch-execution contract (DESIGN.md §9).
 
     Every step impl in this module is *batch-axis safe*: it is built from
-    shape-static jnp ops (gather / scatter-with-drop / ``nonzero(size=)``)
+    shape-static jnp ops (gather / scatter-with-drop / doubling loops)
     with no host-side data-dependent control flow, so ``jax.vmap`` over a
     lane-stacked ``IPGCGraph`` + state reproduces the unbatched step
     bit-exactly per lane. ``pad_prepared`` makes lanes stackable: padding
@@ -246,11 +278,11 @@ GATHER_COUNTS = default_registry().group("ipgc.gathers",
 # logical device pass a step emits bumps one bucket, so "one iteration is
 # one kernel launch" (DESIGN.md §10) is asserted in tests, not eyeballed.
 #   mex/conflict/compact — the three separate passes of a two-phase step
-#   fused               — a one-launch fused step (assign + resolve +
-#                         worklist emission folded into a single pass:
-#                         the fused+compact Pallas kernel on the ELL
-#                         paths, the one-sweep segment core on
-#                         csr-segment)
+#   fused               — a fused step: assign + resolve in one pass
+#                         over the neighbour tile (the fused_compact
+#                         Pallas kernel on the ELL paths, followed by
+#                         XLA's compaction of its survivor mask; the
+#                         one-sweep segment core on csr-segment)
 # Inspect by tracing the raw ``*_impl`` functions with ``jax.eval_shape``
 # under ``LAUNCH_COUNTS.scope()`` (see ``core/policy.measure_launches``).
 # Both groups are reset-scoped ``CounterGroup``s registered in the obs
@@ -285,43 +317,47 @@ def init_colors(n_nodes: int) -> jax.Array:
 # forbidden-window helpers
 # ---------------------------------------------------------------------------
 
-def _scatter_forbidden(rel: jax.Array, ok: jax.Array, n_rows: int,
-                       window: int) -> jax.Array:
-    """OR-scatter row-relative colors into a (n_rows, window) bitmap."""
-    if n_rows * window < 2 ** 31 - 1:
-        rows = jnp.arange(n_rows, dtype=jnp.int32)[:, None]
-        flat = jnp.where(ok, rows * window + rel, n_rows * window)
-        forb = jnp.zeros((n_rows * window + 1,), bool)
-        forb = forb.at[flat.reshape(-1)].set(True, mode="drop")
-        return forb[:-1].reshape(n_rows, window)
-    # huge-graph path (>2^31 cells): 2-D scatter, no flat index
-    rows = jnp.broadcast_to(
-        jnp.arange(n_rows, dtype=jnp.int32)[:, None], rel.shape)
-    rows = jnp.where(ok, rows, n_rows)
-    rel_c = jnp.clip(rel, 0, window - 1)
-    forb = jnp.zeros((n_rows + 1, window), bool)
-    forb = forb.at[rows, rel_c].set(True, mode="drop")
-    return forb[:n_rows]
-
-
 def _ell_forbidden(nc: jax.Array, base_rows: jax.Array, window: int) -> jax.Array:
-    rel = nc - base_rows[:, None]
-    ok = (nc >= 0) & (rel >= 0) & (rel < window)
-    return _scatter_forbidden(rel, ok, nc.shape[0], window)
+    """(R, W) forbidden bitmap of an ELL tile: each lane's window bit,
+    OR-reduced across the row (no scatter: a bool scatter of R*K updates
+    takes the TPU compiler ~20 s at a million rows)."""
+    words = kcsr.window_words(nc, base_rows[:, None], window)
+    return kcsr.words_bitmap(
+        tuple(jax.lax.reduce(w, jnp.uint32(0), jax.lax.bitwise_or, (1,))
+              for w in words), window)
 
 
 def _hub_forbidden(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
                    window: int) -> jax.Array:
     """(n_hub+1, W) forbidden bitmap from COO-tail edges; row n_hub is a
-    guaranteed-False row that non-hub nodes gather."""
+    guaranteed-False row that non-hub nodes gather. One sorted-segment
+    sweep over the tail (kernels/csr_segment.py)."""
     nh = ig.n_hub
-    tc = colors[ig.tail_dst]               # PAD_COLOR for padded entries
-    rel = tc - base[ig.tail_src]
-    ok = ig.tail_valid & (tc >= 0) & (rel >= 0) & (rel < window)
-    flat = jnp.where(ok, ig.tail_slot * window + rel, (nh + 1) * window)
-    forb = jnp.zeros(((nh + 1) * window + 1,), bool)
-    forb = forb.at[flat].set(True, mode="drop")
-    return forb[:-1].reshape(nh + 1, window)
+    if nh == 0:
+        return jnp.zeros((1, window), bool)
+    start, nonempty = _tail_segments(ig)
+    tc = jnp.where(ig.tail_valid, colors[ig.tail_dst], PAD_COLOR)
+    base_e = kcsr.spread(base[ig.hub_ids[:nh]], start, tc.shape[0])
+    words = kcsr.segment_or(kcsr.window_words(tc, base_e, window),
+                            ig.tail_slot, _max_run(ig))
+    forb = kcsr.words_bitmap(
+        tuple(kcsr.read_first(w, start, nonempty) for w in words), window)
+    return jnp.concatenate([forb, jnp.zeros((1, window), bool)])
+
+
+def _max_run(ig: IPGCGraph) -> int:
+    """Most entries one row owns (0: unknown, see ``segment_or``)."""
+    return ig.seg_bound[0] if ig.seg_bound else 0
+
+
+def _tail_segments(ig: IPGCGraph) -> tuple[jax.Array, jax.Array]:
+    """(first tail entry, owns-an-entry) per hub slot ``0..n_hub-1``."""
+    ts = ig.tail_start
+    if ts is None:
+        ts = jnp.searchsorted(ig.tail_slot,
+                              jnp.arange(ig.n_hub + 1, dtype=jnp.int32))
+        ts = ts.astype(jnp.int32)
+    return ts[:-1], ts[1:] > ts[:-1]
 
 
 def _mex_from_forbidden(forb: jax.Array, active: jax.Array,
@@ -391,16 +427,23 @@ def _lose_rows(ig: IPGCGraph, ell_rows: jax.Array, row_ids: jax.Array,
 
 
 def _hub_lose(ig: IPGCGraph, colors: jax.Array, newly_full: jax.Array) -> jax.Array:
-    """(n_hub+1,) conflict flags for hub rows from COO-tail edges."""
+    """(n_hub+1,) conflict flags for hub rows from COO-tail edges (only
+    rows flagged in ``newly_full`` can lose)."""
     nh = ig.n_hub
-    cu = colors[ig.tail_src]
-    cv = colors[ig.tail_dst]
-    pu = ig.priority[ig.tail_src]
-    pv = ig.priority[ig.tail_dst]
-    lose = (ig.tail_valid & (cu >= 0) & (cu == cv) & newly_full[ig.tail_src]
-            & ((pv > pu) | ((pv == pu) & (ig.tail_dst > ig.tail_src))))
-    out = jnp.zeros((nh + 1,), bool)
-    return out.at[jnp.where(lose, ig.tail_slot, nh)].max(lose)
+    if nh == 0:
+        return jnp.zeros((1,), bool)
+    start, nonempty = _tail_segments(ig)
+    t = ig.tail_dst.shape[0]
+    hubs = ig.hub_ids[:nh]
+    cu = jnp.where(newly_full[hubs], colors[hubs], NO_COLOR)
+    cv = jnp.where(ig.tail_valid, colors[ig.tail_dst], PAD_COLOR)
+    lose = kcsr.lose_flags(kcsr.spread(cu, start, t), cv,
+                           kcsr.spread(ig.priority[hubs], start, t),
+                           ig.priority[ig.tail_dst], ig.tail_src,
+                           ig.tail_dst)
+    (lose,) = kcsr.segment_or((lose,), ig.tail_slot, _max_run(ig))
+    out = kcsr.read_first(lose, start, nonempty)
+    return jnp.concatenate([out, jnp.zeros((1,), bool)])
 
 
 # ---------------------------------------------------------------------------
@@ -408,33 +451,42 @@ def _hub_lose(ig: IPGCGraph, colors: jax.Array, newly_full: jax.Array) -> jax.Ar
 # ---------------------------------------------------------------------------
 # Active when the graph was prepared under a ``csr-segment`` LayoutPlan
 # (DESIGN.md §8): no ELL tiles are gathered; both phases run one
-# O(E)-scatter / segment-reduce pass over (edge_src, edge_dst) via
+# sorted-segment reduction over (edge_src, edge_dst) via
 # ``kernels/csr_segment.py``. The hub side-channel is unnecessary — the
 # edge set already covers every entry. The mex/conflict semantics are the
 # exact predicates of the ELL path evaluated over the same neighbour
 # sets, so csr-segment colorings are bit-identical to ell-tail ones.
 #
-# Phase split: compute is row-complete (the forbidden bitmap and conflict
-# flags cover all N rows — segment ops have no worklist-shaped form), so
-# dense and sparse variants share the core and differ only in how the
-# worklist is re-emitted: the dense form re-compacts from the mask, the
-# data-driven form filters its items block in O(C).
+# Phase split: the dense form sweeps every edge (row-complete flags) and
+# re-compacts from the mask; the data-driven form packs the edges of its
+# worklist rows (``_packed_csr_step``) when the graph's ``seg_bound``
+# allows, else sweeps every edge and filters its items block in O(C).
+
+def _row_start(degrees: jax.Array) -> jax.Array:
+    """First CSR edge of every row (exclusive prefix sum of degrees)."""
+    return kcsr.prefix_sum(degrees) - degrees
+
 
 def _csr_two_phase_core(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
                         active: jax.Array, *, window: int):
-    from repro.kernels import csr_segment as kcsr
     n = ig.n_nodes
-    es, ed = ig.edge_src, ig.edge_dst
-    # --- assign (speculative windowed mex over the edge scatter) ---
+    es = ig.edge_src
+    ed, wins = kcsr.split_key(ig.edge_dst)
+    deg = ig.degrees
+    start = _row_start(deg)
+    base_e = kcsr.spread(base, start, es.shape[0])
+    # --- assign (speculative windowed mex over the edge segments) ---
     ec = _gather_neighbor_colors(colors, ed)             # E-shaped gather 1
-    forb = kcsr.edge_forbidden(es, ec, base[es], n, window)
+    forb = kcsr.edge_forbidden(es, ec, base_e, start, deg, window,
+                               _max_run(ig))
     new_c, new_base, newly = _mex_from_forbidden(
         forb, active, base, colors[:n], window)
     colors2 = colors.at[:n].set(new_c)
-    # --- resolve (segment-any of the losing-edge predicate) ---
+    # --- resolve (a newly colored row loses to a winning neighbour) ---
     cv = _gather_neighbor_colors(colors2, ed)            # E-shaped gather 2
-    lose = kcsr.edge_conflict(es, ed, colors2[es], cv, ig.priority[es],
-                              ig.priority[ed], n) & newly
+    lose = kcsr.edge_conflict(es, cv, wins, base_e,
+                              jnp.where(newly, new_c, NO_COLOR), base,
+                              start, deg, window, _max_run(ig))
     colors3 = colors2.at[:n].set(jnp.where(lose, NO_COLOR, colors2[:n]))
     still = lose | (active & ~newly)
     return colors3, new_base, still
@@ -442,15 +494,18 @@ def _csr_two_phase_core(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
 
 def _csr_fused_core(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
                     active: jax.Array, *, window: int):
-    from repro.kernels import csr_segment as kcsr
     n = ig.n_nodes
-    es, ed = ig.edge_src, ig.edge_dst
+    es = ig.edge_src
+    ed, wins = kcsr.split_key(ig.edge_dst)
+    deg = ig.degrees
+    start = _row_start(deg)
     cu = colors[:n]
     pending = active & (cu >= 0)
     ec = _gather_neighbor_colors(colors, ed)             # the ONE gather
-    lose, forb = kcsr.edge_fused(es, ed, cu[es], ec, ig.priority[es],
-                                 ig.priority[ed], base[es], n, window)
-    lose = lose & pending
+    lose, forb = kcsr.edge_fused(
+        es, ec, wins, kcsr.spread(base, start, es.shape[0]),
+        jnp.where(pending, cu, NO_COLOR), base, start, deg, window,
+        _max_run(ig))
     free = ~forb
     has = free.any(axis=1)
     first = jnp.argmax(free, axis=1).astype(jnp.int32)
@@ -481,20 +536,158 @@ def _csr_emit_sparse(wl: Worklist, still: jax.Array, n: int) -> Worklist:
 def _csr_step(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
               wl: Worklist, *, window: int, fused: bool, sparse: bool
               ) -> tuple[jax.Array, jax.Array, Worklist]:
-    core = _csr_fused_core if fused else _csr_two_phase_core
     if fused:
         # ONE edge-parallel pass: conflict + forbidden come out of a
         # single sweep over the shared edge gather (kcsr.edge_fused) and
         # the O(C)/O(N) worklist emission fuses into its epilogue — the
-        # csr analogue of the one-launch fused+compact kernel.
+        # csr analogue of the fused_compact kernel.
         LAUNCH_COUNTS["fused"] += 1
     else:
         LAUNCH_COUNTS["mex"] += 1
         LAUNCH_COUNTS["conflict"] += 1
         LAUNCH_COUNTS["compact"] += 1
+    if sparse:
+        pk = _packed(ig, wl.items, ig.edge_dst,
+                     lambda rows: _row_start(ig.degrees)[rows],
+                     lambda rows: ig.degrees[rows], keyed=True)
+        if pk is not None:
+            return _packed_csr_step(ig, colors, base, wl, pk, window=window,
+                                    fused=fused)
+    core = _csr_fused_core if fused else _csr_two_phase_core
     colors2, base2, still = core(ig, colors, base, wl.mask, window=window)
     emit = _csr_emit_sparse if sparse else _csr_emit_dense
     return colors2, base2, emit(wl, still, ig.n_nodes)
+
+
+# ---------------------------------------------------------------------------
+# packed sparse passes — only the entries of the worklist's rows
+# ---------------------------------------------------------------------------
+# A data-driven step at capacity C needs the edge entries (csr-segment) or
+# tail entries (hub side-channel) of its C rows only. ``_packed`` lays them
+# out contiguously in a static buffer sized by the graph's ``seg_bound``
+# (the most entries any C rows own); when that bound is over half of all
+# entries the step sweeps them all instead. Per row the result is the
+# same segment reduction over the same entries, so the two forms are
+# bit-identical — only the amount of gathered data differs.
+
+@dataclasses.dataclass(frozen=True)
+class _Packed:
+    rows: jax.Array       # i32[C] row of each owner (0 for pad owners)
+    off: jax.Array        # i32[C] first slot of each owner
+    nonempty: jax.Array   # bool[C] owner has an entry
+    dst: jax.Array        # i32[cap] entry destination (N = no entry)
+    wins: jax.Array       # bool[cap] destination wins the tie-break
+    src: jax.Array        # i32[cap] owner row of each slot (run id)
+    max_run: int          # most entries one owner has
+
+    def spread(self, v: jax.Array) -> jax.Array:
+        return kcsr.spread(v, self.off, self.dst.shape[0])
+
+    def rows_words(self, words: tuple) -> tuple:
+        return kcsr.row_windows(words, self.src, self.off, self.nonempty,
+                                self.max_run)
+
+
+def _packed(ig: IPGCGraph, items: jax.Array, entry_dst: jax.Array,
+            starts_of, counts_of, *, keyed: bool) -> "_Packed | None":
+    """Pack the entries of the rows in ``items`` (``None``: no static
+    bound small enough — sweep every entry). ``keyed`` entries carry the
+    tie-break in their sign bit (csr-segment); otherwise it is computed
+    from the priorities."""
+    c = items.shape[0]
+    if not ig.seg_bound:
+        return None
+    k = min(max(c - 1, 0).bit_length(), len(ig.seg_bound) - 1)
+    cap = max(-(-ig.seg_bound[k] // 1024) * 1024, 1024)
+    m = entry_dst.shape[0]
+    if 2 * cap > m:
+        return None
+    n = ig.n_nodes
+    valid = items < n
+    rows = jnp.where(valid, items, 0)
+    counts = jnp.where(valid, counts_of(rows), 0)
+    pos, off, live = kcsr.packed_ranges(starts_of(rows), counts, cap)
+    src = kcsr.spread(rows, off, cap)
+    dst = entry_dst[jnp.clip(pos, 0, m - 1)]
+    if keyed:
+        dst, wins = kcsr.split_key(dst)
+    else:
+        wins = kcsr.wins(kcsr.spread(ig.priority[rows], off, cap),
+                         ig.priority[dst], src, dst)
+    return _Packed(rows=rows, off=off, nonempty=counts > 0,
+                   dst=jnp.where(live, dst, n), wins=wins, src=src,
+                   max_run=_max_run(ig))
+
+
+def _packed_forbidden(pk: _Packed, ec: jax.Array, base_rows: jax.Array,
+                      window: int) -> jax.Array:
+    """(C, W) forbidden bitmap of the packed rows."""
+    words = kcsr.window_words(ec, pk.spread(base_rows), window)
+    return kcsr.words_bitmap(pk.rows_words(words), window)
+
+
+def _packed_lose(pk: _Packed, cv: jax.Array, c_rows: jax.Array,
+                 base_rows: jax.Array, window: int) -> jax.Array:
+    """(C,) conflict flags of the packed rows whose color is ``c_rows``
+    (negative for rows that cannot lose; see ``kcsr.edge_conflict``)."""
+    win_c = jnp.where(pk.wins, cv, -1)
+    words = pk.rows_words(kcsr.window_words(win_c, pk.spread(base_rows),
+                                            window))
+    return (c_rows >= 0) & kcsr.window_bit(words, c_rows - base_rows)
+
+
+def _hub_packed(ig: IPGCGraph, items: jax.Array) -> "_Packed | None":
+    """Pack the tail entries of the hub rows in ``items``."""
+    ts, nh = ig.tail_start, ig.n_hub
+    if ts is None:
+        return None
+
+    def slot(rows):
+        return jnp.minimum(ig.hub_slot[rows], nh)
+
+    return _packed(ig, items, ig.tail_dst, lambda rows: ts[slot(rows)],
+                   lambda rows: (ts[jnp.minimum(slot(rows) + 1, nh)]
+                                 - ts[slot(rows)]), keyed=False)
+
+
+def _packed_csr_step(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
+                     wl: Worklist, pk: _Packed, *, window: int, fused: bool
+                     ) -> tuple[jax.Array, jax.Array, Worklist]:
+    """The csr-segment sparse step over the packed edges of its rows."""
+    n = ig.n_nodes
+    items = wl.items
+    valid = items < n
+    ids = jnp.where(valid, items, n)
+    base_rows = base[pk.rows]
+    cu = jnp.where(valid, colors[pk.rows], PAD_COLOR)
+    ec = _gather_neighbor_colors(colors, pk.dst)
+    if fused:
+        pending = valid & (cu >= 0)
+        lose = _packed_lose(pk, ec, jnp.where(pending, cu, NO_COLOR),
+                            base_rows, window)
+        free = ~_packed_forbidden(pk, ec, base_rows, window)
+        has = free.any(axis=1)
+        first = jnp.argmax(free, axis=1).astype(jnp.int32)
+        still = lose | (valid & (cu < 0))
+        new_c = jnp.where(still & has, base_rows + first,
+                          jnp.where(lose, NO_COLOR, cu))
+        new_base = jnp.where(still & ~has, base_rows + window, base_rows)
+    else:
+        forb = _packed_forbidden(pk, ec, base_rows, window)
+        new_c, new_base, newly = _mex_from_forbidden(forb, valid, base_rows,
+                                                     cu, window)
+        colors2 = colors.at[ids].set(jnp.where(valid, new_c, PAD_COLOR))
+        cv = _gather_neighbor_colors(colors2, pk.dst)
+        lose = _packed_lose(pk, cv, jnp.where(newly, new_c, NO_COLOR),
+                            base_rows, window)
+        new_c = jnp.where(lose, NO_COLOR, new_c)
+        still = lose | (valid & ~newly)
+    colors2 = colors.at[ids].set(jnp.where(valid, new_c, PAD_COLOR))
+    colors2 = colors2.at[n].set(PAD_COLOR)
+    base2 = base.at[ids].set(new_base, mode="drop")
+    new_items, count = compact_items(items, still, n)
+    mask = wl.mask.at[ids].set(still, mode="drop")
+    return colors2, base2, Worklist(mask=mask, items=new_items, count=count)
 
 
 # ---------------------------------------------------------------------------
@@ -565,7 +758,10 @@ def sparse_step_impl(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
     ell_rows = jnp.where(valid[:, None], ig.ell_idx[safe], n)    # (C, K)
     nc = _gather_neighbor_colors(colors, ell_rows)
     base_rows = base[safe]
-    if has_hubs:
+    pk = _hub_packed(ig, items) if has_hubs else None
+    if pk is not None:
+        extra = _packed_forbidden(pk, colors[pk.dst], base_rows, window)
+    elif has_hubs:
         hub_forb = _hub_forbidden(ig, colors, base, window)
         extra = hub_forb[jnp.minimum(ig.hub_slot[safe], ig.n_hub)]
     else:
@@ -584,7 +780,11 @@ def sparse_step_impl(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
     # --- resolve ---
     lose = _lose_rows(ig, ell_rows, jnp.where(valid, items, n), colors2,
                       newly, impl, tile_rows)
-    if has_hubs:
+    if pk is not None:
+        lose = lose | (_packed_lose(pk, colors2[pk.dst],
+                                    jnp.where(newly, new_c, NO_COLOR),
+                                    base_rows, window) & valid)
+    elif has_hubs:
         newly_full = jnp.zeros((n + 1,), bool).at[
             jnp.where(newly, items, n)].set(newly, mode="drop")[: n + 1]
         hub_l = _hub_lose(ig, colors2, newly_full)
@@ -667,11 +867,11 @@ def _fused_compact_rows(ig: IPGCGraph, nc: jax.Array, npr: jax.Array,
                         tile_rows: int | None, capacity: int
                         ) -> tuple[jax.Array, jax.Array, jax.Array,
                                    jax.Array, jax.Array]:
-    """ONE-launch row-wise core (DESIGN.md §10): resolve + windowed mex +
-    new-color/base selection + compacted worklist emission in a single
-    pass. ``ids`` is the emitted value, so the dense caller passes row
-    iota (emission == ``compact_mask``) and the sparse caller its items
-    block (emission == ``compact_items``). Returns
+    """ONE-pass row-wise core (DESIGN.md §10): resolve + windowed mex +
+    new-color/base selection in a single kernel, then the compacted
+    worklist emission. ``ids`` is the emitted value, so the dense caller
+    passes row iota (emission == ``compact_mask``) and the sparse caller
+    its items block (emission == ``compact_items``). Returns
     ``(new_colors, new_base, still, items, count)``.
     """
     LAUNCH_COUNTS["fused"] += 1
@@ -695,12 +895,10 @@ def _fused_compact_rows(ig: IPGCGraph, nc: jax.Array, npr: jax.Array,
     new_c = jnp.where(need & has, base_rows + first,
                       jnp.where(lose, NO_COLOR, cu))
     new_base = jnp.where(need & ~has, base_rows + window, base_rows)
-    # folded emission — bit-identical to compact_mask/compact_items over
+    # emission — bit-identical to compact_mask/compact_items over
     # ``need``: surviving ids ascending, sentinel-n tail, count = popcount
-    (pos,) = jnp.nonzero(need, size=capacity, fill_value=nc.shape[0])
-    ids_ext = jnp.concatenate(
-        [ids.astype(jnp.int32), jnp.full((1,), n, jnp.int32)])
-    return new_c, new_base, need, ids_ext[pos], need.sum(dtype=jnp.int32)
+    items, count = compact_items(ids.astype(jnp.int32), need, n, capacity)
+    return new_c, new_base, need, items, count
 
 
 def fused_dense_step_impl(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
@@ -731,8 +929,8 @@ def fused_dense_step_impl(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
         extra = None
         hub_lose = None
 
-    # ONE launch: resolve + assign + worklist emission (emitted value =
-    # row iota, so the compacted items == compact_mask of ``still``)
+    # ONE kernel pass: resolve + assign, then worklist emission (emitted
+    # value = row iota, so the compacted items == compact_mask of ``still``)
     new_c, new_base, still, items, count = _fused_compact_rows(
         ig, nc, npr, ig.ell_idx, base, cu, pu, row_ids, active, pending,
         extra, hub_lose, window, impl, tile_rows, wl.items.shape[0])
@@ -763,7 +961,13 @@ def fused_sparse_step_impl(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
     base_rows = base[safe]
     pending = valid & (cu >= 0)
 
-    if has_hubs:
+    pk = _hub_packed(ig, items) if has_hubs else None
+    if pk is not None:
+        tc = colors[pk.dst]
+        extra = _packed_forbidden(pk, tc, base_rows, window)
+        hub_lose = _packed_lose(pk, tc, jnp.where(pending, cu, NO_COLOR),
+                                base_rows, window) & valid
+    elif has_hubs:
         hub_slot = jnp.minimum(ig.hub_slot[safe], ig.n_hub)
         extra = _hub_forbidden(ig, colors, base, window)[hub_slot]
         pending_full = jnp.zeros((n + 1,), bool).at[
@@ -773,7 +977,7 @@ def fused_sparse_step_impl(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
         extra = None
         hub_lose = None
 
-    # ONE launch: emitted value = the items block (invalid rows carry the
+    # ONE kernel pass: emitted value = the items block (invalid rows carry the
     # sentinel n and are inactive), so the compacted items ==
     # compact_items of ``still`` over the old block
     new_c, new_base_rows, still, new_items, count = _fused_compact_rows(

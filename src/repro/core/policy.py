@@ -391,10 +391,11 @@ def measure_launches(step_impl, ig, colors, aux, wl, **step_kw) -> dict:
     and return the ``ipgc.LAUNCH_COUNTS`` delta it produced.
 
     The dict maps pass kind -> launches per iteration (``fused`` /
-    ``mex`` / ``conflict`` / ``compact``); a one-launch fused iteration
-    is ``{"fused": 1}`` with every other bucket 0, which is how the
-    engine's "one iteration = one kernel launch" claim is asserted in
-    tests and reported by ``bench_engine_modes --kernels``.
+    ``mex`` / ``conflict`` / ``compact``); a fused iteration is
+    ``{"fused": 1}`` with every other bucket 0 — one pass over the
+    neighbour tile, whose worklist compaction counts with it — which is
+    how the engine's launch contract is asserted in tests and reported
+    by ``bench_engine_modes --kernels``.
 
     The measurement runs inside ``LAUNCH_COUNTS.scope()`` (obs/
     metrics.py): the group is zeroed for the trace and the caller's

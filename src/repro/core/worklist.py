@@ -20,6 +20,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.csr_segment import prefix_sum
+
 
 class Worklist(NamedTuple):
     mask: jax.Array    # bool[N]
@@ -68,17 +70,30 @@ def compact_mask(mask: jax.Array, capacity: int, n_nodes: int) -> tuple[jax.Arra
     the device. jnp reference implementation; ``kernels/compact.py`` is the
     Pallas version with a sequential-grid carry.
     """
-    (idx,) = jnp.nonzero(mask, size=capacity, fill_value=n_nodes)
-    return idx.astype(jnp.int32), mask.sum(dtype=jnp.int32)
+    ids = jnp.arange(mask.shape[0], dtype=jnp.int32)
+    return _compact(ids, mask, capacity, n_nodes), mask.sum(dtype=jnp.int32)
 
 
-def compact_items(items: jax.Array, keep: jax.Array, n_nodes: int) -> tuple[jax.Array, jax.Array]:
+def compact_items(items: jax.Array, keep: jax.Array, n_nodes: int,
+                  capacity: int | None = None) -> tuple[jax.Array, jax.Array]:
     """Filter the existing worklist in O(C) — the data-driven phase never
-    touches O(N) state to rebuild its own worklist."""
-    c = items.shape[0]
-    (pos,) = jnp.nonzero(keep, size=c, fill_value=c)
-    items_ext = jnp.concatenate([items, jnp.full((1,), n_nodes, items.dtype)])
-    return items_ext[pos], keep.sum(dtype=jnp.int32)
+    touches O(N) state to rebuild its own worklist. The result holds
+    ``capacity`` slots (default: as many as ``items``)."""
+    cap = items.shape[0] if capacity is None else capacity
+    return (_compact(items.astype(jnp.int32), keep, cap, n_nodes),
+            keep.sum(dtype=jnp.int32))
+
+
+def _compact(values: jax.Array, keep: jax.Array, capacity: int,
+             fill: int) -> jax.Array:
+    """The kept ``values`` in order, in ``capacity`` slots padded with
+    ``fill`` (kept values past the capacity are dropped) — what
+    ``jnp.nonzero(size=)`` gives, built from a doubling prefix sum and
+    one scatter: XLA's ``cumsum``, which ``nonzero`` lowers to, takes
+    about 10 s to compile for a TPU at a million rows."""
+    slot = prefix_sum(keep.astype(jnp.int32)) - 1
+    out = jnp.full((capacity,), fill, jnp.int32)
+    return out.at[jnp.where(keep, slot, capacity)].set(values, mode="drop")
 
 
 def bucket_capacities(n_nodes: int, *, ratio: int = 4, floor: int = 1024) -> list[int]:

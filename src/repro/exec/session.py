@@ -40,6 +40,7 @@ import dataclasses
 import math
 import threading
 import time
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -135,6 +136,13 @@ def _graph_key(g) -> tuple:
     if isinstance(g, Graph):
         return ("graph", id(g), g.name, g.n_nodes, g.n_edges)
     return ("ig", id(g), g.n_nodes, g.ell_width, g.n_hub, g.layout_kind)
+
+
+def _csr_checksum(g: Graph) -> int:
+    """CRC32 of the graph's CSR arrays (content identity)."""
+    a = g.arrays
+    crc = zlib.crc32(np.ascontiguousarray(a.row_ptr).data)
+    return zlib.crc32(np.ascontiguousarray(a.col_idx).data, crc)
 
 
 class Session:
@@ -659,11 +667,13 @@ class Session:
         # auto-built meshes over the same device set are interchangeable;
         # a caller-provided mesh is cached by identity (steps close over
         # it). The algorithm and plan join as frozen instances. Unlike
-        # the prep entries, the graph joins by CONTENT (name + static
-        # sizes) — the legacy steps_cache contract: a caller that
-        # rebuilds an equal Graph per request must still reuse the
-        # partitioned graph and jitted shard_map steps.
-        key = ("dist", g.name, g.n_nodes, g.n_edges, n_shards, node_axes,
+        # the prep entries, the graph joins by CONTENT (name, sizes and a
+        # checksum of its CSR) — the legacy steps_cache contract: a caller
+        # that rebuilds an equal Graph per request must still reuse the
+        # partitioned graph and jitted shard_map steps, and a relabeled
+        # graph of the same name and sizes must not.
+        key = ("dist", g.name, g.n_nodes, g.n_edges, _csr_checksum(g),
+               n_shards, node_axes,
                spec.window, spec.priority, fused, spec.balance, alg, plan,
                spec.tile_rows, spec.exchange,
                id(mesh) if custom_mesh else None)

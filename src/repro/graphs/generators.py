@@ -59,14 +59,21 @@ def edges_rmat(scale: int, edge_factor: int, seed: int,
     e = n * edge_factor
     src = np.zeros(e, dtype=np.int64)
     dst = np.zeros(e, dtype=np.int64)
-    for _ in range(scale):
-        r = rng.random(e)
-        bit_s = (r >= a + b).astype(np.int64)          # lower half of rows
-        r2 = rng.random(e)
-        p_d = np.where(bit_s == 0, b / (a + b), 1 - (c / (1 - a - b)))
-        bit_d = (r2 < p_d).astype(np.int64)            # right half of cols
-        src = (src << 1) | bit_s
-        dst = (dst << 1) | bit_d
+    r = np.empty(e)
+    p_d = np.empty(e)
+    bit_s = np.empty(e, dtype=bool)
+    bit_d = np.empty(e, dtype=bool)
+    for _ in range(scale):                 # in place: 2^25 edges at 2^21
+        rng.random(out=r)
+        np.greater_equal(r, a + b, out=bit_s)          # lower half of rows
+        rng.random(out=r)
+        np.copyto(p_d, b / (a + b))
+        np.copyto(p_d, 1 - (c / (1 - a - b)), where=bit_s)
+        np.less(r, p_d, out=bit_d)                     # right half of cols
+        np.left_shift(src, 1, out=src)
+        src |= bit_s
+        np.left_shift(dst, 1, out=dst)
+        dst |= bit_d
     # permute labels so ids are not degree-correlated
     perm = rng.permutation(n)
     return perm[src], perm[dst], n

@@ -109,8 +109,9 @@ def normalize(edges: EdgeList, *, symmetrize: bool = True) -> EdgeList:
     """Canonical directed entry set: symmetrized (optional), self loops
     dropped, duplicates removed, sorted by (src, dst).
 
-    Dedup is lexsort + adjacent-pair comparison — no flat ``s * n + d``
-    key, so arbitrarily large node counts cannot overflow the sort key.
+    Below 2^31 nodes the sort is one int64 key ``s * n + d``, several
+    times faster than a two-key lexsort at tens of millions of entries;
+    above, lexsort keeps the key from overflowing.
     """
     s, d = edges.src, edges.dst
     if symmetrize:
@@ -118,8 +119,13 @@ def normalize(edges: EdgeList, *, symmetrize: bool = True) -> EdgeList:
         d = np.concatenate([edges.dst, edges.src])
     keep = s != d                      # drop self loops
     s, d = s[keep], d[keep]
-    order = np.lexsort((d, s))
-    s, d = s[order], d[order]
+    n = edges.n_nodes
+    if n < 2 ** 31:
+        key = np.sort(s.astype(np.int64) * n + d)
+        s, d = (key // n).astype(s.dtype), (key % n).astype(d.dtype)
+    else:
+        order = np.lexsort((d, s))
+        s, d = s[order], d[order]
     if len(s):
         first = np.empty(len(s), dtype=bool)
         first[0] = True

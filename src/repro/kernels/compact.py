@@ -2,19 +2,21 @@
 
 Turns a dense active mask into the compacted index array — the TPU-native
 replacement for IrGL's warp-aggregated atomic worklist pushes
-(DESIGN.md §2). The TPU grid executes sequentially, so a running global
-offset lives in SMEM scratch and is carried across grid steps; each step
+(DESIGN.md §2). Each grid step owns one ``(1, TILE)`` block of the mask
+and the same block of the output, and
 
-  1. computes the tile's exclusive prefix sum of the mask,
-  2. materialises the tile's compacted local indices (one-hot position
-     match — O(TILE^2) VPU compares, still HBM-bound overall),
-  3. stores them with a *dynamic-offset, static-size* slice at the global
-     offset (dynamic-slice stores are supported; scatter stores are not),
-  4. bumps the carry.
+  1. computes the tile's exclusive prefix sum of the mask (a masked
+     reduction over a ``(TILE, TILE)`` iota compare; Mosaic has no
+     ``cumsum``),
+  2. materialises the tile's compacted global indices in positions
+     ``[0, tile_count)`` of its own output block (one-hot position match —
+     O(TILE^2) VPU compares, still HBM-bound overall).
 
-Each tile's TILE-wide store overwrites the junk tail of the previous
-tile's store, so after the final step positions [0, count) are exactly the
-compacted indices; the wrapper masks positions >= count with the sentinel.
+The caller stitches the tiles: an exclusive prefix sum over the per-tile
+counts gives each tile's global offset, and one scatter moves the valid
+prefix of every block there. Mosaic refuses a store into VMEM at an
+offset it cannot prove aligned, so the kernel never stores at a running
+offset, and it never holds more than one tile of the output.
 """
 from __future__ import annotations
 
@@ -23,36 +25,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-import jax.experimental.pallas.tpu as pltpu
+
+from repro.kernels.csr_segment import prefix_sum
 
 
-def _compact_kernel(mask_ref, out_ref, count_ref, carry_ref, *, tile: int,
-                    n_grid: int):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        carry_ref[0] = 0
-
-    m = mask_ref[...].astype(jnp.int32)            # (1, TILE)
-    csum = jnp.cumsum(m, axis=1)
-    excl = csum - m                                # exclusive prefix
-    tile_count = csum[0, tile - 1]
-
-    # compacted local indices: pos p holds j s.t. mask[j] & excl[j] == p
-    iota_j = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)   # j
-    iota_p = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)   # p
-    hit = (excl[0][None, :] == iota_p) & (m[0][None, :] != 0)       # (p, j)
-    local = jnp.sum(jnp.where(hit, iota_j, 0), axis=1)              # (p,)
-    base = carry_ref[0]
-    global_idx = local + step * tile               # absolute node ids
-
-    out_ref[pl.ds(base, tile)] = global_idx
-    carry_ref[0] = base + tile_count
-
-    @pl.when(step == n_grid - 1)
-    def _fin():
-        count_ref[0] = carry_ref[0]
+def _compact_kernel(mask_ref, out_ref, *, tile: int):
+    m = mask_ref[...]                               # (1, TILE) int32 0/1
+    src = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)   # j
+    dst = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)   # p / i
+    # row j of each (TILE, TILE) matrix below is about mask position j
+    m_col = jnp.sum(jnp.where(dst == src, m, 0), axis=1, keepdims=True)
+    excl = jnp.sum(jnp.where(dst < src, m, 0), axis=1, keepdims=True)
+    hit = (excl == dst) & (m_col != 0)              # (j, p)
+    local = jnp.sum(jnp.where(hit, src, 0), axis=0, keepdims=True)
+    out_ref[...] = local + pl.program_id(0) * tile  # absolute node ids
 
 
 def compact_pallas(mask: jax.Array, *, tile: int = 256,
@@ -61,27 +47,20 @@ def compact_pallas(mask: jax.Array, *, tile: int = 256,
     n = mask.shape[0]
     pad = (-n) % tile
     m = jnp.pad(mask.astype(jnp.int32), (0, pad))
-    npad = n + pad
-    grid = (npad // tile,)
-    items, count = pl.pallas_call(
-        functools.partial(_compact_kernel, tile=tile, n_grid=grid[0]),
-        grid=grid,
+    n_grid = (n + pad) // tile
+    local = pl.pallas_call(
+        functools.partial(_compact_kernel, tile=tile),
+        grid=(n_grid,),
         in_specs=[pl.BlockSpec((1, tile), lambda i: (0, i))],
-        out_specs=[
-            # whole items array stays VMEM-resident across the sequential
-            # grid (dynamic-offset stores need VMEM; bounds N <= ~4M int32)
-            pl.BlockSpec((npad,), lambda i: (0,)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((npad,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n + pad), jnp.int32),
         interpret=interpret,
     )(m[None, :])
-    cnt = count[0]
-    iota = jnp.arange(n, dtype=jnp.int32)
-    items = jnp.where(iota < cnt, items[:n], n)    # sentinel the junk tail
-    # padded-region indices can never appear: mask was zero there
-    return items, cnt
+    counts = m.reshape(n_grid, tile).sum(axis=1)
+    offsets = prefix_sum(counts) - counts
+    pos = jnp.arange(tile, dtype=jnp.int32)
+    # positions past a tile's count hold junk; index n drops them
+    dest = jnp.where(pos < counts[:, None], offsets[:, None] + pos, n)
+    items = jnp.full((n,), n, jnp.int32).at[dest.reshape(-1)].set(
+        local.reshape(-1), mode="drop")
+    return items, counts.sum(dtype=jnp.int32)

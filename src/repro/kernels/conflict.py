@@ -11,24 +11,18 @@ comparisons into one VMEM-resident pass instead of five HBM sweeps.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.rowtile import loses, slab
 
-def _conflict_kernel(nc_ref, npr_ref, nid_ref, cu_ref, pu_ref, uid_ref,
-                     out_ref):
-    nc = nc_ref[...]          # (TR, K) neighbour colors
-    npr = npr_ref[...]        # (TR, K) neighbour priorities (pad = -1)
-    nid = nid_ref[...]        # (TR, K) neighbour ids
-    cu = cu_ref[...]          # (TR, 1) own color
-    pu = pu_ref[...]          # (TR, 1) own priority
-    uid = uid_ref[...]        # (TR, 1) own id
-    same = (nc == cu) & (cu >= 0)
-    higher = (npr > pu) | ((npr == pu) & (nid > uid))
-    out_ref[...] = jnp.any(same & higher, axis=1).astype(jnp.int32)[:, None]
+
+def _conflict_kernel(nc_ref, npr_ref, nid_ref, row_ref, out_ref):
+    row = row_ref[...]        # (TR, 3) own color / priority / id
+    out_ref[...] = loses(nc_ref[...], npr_ref[...], nid_ref[...],
+                         row[:, 0:1], row[:, 1:2],
+                         row[:, 2:3]).astype(jnp.int32)
 
 
 def conflict_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
@@ -45,20 +39,14 @@ def conflict_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
         pu = jnp.pad(pu, (0, pad), constant_values=-1)
         ids = jnp.pad(ids, (0, pad))
     rp = r + pad
-    col = lambda x: x[:, None].astype(jnp.int32)
+    tile = pl.BlockSpec((tile_rows, k), lambda i: (i, 0))
     out = pl.pallas_call(
         _conflict_kernel,
         grid=(rp // tile_rows,),
-        in_specs=[
-            pl.BlockSpec((tile_rows, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile_rows, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile_rows, k), lambda i: (i, 0)),
-            pl.BlockSpec((tile_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile_rows, 1), lambda i: (i, 0)),
-        ],
+        in_specs=[tile, tile, tile,
+                  pl.BlockSpec((tile_rows, 3), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile_rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, 1), jnp.int32),
         interpret=interpret,
-    )(nc, npr, nbr_ids, col(cu), col(pu), col(ids))
+    )(nc, npr, nbr_ids, slab(cu, pu, ids))
     return out[:r, 0] != 0

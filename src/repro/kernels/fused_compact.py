@@ -1,12 +1,11 @@
-"""Pallas TPU kernel: ONE-LAUNCH IPGC iteration (assign + resolve +
-worklist compaction in a single grid).
+"""Pallas TPU kernel: one IPGC iteration (assign + resolve + new-color
+selection) in a single grid, followed by the worklist emission.
 
-The fused step kernel (``kernels/fused_step.py``) left one extra dispatch
-per iteration: the surviving-node compaction still ran as a separate
-``compact_pallas`` launch, with the intermediate ``still`` mask
-round-tripping through HBM between the two. This kernel folds the
-compaction into the same row-tile grid (DESIGN.md §10), so a dense-mode
-IPGC iteration is exactly one kernel launch:
+The fused step kernel (``kernels/fused_step.py``) returns only the
+per-row verdicts (lose, first free slot); the caller then selected the new
+colors and bases. This kernel folds that selection into the same row-tile
+grid (DESIGN.md §10), so a dense-mode IPGC iteration launches one Pallas
+kernel and then one XLA compaction:
 
 per (TILE_R,)-row grid step —
 
@@ -17,14 +16,16 @@ per (TILE_R,)-row grid step —
      OR-accumulated per ELL lane, seeded from the hub side-channel);
      rows that lost or were still uncolored take ``base + first`` or
      advance their base when the window is exhausted.
-  3. compact: the tile's surviving rows (``still = need``) emit their own
-     ids at a running global offset carried in SMEM across the sequential
-     grid — ``compact.py``'s carry machinery (exclusive prefix sum +
-     one-hot position match + dynamic-offset static-size store), fused
-     rather than re-launched. Each tile's TILE_R-wide store overwrites
-     the junk tail of the previous tile's store, so after the last step
-     positions [0, count) hold exactly the surviving ids in ascending
-     tile order; the wrapper masks positions >= count with the sentinel.
+  3. the tile's new colors, new bases and survivor flags (``still =
+     need``) leave as one ``(TILE_R, 3)`` output block.
+
+The compaction of the survivors into the worklist runs after the kernel,
+as the same ``worklist.compact_items`` the jnp path uses. An in-kernel
+compaction needs each tile to store at a running offset, and Mosaic
+refuses a store into VMEM at an offset it cannot prove aligned; holding
+the whole items array in VMEM instead does not fit at real graph sizes.
+Per-tile compacted blocks would still need an XLA prefix sum and scatter
+to stitch, which is the work ``jnp.nonzero`` does on the survivor mask.
 
 The emitted value is the row's ``ids`` input (not a computed global
 index), so ONE kernel serves both worklist forms: the dense step passes
@@ -47,82 +48,38 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-import jax.experimental.pallas.tpu as pltpu
+
+from repro.core.worklist import compact_items
+from repro.kernels.rowtile import first_free, lanes, loses, slab
 
 
-def _fused_compact_kernel(*refs, window: int, k_width: int, tile_rows: int,
-                          n_grid: int, no_color: int, with_hub: bool):
+def _fused_compact_kernel(*refs, window: int, no_color: int, with_hub: bool):
     if with_hub:
-        (nc_ref, npr_ref, nid_ref, base_ref, cu_ref, pu_ref, uid_ref,
-         act_ref, pend_ref, extra_ref, hl_ref,
-         newc_ref, newb_ref, still_ref, items_ref, count_ref,
-         carry_ref) = refs
+        nc_ref, npr_ref, nid_ref, row_ref, extra_ref, out_ref = refs
     else:
-        (nc_ref, npr_ref, nid_ref, base_ref, cu_ref, pu_ref, uid_ref,
-         act_ref, pend_ref,
-         newc_ref, newb_ref, still_ref, items_ref, count_ref,
-         carry_ref) = refs
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        carry_ref[0] = 0
-
+        nc_ref, npr_ref, nid_ref, row_ref, out_ref = refs
     nc = nc_ref[...]                      # (TR, K) neighbour colors
-    npr = npr_ref[...]                    # (TR, K) neighbour priorities
-    nid = nid_ref[...]                    # (TR, K) neighbour ids
-    base = base_ref[...]                  # (TR, 1) window base
-    cu = cu_ref[...]                      # (TR, 1) own (pending) color
-    pu = pu_ref[...]                      # (TR, 1) own priority
-    uid = uid_ref[...]                    # (TR, 1) own id (emitted value)
-    act = act_ref[...] != 0               # (TR, 1) active (in worklist)
-    pend = pend_ref[...] != 0             # (TR, 1) speculated last round
+    # (TR, 6|7) base / own color / priority / id / active / pending
+    # [/ hub lose]
+    row = row_ref[...]
+    base, cu, pu, uid = (row[:, j:j + 1] for j in range(4))
+    act = row[:, 4:5] != 0
+    pend = row[:, 5:6] != 0
 
-    # --- resolve: conflict check on the resident tile ---
-    same = (nc == cu) & (cu >= 0)
-    higher = (npr > pu) | ((npr == pu) & (nid > uid))
-    lose = jnp.any(same & higher, axis=1)[:, None] & pend
+    lose = loses(nc, npr_ref[...], nid_ref[...], cu, pu, uid) & pend
     if with_hub:
-        lose = lose | ((hl_ref[...] != 0) & pend)
-
-    # --- assign: windowed mex over the SAME tile ---
-    rel = nc - base
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (tile_rows, window), 1)
-
-    def body(k, forb):
-        r = jax.lax.dynamic_slice_in_dim(rel, k, 1, axis=1)   # (TR, 1)
-        return forb | (r == iota_w)
-
-    init = (extra_ref[...] != 0) if with_hub else jnp.zeros(
-        (tile_rows, window), bool)
-    forb = jax.lax.fori_loop(0, k_width, body, init)
-    free = jnp.logical_not(forb)
-    has = jnp.any(free, axis=1)[:, None]
-    first = jnp.argmax(free, axis=1).astype(jnp.int32)[:, None]
+        lose = lose | ((row[:, 6:7] != 0) & pend)
+        forb = extra_ref[...] != 0
+    else:
+        forb = jnp.zeros((nc.shape[0], window), bool)
+    first = first_free(nc, base, forb, window)
+    has = first >= 0
 
     need = lose | (act & (cu < 0))        # rows to (re)color = survivors
     new_c = jnp.where(need & has, base + first,
                       jnp.where(lose, no_color, cu))
     new_b = jnp.where(need & ~has, base + window, base)
-    newc_ref[...] = new_c
-    newb_ref[...] = new_b
-    still_ref[...] = need.astype(jnp.int32)
-
-    # --- compact: emit surviving ids at the SMEM-carried global offset ---
-    m = need[:, 0].astype(jnp.int32)[None, :]       # (1, TR)
-    csum = jnp.cumsum(m, axis=1)
-    excl = csum - m                                 # exclusive prefix
-    tile_count = csum[0, tile_rows - 1]
-    iota_p = jax.lax.broadcasted_iota(jnp.int32, (tile_rows, tile_rows), 0)
-    hit = (excl[0][None, :] == iota_p) & (m[0][None, :] != 0)     # (p, j)
-    vals = jnp.sum(jnp.where(hit, uid[:, 0][None, :], 0), axis=1)  # (p,)
-    off = carry_ref[0]
-    items_ref[pl.ds(off, tile_rows)] = vals
-    carry_ref[0] = off + tile_count
-
-    @pl.when(step == n_grid - 1)
-    def _fin():
-        count_ref[0] = carry_ref[0]
+    out_ref[...] = lanes(new_c, new_b, need.astype(jnp.int32))
 
 
 def fused_compact_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
@@ -136,7 +93,7 @@ def fused_compact_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
                          interpret: bool = False
                          ) -> tuple[jax.Array, jax.Array, jax.Array,
                                     jax.Array, jax.Array]:
-    """One-launch fused step + compaction over R rows.
+    """Fused step kernel + worklist emission over R rows.
 
     nc/npr/nbr_ids: (R, K) int32 neighbour color/priority/id tiles
     base:           (R,)  int32 window base per row
@@ -158,60 +115,38 @@ def fused_compact_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
     with_hub = extra_forb is not None
     assert (hub_lose is not None) == with_hub, \
         "extra_forb and hub_lose arrive together (the hub variant)"
+    rows = [base, cu, pu, ids, active, pending]
     if with_hub:
         assert extra_forb.shape == (r, window)
+        rows.append(hub_lose)
+    row = slab(*rows)
     pad = (-r) % tile_rows
     if pad:
         nc = jnp.pad(nc, ((0, pad), (0, 0)), constant_values=-2)
         npr = jnp.pad(npr, ((0, pad), (0, 0)), constant_values=-1)
         nbr_ids = jnp.pad(nbr_ids, ((0, pad), (0, 0)))
-        base = jnp.pad(base, (0, pad))
-        cu = jnp.pad(cu, (0, pad), constant_values=-2)
-        pu = jnp.pad(pu, (0, pad), constant_values=-1)
-        ids = jnp.pad(ids, (0, pad), constant_values=n_sentinel)
-        active = jnp.pad(active, (0, pad))     # pad rows inert: never emit
-        pending = jnp.pad(pending, (0, pad))
+        # pad rows are inert (active and pending 0): they never survive
+        row = jnp.pad(row, ((0, pad), (0, 0)))
         if with_hub:
             extra_forb = jnp.pad(extra_forb, ((0, pad), (0, 0)))
-            hub_lose = jnp.pad(hub_lose, (0, pad))
     rp = r + pad
-    assert capacity <= rp, (capacity, rp)
-    col = lambda x: x[:, None].astype(jnp.int32)
-    row_spec = pl.BlockSpec((tile_rows, k), lambda i: (i, 0))
-    one_spec = pl.BlockSpec((tile_rows, 1), lambda i: (i, 0))
-    win_spec = pl.BlockSpec((tile_rows, window), lambda i: (i, 0))
-    n_grid = rp // tile_rows
-    operands = [nc, npr, nbr_ids, col(base), col(cu), col(pu), col(ids),
-                col(active), col(pending)]
-    in_specs = [row_spec, row_spec, row_spec, one_spec, one_spec, one_spec,
-                one_spec, one_spec, one_spec]
+    tile = pl.BlockSpec((tile_rows, k), lambda i: (i, 0))
+    operands = [nc, npr, nbr_ids, row]
+    in_specs = [tile, tile, tile,
+                pl.BlockSpec((tile_rows, len(rows)), lambda i: (i, 0))]
     if with_hub:
-        operands += [extra_forb.astype(jnp.int32), col(hub_lose)]
-        in_specs += [win_spec, one_spec]
-    newc, newb, still, items, count = pl.pallas_call(
-        functools.partial(_fused_compact_kernel, window=window, k_width=k,
-                          tile_rows=tile_rows, n_grid=n_grid,
+        operands.append(extra_forb.astype(jnp.int32))
+        in_specs.append(pl.BlockSpec((tile_rows, window), lambda i: (i, 0)))
+    out = pl.pallas_call(
+        functools.partial(_fused_compact_kernel, window=window,
                           no_color=no_color, with_hub=with_hub),
-        grid=(n_grid,),
+        grid=(rp // tile_rows,),
         in_specs=in_specs,
-        out_specs=[
-            one_spec, one_spec, one_spec,
-            # whole items array stays VMEM-resident across the sequential
-            # grid — dynamic-offset stores need VMEM (see compact.py)
-            pl.BlockSpec((rp,), lambda i: (0,)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((rp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((rp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((rp,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        out_specs=pl.BlockSpec((tile_rows, 3), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rp, 3), jnp.int32),
         interpret=interpret,
     )(*operands)
-    cnt = count[0]
-    iota = jnp.arange(capacity, dtype=jnp.int32)
-    items = jnp.where(iota < cnt, items[:capacity], n_sentinel)
-    return newc[:r, 0], newb[:r, 0], still[:r, 0] != 0, items, cnt
+    still = out[:r, 2] != 0
+    items, count = compact_items(ids.astype(jnp.int32), still, n_sentinel,
+                                 capacity)
+    return out[:r, 0], out[:r, 1], still, items, count

@@ -9,7 +9,8 @@ so ONE tile load feeds both:
      color with a higher (priority, id) pair — 5 compares + a K-reduce on
      the resident tile.
   2. windowed mex: forbidden bitmap OR-accumulated from the SAME tile
-     (plus the hub side-channel bitmap), then first-free via argmax.
+     (plus the hub side-channel bitmap), then first-free as a min over
+     the window iota (rowtile.py).
 
 Outputs are per-row ``lose`` flags and the first free window index
 (``-1`` when the window is exhausted); the caller applies the need/pending
@@ -25,40 +26,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.rowtile import first_free, lanes, loses, slab
 
-def _fused_kernel(nc_ref, npr_ref, nid_ref, base_ref, cu_ref, pu_ref,
-                  uid_ref, pend_ref, extra_ref, lose_ref, first_ref, *,
-                  window: int, k_width: int):
+
+def _fused_kernel(nc_ref, npr_ref, nid_ref, row_ref, extra_ref, out_ref, *,
+                  window: int):
     nc = nc_ref[...]                      # (TR, K) neighbour colors
-    npr = npr_ref[...]                    # (TR, K) neighbour priorities
-    nid = nid_ref[...]                    # (TR, K) neighbour ids
-    base = base_ref[...]                  # (TR, 1) window base
-    cu = cu_ref[...]                      # (TR, 1) own (pending) color
-    pu = pu_ref[...]                      # (TR, 1) own priority
-    uid = uid_ref[...]                    # (TR, 1) own id
-    pend = pend_ref[...]                  # (TR, 1) int32 0/1 pending flag
-    extra = extra_ref[...]                # (TR, W) int32 0/1 hub forbidden
-
-    # --- resolve: conflict check on the resident tile ---
-    same = (nc == cu) & (cu >= 0)
-    higher = (npr > pu) | ((npr == pu) & (nid > uid))
-    lose = jnp.any(same & higher, axis=1) & (pend[:, 0] != 0)
-    lose_ref[...] = lose.astype(jnp.int32)[:, None]
-
-    # --- assign: windowed mex over the SAME tile ---
-    rel = nc - base                       # row-relative colors
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (nc.shape[0], window), 1)
-
-    def body(k, forb):
-        r = jax.lax.dynamic_slice_in_dim(rel, k, 1, axis=1)  # (TR, 1)
-        # negative rel (uncolored/pad neighbours) and rel >= W never match
-        return forb | (r == iota_w)
-
-    forb = jax.lax.fori_loop(0, k_width, body, extra != 0)
-    free = jnp.logical_not(forb)
-    has = jnp.any(free, axis=1)
-    first = jnp.argmax(free, axis=1).astype(jnp.int32)
-    first_ref[...] = jnp.where(has, first, -1)[:, None]
+    row = row_ref[...]                    # (TR, 5) base/cu/pu/uid/pending
+    base, cu, pu, uid = (row[:, j:j + 1] for j in range(4))
+    lose = loses(nc, npr_ref[...], nid_ref[...], cu, pu, uid) & (
+        row[:, 4:5] != 0)
+    first = first_free(nc, base, extra_ref[...] != 0, window)
+    out_ref[...] = lanes(lose.astype(jnp.int32), first)
 
 
 def fused_step_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
@@ -92,19 +71,16 @@ def fused_step_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
         pending = jnp.pad(pending, (0, pad))
         extra_forb = jnp.pad(extra_forb, ((0, pad), (0, 0)))
     rp = r + pad
-    col = lambda x: x[:, None].astype(jnp.int32)
-    row_spec = pl.BlockSpec((tile_rows, k), lambda i: (i, 0))
-    one_spec = pl.BlockSpec((tile_rows, 1), lambda i: (i, 0))
-    win_spec = pl.BlockSpec((tile_rows, window), lambda i: (i, 0))
-    lose, first = pl.pallas_call(
-        functools.partial(_fused_kernel, window=window, k_width=k),
+    tile = pl.BlockSpec((tile_rows, k), lambda i: (i, 0))
+    out = pl.pallas_call(
+        functools.partial(_fused_kernel, window=window),
         grid=(rp // tile_rows,),
-        in_specs=[row_spec, row_spec, row_spec, one_spec, one_spec,
-                  one_spec, one_spec, one_spec, win_spec],
-        out_specs=[one_spec, one_spec],
-        out_shape=[jax.ShapeDtypeStruct((rp, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((rp, 1), jnp.int32)],
+        in_specs=[tile, tile, tile,
+                  pl.BlockSpec((tile_rows, 5), lambda i: (i, 0)),
+                  pl.BlockSpec((tile_rows, window), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((tile_rows, 2), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rp, 2), jnp.int32),
         interpret=interpret,
-    )(nc, npr, nbr_ids, col(base), col(cu), col(pu), col(ids),
-      col(pending), extra_forb.astype(jnp.int32))
-    return lose[:r, 0] != 0, first[:r, 0]
+    )(nc, npr, nbr_ids, slab(base, cu, pu, ids, pending),
+      extra_forb.astype(jnp.int32))
+    return out[:r, 0] != 0, out[:r, 1]

@@ -12,39 +12,28 @@ kernel is a masked row reduction over the ELL axis:
   nbr_max[u] = max_k npr[u, k]                     (-1 if no active nbr)
   nbr_min[u] = min_k (npr[u, k] if npr >= 0 else LARGE)
 
-Layout reasoning (HBM->VMEM->VREG): K is the unrolled reduction dim; each k
-contributes one (TILE_R, 1) compare, so the working set is just the npr
-tile plus two (TILE_R, 1) accumulators — pure VPU work, no MXU. Priorities
+Layout reasoning (HBM->VMEM->VREG): both extrema are lane reductions of
+the resident (TILE_R, K) npr tile, written as one (TILE_R, 2) output block
+— pure VPU work, no MXU. Priorities
 arrive pre-hashed (the splitmix hash is cheap elementwise jnp; the kernel
 covers the O(rows * K) reduction hot-spot).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.rowtile import lanes
+
 LARGE = 0x7FFFFFFF  # int literal: jnp constants would be captured as consts
 
 
-def _extrema_kernel(npr_ref, max_ref, min_ref, *, k_width: int):
+def _extrema_kernel(npr_ref, out_ref):
     npr = npr_ref[...]                    # (TR, K) int32, inactive = -1
-    tr = npr.shape[0]
-
-    def body(k, carry):
-        mx, mn = carry
-        p = jax.lax.dynamic_slice_in_dim(npr, k, 1, axis=1)  # (TR, 1)
-        mx = jnp.maximum(mx, p)
-        mn = jnp.minimum(mn, jnp.where(p >= 0, p, LARGE))
-        return mx, mn
-
-    init = (jnp.full((tr, 1), -1, jnp.int32), jnp.full((tr, 1), LARGE,
-                                                       jnp.int32))
-    mx, mn = jax.lax.fori_loop(0, k_width, body, init)
-    max_ref[...] = mx
-    min_ref[...] = mn
+    out_ref[...] = lanes(
+        jnp.max(npr, axis=1, keepdims=True),
+        jnp.min(jnp.where(npr >= 0, npr, LARGE), axis=1, keepdims=True))
 
 
 def jpl_extrema_pallas(npr: jax.Array, *, tile_rows: int = 32,
@@ -61,15 +50,12 @@ def jpl_extrema_pallas(npr: jax.Array, *, tile_rows: int = 32,
     if pad:
         npr = jnp.pad(npr, ((0, pad), (0, 0)), constant_values=-1)
     rp = r + pad
-    grid = (rp // tile_rows,)
-    mx, mn = pl.pallas_call(
-        functools.partial(_extrema_kernel, k_width=k),
-        grid=grid,
+    out = pl.pallas_call(
+        _extrema_kernel,
+        grid=(rp // tile_rows,),
         in_specs=[pl.BlockSpec((tile_rows, k), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((tile_rows, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((tile_rows, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((rp, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((rp, 1), jnp.int32)],
+        out_specs=pl.BlockSpec((tile_rows, 2), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rp, 2), jnp.int32),
         interpret=interpret,
     )(npr)
-    return mx[:r, 0], mn[:r, 0]
+    return out[:r, 0], out[:r, 1]

@@ -8,8 +8,9 @@ work on (TILE_R, 128) vectors.
 
 Layout reasoning (HBM->VMEM->VREG):
   * W = window is fixed at a multiple of 128 — one or more full lane rows.
-  * K (ELL width) is the unrolled reduction dim; each k contributes one
-    (TILE_R, W) compare+or, so the working set is 3 * TILE_R * W * 4 bytes
+  * K (ELL width) is the unrolled reduction dim (static lane slices, see
+    rowtile.py); each k contributes one (TILE_R, W) compare+or, so the
+    working set is 3 * TILE_R * W * 4 bytes
     (nc tile + forbidden accumulator + iota), far under VMEM for
     TILE_R = 8..64.
   * neighbour colors arrive pre-gathered (the gather is an XLA dynamic-
@@ -24,25 +25,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.rowtile import first_free
 
-def _mex_kernel(nc_ref, base_ref, extra_ref, out_ref, *, window: int,
-                k_width: int):
-    nc = nc_ref[...]                      # (TR, K) int32
-    base = base_ref[...]                  # (TR, 1) int32
-    extra = extra_ref[...]                # (TR, W) int32 0/1
-    rel = nc - base                       # row-relative colors
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (nc.shape[0], window), 1)
 
-    def body(k, forb):
-        r = jax.lax.dynamic_slice_in_dim(rel, k, 1, axis=1)  # (TR, 1)
-        # negative rel (uncolored/pad neighbours) and rel >= W never match
-        return forb | (r == iota_w)
-
-    forb = jax.lax.fori_loop(0, k_width, body, extra != 0)
-    free = jnp.logical_not(forb)
-    has = jnp.any(free, axis=1)
-    first = jnp.argmax(free, axis=1).astype(jnp.int32)
-    out_ref[...] = jnp.where(has, first, -1)[:, None]
+def _mex_kernel(nc_ref, base_ref, extra_ref, out_ref, *, window: int):
+    out_ref[...] = first_free(nc_ref[...], base_ref[...],
+                              extra_ref[...] != 0, window)
 
 
 def mex_window_pallas(nc: jax.Array, base: jax.Array, extra_forb: jax.Array,
@@ -64,7 +52,7 @@ def mex_window_pallas(nc: jax.Array, base: jax.Array, extra_forb: jax.Array,
     rp = r + pad
     grid = (rp // tile_rows,)
     out = pl.pallas_call(
-        functools.partial(_mex_kernel, window=window, k_width=k),
+        functools.partial(_mex_kernel, window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_rows, k), lambda i: (i, 0)),

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On TPU the kernels compile natively; on CPU (this container) they execute
-in interpret mode so the engine's ``impl="pallas"`` path stays testable
-end-to-end. CPU *benchmarks* use the jnp reference path (``impl="jnp"``)
+On a TPU backend the kernels compile natively; on any other backend they
+execute in interpret mode (``interpret()``) so the engine's
+``impl="pallas"`` path stays testable end-to-end. CPU *benchmarks* use the jnp reference path (``impl="jnp"``)
 — interpret mode measures Python, not the kernel.
 """
 from __future__ import annotations
@@ -27,7 +27,10 @@ def _tile(tile_rows: "int | None") -> int:
     return DEFAULT_TILE_ROWS if tile_rows is None else tile_rows
 
 
-def _interpret() -> bool:
+def interpret() -> bool:
+    """Whether the Pallas kernels run in interpret mode: everywhere but
+    on a TPU backend, where they always compile natively. The one place
+    this is decided (the autotuner and the kernel bench ask here)."""
     return jax.default_backend() != "tpu"
 
 
@@ -37,7 +40,7 @@ def mex_window(nc: jax.Array, base: jax.Array, extra_forb: jax.Array,
                ) -> tuple[jax.Array, jax.Array]:
     first = mex_window_pallas(nc, base, extra_forb, window,
                               tile_rows=_tile(tile_rows),
-                              interpret=_interpret())
+                              interpret=interpret())
     return first, first >= 0
 
 
@@ -47,7 +50,7 @@ def conflict(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
              tile_rows: "int | None" = None) -> jax.Array:
     return conflict_pallas(nc, npr, nbr_ids, cu, pu, ids,
                            tile_rows=_tile(tile_rows),
-                           interpret=_interpret())
+                           interpret=interpret())
 
 
 @partial(jax.jit, static_argnames=("window", "tile_rows"))
@@ -60,7 +63,7 @@ def fused_step(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
     conflict check and the windowed mex (see kernels/fused_step.py)."""
     return fused_step_pallas(nc, npr, nbr_ids, base, cu, pu, ids, pending,
                              extra_forb, window, tile_rows=_tile(tile_rows),
-                             interpret=_interpret())
+                             interpret=interpret())
 
 
 def fused_compact(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
@@ -72,7 +75,7 @@ def fused_compact(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
                   tile_rows: "int | None" = None
                   ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                              jax.Array]:
-    """ONE-launch fused step + worklist compaction (DESIGN.md §10).
+    """Fused step kernel + worklist compaction (DESIGN.md §10).
 
     Not independently jitted: the optional hub operands change the
     traced signature, and every caller (the ipgc step impls) already
@@ -83,7 +86,7 @@ def fused_compact(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
                                 window, capacity=capacity,
                                 n_sentinel=n_sentinel,
                                 tile_rows=_tile(tile_rows),
-                                interpret=_interpret())
+                                interpret=interpret())
 
 
 @partial(jax.jit, static_argnames=("tile_rows",))
@@ -92,16 +95,16 @@ def jpl_extrema(npr: jax.Array, tile_rows: "int | None" = None
     """Per-row (max, masked min) of active-neighbour JPL priorities (the
     independent-set membership compare; see kernels/jpl_prio.py)."""
     return jpl_extrema_pallas(npr, tile_rows=_tile(tile_rows),
-                              interpret=_interpret())
+                              interpret=interpret())
 
 
 @jax.jit
 def compact(mask: jax.Array) -> tuple[jax.Array, jax.Array]:
-    return compact_pallas(mask, interpret=_interpret())
+    return compact_pallas(mask, interpret=interpret())
 
 
 @jax.jit
 def frontier_probe(nbr_in_frontier: jax.Array,
                    unvisited: jax.Array) -> jax.Array:
     return frontier_probe_pallas(nbr_in_frontier, unvisited,
-                                 interpret=_interpret())
+                                 interpret=interpret())

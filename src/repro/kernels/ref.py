@@ -47,7 +47,7 @@ def fused_compact_ref(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
                       capacity: int, n_sentinel: int, no_color: int = -1
                       ) -> tuple[jax.Array, jax.Array, jax.Array,
                                  jax.Array, jax.Array]:
-    """One-launch fused step + compaction oracle (kernels/fused_compact.py):
+    """Fused step + compaction oracle (kernels/fused_compact.py):
     resolve + windowed mex + new-color/base selection + emission of the
     surviving rows' ``ids`` in ascending row order with a sentinel tail —
     the exact semantics of the jnp fused step followed by

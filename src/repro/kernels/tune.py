@@ -13,7 +13,8 @@ default everywhere, the engine asks this module at Session prepare time:
     candidate tile heights over a small synthetic workload shaped like
     that kind (hub operands on for the hub kinds) and records the winner;
   * winners persist in an on-disk JSON cache keyed like the Session
-    compile cache (one entry per triple, schema below), so later
+    compile cache (one entry per triple, schema below; default
+    ``<checkout>/.cache/tune.json``, see repro/caches.py), so later
     processes skip the sweep;
   * the chosen ``tile_rows`` rides ``ExecutionSpec.static_key()`` — it is
     a static jit argument all the way down, so two runs tuned to
@@ -36,6 +37,8 @@ import os
 import time
 
 import numpy as np
+
+from repro.caches import CACHE_ROOT
 
 CACHE_ENV = "REPRO_TUNE_CACHE"
 CACHE_VERSION = 1
@@ -65,8 +68,7 @@ def cache_path() -> str:
     override = os.environ.get(CACHE_ENV)
     if override:
         return override
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        "tune.json")
+    return str(CACHE_ROOT / "tune.json")
 
 
 def tune_key(backend: str, kind: str, dtype: str = "int32") -> str:
@@ -117,15 +119,15 @@ def _sweep_case(kind: str, rng: np.random.Generator):
 
 
 def _time_candidate(case, tile_rows: int) -> float:
-    """Median warm wall-micros of the one-launch kernel at this tile
+    """Median warm wall-micros of the fused step kernel at this tile
     height, measured through ``jit`` so tracing cost (identical for every
     candidate, and amortised by the step jits in real runs) stays out of
     the timed region — un-jitted timings are all trace overhead and rank
     the candidates by noise."""
     import jax
     from repro.kernels.fused_compact import fused_compact_pallas
+    from repro.kernels.ops import interpret
 
-    interpret = jax.default_backend() != "tpu"
     with_hub = case[-1] is not None
     operands = [a for a in case if a is not None]
 
@@ -140,7 +142,7 @@ def _time_candidate(case, tile_rows: int) -> float:
                                     capacity=_SWEEP_ROWS,
                                     n_sentinel=_SWEEP_ROWS,
                                     tile_rows=tile_rows,
-                                    interpret=interpret)
+                                    interpret=interpret())
 
     jax.block_until_ready(call(*operands))   # compile
     jax.block_until_ready(call(*operands))   # warm

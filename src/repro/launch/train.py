@@ -39,7 +39,6 @@ def build_step(cfg, opt_cfg, *, compress: bool = False, mesh=None):
 
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     assert mesh is not None
 
@@ -51,10 +50,10 @@ def build_step(cfg, opt_cfg, *, compress: bool = False, mesh=None):
             grads, err2 = compressed_psum(grads, err, "data")
             return loss, grads, err2
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             dp_grads, mesh=mesh,
             in_specs=(P(), jax.tree.map(lambda _: P("data"), batch), P()),
-            out_specs=(P(), P(), P()), check_rep=False)
+            out_specs=(P(), P(), P()), check_vma=False)
         loss, grads, err2 = sharded(params, batch, err)
         p2, o2, om = adamw_update(grads, opt, params, opt_cfg)
         return p2, o2, err2, {"loss": loss, **om}

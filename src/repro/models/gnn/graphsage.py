@@ -106,7 +106,6 @@ def forward_full_owner(params, batch: g.GraphBatch, cfg: SAGEConfig, *,
     (graphs.partition.repartition + sort by dst block).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n = batch.node_feat.shape[0]
     n_shards = 1
@@ -129,9 +128,10 @@ def forward_full_owner(params, batch: g.GraphBatch, cfg: SAGEConfig, *,
             out = _layer(params, i, out, agg, last=(i == cfg.n_layers - 1))
         return out
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(node_axes, None), P(node_axes), P(node_axes)),
-                   out_specs=P(node_axes, None), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(node_axes, None), P(node_axes),
+                                 P(node_axes)),
+                       out_specs=P(node_axes, None), check_vma=False)
     return fn(batch.node_feat, batch.edge_src, batch.edge_dst)
 
 

@@ -21,7 +21,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,11 +129,11 @@ def moe_ffn(x: jax.Array, p: dict, cfg: MoESettings, *, mesh=None,
             w_out = jax.lax.all_gather(w_out, fsdp_axes, axis=1, tiled=True)
         return local(x_l, wr, w_in, w_gate, w_out, e_local=e_local, dist=True)
 
-    fn = shard_map(sharded, mesh=mesh,
-                   in_specs=(x_spec, P(),
-                             P(model_axis, None, fa),
-                             P(model_axis, None, fa),
-                             P(model_axis, fa, None)),
-                   out_specs=(x_spec, P()),
-                   check_rep=False)
+    fn = jax.shard_map(sharded, mesh=mesh,
+                       in_specs=(x_spec, P(),
+                                 P(model_axis, None, fa),
+                                 P(model_axis, None, fa),
+                                 P(model_axis, fa, None)),
+                       out_specs=(x_spec, P()),
+                       check_vma=False)
     return fn(x, p["router"], p["we_in"], p["we_gate"], p["we_out"])

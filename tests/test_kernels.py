@@ -294,36 +294,104 @@ def test_fused_compact_property(r, k, hub, data):
 # ---------------------------------------------------------------------------
 
 def _edge_case(rng, e, n, w):
-    es = rng.integers(0, n, size=(e,)).astype(np.int32)
+    """A CSR edge list (sources ascending, the segment layout's contract)
+    with per-row window bases and colors: a row's color lies in its
+    window or is negative, as every row a step may uncolor."""
+    es = np.sort(rng.integers(0, n, size=(e,))).astype(np.int32)
     ed = rng.integers(0, n, size=(e,)).astype(np.int32)
-    cu_e = rng.integers(-2, 20, size=(e,)).astype(np.int32)
-    cv_e = rng.integers(-2, 20, size=(e,)).astype(np.int32)
-    pu_e = rng.integers(0, 50, size=(e,)).astype(np.int32)
-    pv_e = rng.integers(0, 50, size=(e,)).astype(np.int32)
-    base = (rng.integers(0, 3, size=(e,)) * w).astype(np.int32)
-    return tuple(map(jnp.asarray, (es, ed, cu_e, cv_e, pu_e, pv_e, base)))
+    base = (rng.integers(0, 3, size=(n,)) * w).astype(np.int32)
+    c_rows = np.where(rng.random(n) < 0.3, -1,
+                      base + rng.integers(0, w, size=(n,))).astype(np.int32)
+    cv_e = rng.integers(-2, 3 * w, size=(e,)).astype(np.int32)
+    prio = rng.integers(0, 50, size=(n,)).astype(np.int32)
+    return tuple(map(jnp.asarray, (es, ed, c_rows, cv_e, prio, base)))
 
 
 @pytest.mark.parametrize("e,n", [(1, 1), (40, 10), (500, 64)])
 def test_edge_cores_match_ref(e, n):
     from repro.kernels import csr_segment as kcsr
     rng = np.random.default_rng(e + n)
-    es, ed, cu_e, cv_e, pu_e, pv_e, base = _edge_case(rng, e, n, 32)
-    got_c = kcsr.edge_conflict(es, ed, cu_e, cv_e, pu_e, pv_e, n)
+    es, ed, c_rows, cv_e, prio, base = _edge_case(rng, e, n, 32)
+    deg = np.bincount(np.asarray(es), minlength=n).astype(np.int32)
+    start = jnp.asarray(np.cumsum(deg) - deg, jnp.int32)
+    deg = jnp.asarray(deg)
+    cu_e, pu_e, pv_e, base_e = c_rows[es], prio[es], prio[ed], base[es]
+    wins_e = kcsr.wins(pu_e, pv_e, es, ed)
+    got_c = kcsr.edge_conflict(es, cv_e, wins_e, base_e, c_rows, base,
+                               start, deg, 32)
     want_c = ref.edge_conflict_ref(es, ed, cu_e, cv_e, pu_e, pv_e, n)
     np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
-    got_f = kcsr.edge_forbidden(es, cv_e, base, n, 32)
-    want_f = ref.edge_forbidden_ref(es, cv_e, base, n, 32)
+    got_f = kcsr.edge_forbidden(es, cv_e, base_e, start, deg, 32)
+    want_f = ref.edge_forbidden_ref(es, cv_e, base_e, n, 32)
     np.testing.assert_array_equal(np.asarray(got_f), np.asarray(want_f))
     # the one-pass core is exactly the pair from one shared sweep
-    fc, ff = kcsr.edge_fused(es, ed, cu_e, cv_e, pu_e, pv_e, base, n, 32)
+    fc, ff = kcsr.edge_fused(es, cv_e, wins_e, base_e, c_rows, base, start,
+                             deg, 32)
     np.testing.assert_array_equal(np.asarray(fc), np.asarray(want_c))
     np.testing.assert_array_equal(np.asarray(ff), np.asarray(want_f))
 
 
-# ---------------------------------------------------------------------------
-# jpl extrema kernel vs oracle
-# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("n", [1, 2, 7, 1000])
+def test_segment_primitives_match_numpy(n):
+    """prefix_sum / spread / segment_or against their numpy meaning."""
+    from repro.kernels import csr_segment as kcsr
+    rng = np.random.default_rng(n)
+    x = rng.integers(-50, 50, size=n).astype(np.int32)
+    np.testing.assert_array_equal(
+        np.asarray(kcsr.prefix_sum(jnp.asarray(x))), np.cumsum(x))
+    counts = rng.integers(0, 4, size=n).astype(np.int32)   # empty owners too
+    starts = (np.cumsum(counts) - counts).astype(np.int32)
+    m = int(counts.sum()) + 3                              # + a pad tail
+    vals = rng.integers(-9, 9, size=n).astype(np.int32)
+    owner = np.repeat(np.arange(n), counts)
+    # entries past the last range take the last owner's value
+    want = np.concatenate([vals[owner], np.full(3, vals[-1])])
+    got = kcsr.spread(jnp.asarray(vals), jnp.asarray(starts), m)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    bits = rng.integers(0, 2 ** 31, size=owner.size).astype(np.uint32)
+    (acc,) = kcsr.segment_or((jnp.asarray(bits),), jnp.asarray(owner),
+                             int(counts.max()))
+    got = kcsr.read_first(acc, jnp.asarray(starts), jnp.asarray(counts > 0))
+    want = [np.bitwise_or.reduce(bits[owner == i]) if counts[i] else 0
+            for i in range(n)]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want,
+                                                              np.uint32))
+
+
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("layout,ell_cap", [("csr-segment", None),
+                                            ("ell-tail", 8)])
+def test_packed_sparse_steps_match_full_sweep(layout, ell_cap, fused):
+    """Sparse steps that read only their rows' entries (the packed form)
+    color exactly like sweeps over every edge / tail entry. A geometric
+    graph: its top 1024 rows own few enough entries to pack."""
+    import dataclasses
+
+    from repro.algos import get_algorithm
+    from repro.core import ipgc
+    from repro.exec import ExecutionSpec, Session
+    from repro.graphs import generators, ingest
+    from repro.graphs.layout import run_pipeline
+
+    src, dst, n = generators.edges_rgg(8192, 16, 0)
+    g = run_pipeline(ingest.from_arrays(src, dst, n, name="rgg"),
+                     layout=layout, ell_cap=ell_cap)
+    ig = get_algorithm("ipgc").prepare(g)
+    probe = jnp.zeros((1024,), jnp.int32)
+    if layout == "csr-segment":
+        packed = ipgc._packed(ig, probe, ig.edge_dst, lambda r: r,
+                              lambda r: r, keyed=True)
+    else:
+        packed = ipgc._hub_packed(ig, probe)
+    assert packed is not None          # the floor bucket does pack
+    spec = ExecutionSpec(regime="host", fused=fused, window=32)
+    sess = Session()
+    got = sess.run(spec, ig)
+    want = sess.run(spec, dataclasses.replace(ig, seg_bound=()))
+    np.testing.assert_array_equal(got.colors, want.colors)
+    assert (got.iterations, got.mode_trace) == (want.iterations,
+                                                want.mode_trace)
+
 
 @pytest.mark.parametrize("r,k", [(1, 1), (33, 8), (200, 40)])
 def test_jpl_extrema_matches_ref(r, k):

@@ -142,7 +142,6 @@ def test_int8_quantization_bounded_error(rows, cols):
 
 def test_compressed_psum_single_device():
     mesh = jax.make_mesh((1,), ("data",))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     g = {"w": jnp.asarray([[1.0, -2.0, 3.0]])}
     err = compress_init(g)
@@ -151,8 +150,8 @@ def test_compressed_psum_single_device():
         from repro.optim.compression import compressed_psum
         return compressed_psum(g, e.error, "data")
 
-    red, new_e = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                           out_specs=(P(), P()), check_rep=False)(g, err)
+    red, new_e = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                               out_specs=(P(), P()), check_vma=False)(g, err)
     np.testing.assert_allclose(np.asarray(red["w"]), [[1.0, -2.0, 3.0]],
                                atol=0.02)
 
