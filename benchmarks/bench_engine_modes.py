@@ -794,7 +794,7 @@ def bench_kernels(scale: float = 0.02, rows: int = 2048, runs: int = 5,
 
     from repro.core import ipgc
     from repro.core.policy import Timer, measure_launches
-    from repro.core.worklist import full_worklist
+    from repro.core.worklist import compact_items, full_worklist
     from repro.kernels import tune
     from repro.kernels.compact import compact_pallas
     from repro.kernels.fused_compact import fused_compact_pallas
@@ -837,9 +837,11 @@ def bench_kernels(scale: float = 0.02, rows: int = 2048, runs: int = 5,
 
         @jax.jit
         def one_launch():
-            return fused_compact_pallas(
-                *case, window, capacity=rows, n_sentinel=rows,
-                tile_rows=tuned_tile, interpret=interpret)
+            # the fused kernel, then the step's XLA compaction
+            new_c, new_b, need = fused_compact_pallas(
+                *case, window, tile_rows=tuned_tile, interpret=interpret)
+            items, count = compact_items(ids, need, rows, rows)
+            return new_c, new_b, need, items, count
 
         @jax.jit
         def separate():

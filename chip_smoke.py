@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """Smoke run of the coloring service on TPU chips, at published graph size.
 
-    python chip_smoke.py             # one chip: every phase below but "dist"
-    python chip_smoke.py --chips 4   # four chips: only the "dist" phase
+    python chip_smoke.py                # one chip: every phase but "dist"
+    python chip_smoke.py --pallas-full  # one chip: only "pallas", at 2^21
+    python chip_smoke.py --chips 4      # four chips: only the "dist" phase
 
 The graph is kron_g500-logn21 at its published size (2^21 nodes, Graph500
 edge factor 16), generated from a seed and built once. Phases, in one
@@ -16,10 +17,10 @@ process (a second process could not open a chip this one holds):
   pallas    layout "ell-tail", impl "pallas" against impl "jnp" on the
             same layout, bit-identical, for the fused and the two-phase
             step families; the lowered steps must hold the native kernel
-            (``tpu_custom_call``), so nothing runs interpreted. On the
-            same graph at 2^12 nodes: four more colorings at 2^21 would
-            not fit the run's 20 minutes (tests/test_tpu_compile.py
-            compiles every kernel for the chip at 2^21 rows)
+            (``tpu_custom_call``), so nothing runs interpreted. In the
+            default run on the same generator at 2^12 nodes, since four
+            more colorings at 2^21 do not fit its 20 minutes beside the
+            other phases; ``--pallas-full`` runs it alone at 2^21
   service   8 heavy-tail requests through Session.stream(...).serving(),
             each bit-identical to a solo Session.run
   dist      (--chips 4) the dist regime over 4 devices, exchange "dense"
@@ -129,6 +130,8 @@ def phase_pallas(g, devices, on_chip: bool) -> None:
         t = time.perf_counter()
         ref = sess.run(ExecutionSpec(impl="jnp", **spec), ig)
         t_ref = time.perf_counter() - t
+        emit("pallas-reference", fused=fused, impl="jnp", seconds=t_ref,
+             **summary(ref))
         t = time.perf_counter()
         got = sess.run(ExecutionSpec(impl="pallas", **spec), ig)
         t_got = time.perf_counter() - t
@@ -238,9 +241,13 @@ def phase_dist(g, devices) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--pallas-full", action="store_true",
+                    help="only the Pallas phase, on the 2^21-node graph")
     ap.add_argument("--rehearse", action="store_true",
                     help="small graph, any backend; prints no result")
     args = ap.parse_args()
+    if args.pallas_full and args.chips != 1:
+        ap.error("--pallas-full runs on one chip")
     if (args.rehearse and args.chips > 1
             and os.environ.get("JAX_PLATFORMS") == "cpu"):
         os.environ["XLA_FLAGS"] = (
@@ -279,6 +286,8 @@ def main() -> int:
     g = graph(SMALL_SCALE if args.rehearse else FULL_SCALE)
     if args.chips == 4:
         phase_dist(g, devices)
+    elif args.pallas_full:
+        phase_pallas(g, devices, on_chip=not args.rehearse)
     else:
         phase_host_outlined(g, devices)
         phase_pallas(graph(SMALL_SCALE), devices,

@@ -38,13 +38,15 @@ class IPGC(Algorithm):
                         thresh: int | None = None):
         # local import: distributed.py imports the engine (result type)
         from repro.core.distributed import (make_dist_dense_step,
-                                            make_dist_sparse_step)
+                                            make_dist_sparse_step,
+                                            shard_graph)
+        sg = shard_graph(ig_local, mesh, node_axes)
         dense = make_dist_dense_step(ig_local, mesh, node_axes,
                                      window=window, fused=fused,
                                      exchange=exchange, boundary=boundary,
-                                     thresh=thresh)
+                                     thresh=thresh, sg=sg)
         sparse = make_dist_sparse_step(ig_local, mesh, node_axes,
                                        window=window, fused=fused,
                                        exchange=exchange, boundary=boundary,
-                                       thresh=thresh)
+                                       thresh=thresh, sg=sg)
         return dense, sparse

@@ -60,15 +60,17 @@ class SpecGreedy(Algorithm):
                         fused: bool, exchange: str = "dense", boundary=None,
                         thresh: int | None = None):
         from repro.core.distributed import (make_dist_dense_step,
-                                            make_dist_sparse_step)
+                                            make_dist_sparse_step,
+                                            shard_graph)
+        sg = shard_graph(ig_local, mesh, node_axes)
         dense = make_dist_dense_step(ig_local, mesh, node_axes,
                                      window=window, fused=True,
                                      exchange=exchange, boundary=boundary,
-                                     thresh=thresh)
+                                     thresh=thresh, sg=sg)
         sparse = make_dist_sparse_step(ig_local, mesh, node_axes,
                                        window=window, fused=True,
                                        exchange=exchange, boundary=boundary,
-                                       thresh=thresh)
+                                       thresh=thresh, sg=sg)
         return dense, sparse
 
     def finalize(self, colors):

@@ -41,17 +41,20 @@ stays bit-identical to the host engine (tests/test_boundary.py).
 """
 from __future__ import annotations
 
+import dataclasses
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import ipgc
 from repro.core.engine import ColoringResult
 from repro.core.policy import Policy
-from repro.core.worklist import Worklist, compact_items, resize_block
+from repro.core.worklist import (Worklist, compact_items, compact_mask,
+                                 resize_block)
 from repro.graphs.csr import Graph, NO_COLOR
 from repro.obs.metrics import default_registry
 
@@ -175,15 +178,204 @@ def _shard_offset(mesh, node_axes: tuple):
     return idx
 
 
-def _local_graph_view(ig_local: ipgc.IPGCGraph, n: int, ell_l, deg_l,
-                      hubslot_l, prio, tail_src, tail_dst, tail_valid,
-                      tail_slot, hub_ids) -> ipgc.IPGCGraph:
-    """IPGCGraph over this shard's row block (tail/priority replicated)."""
+# ---------------------------------------------------------------------------
+# the graph operands of the sharded steps: ELL blocks + shard-local tails
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class ShardGraph:
+    """A prepared graph laid out for the sharded steps, placed on the mesh.
+
+    ELL rows are row-sharded and priorities replicated. The hub tail is
+    split by owner (owner computes): a hub's COO-tail entries live on the
+    shard that owns the hub row, so a step sweeps or packs only its own
+    entries, never the whole tail (50M entries at kron_g500-logn21
+    scale). Every shard's slice is padded to one length (pad entries
+    point at the sentinel ``n``, hold the inert slot ``n_hub`` and are
+    invalid) and to ``n_hub`` hub slots, the most any shard owns (spare
+    slots own empty ranges at the end). Hub slots are shard-local:
+    ``hub_slot[u]`` is u's slot on its owner shard (``n_hub`` for
+    non-hubs), replicated.
+
+    The arrays are jit *arguments* of the steps, placed once. Closed over
+    instead, jit would embed them in every step program as constants
+    (about a gigabyte at that scale) and copy them to the devices again
+    on every call.
+    """
+
+    n_hub: int
+    seg_bound: tuple      # ipgc.IPGCGraph.seg_bound over every shard
+    # (ell_idx, hub_slot, priority, (tail_src, tail_dst, tail_valid,
+    #  tail_slot, tail_start, hub_ids)); tail arrays shard-major
+    arrays: tuple
+    specs: tuple          # shard_map specs of ``arrays``
+
+
+def shard_graph(ig: ipgc.IPGCGraph, mesh, node_axes: tuple) -> ShardGraph:
+    """Lay a prepared graph out over the mesh's equal row blocks (as
+    ``prepare_partition`` numbers them)."""
+    n_hub, seg_bound, hub_slot, tails = split_tail(
+        ig, math.prod(mesh.shape[a] for a in node_axes))
+    na = node_axes
+    specs = (P(na, None), P(), P(), P(na))
+
+    def placed(spec, x):
+        return jax.device_put(x, NamedSharding(mesh, spec))
+    return ShardGraph(
+        n_hub=n_hub, seg_bound=seg_bound,
+        arrays=(placed(specs[0], ig.ell_idx), placed(P(), hub_slot),
+                placed(P(), ig.priority),
+                tuple(placed(specs[3], a) for a in tails)),
+        specs=specs)
+
+
+def split_tail(ig: ipgc.IPGCGraph, n_shards: int):
+    """The host-side split behind ``shard_graph``: ``(n_hub, seg_bound,
+    hub_slot, (tail_src, tail_dst, tail_valid, tail_slot, tail_start,
+    hub_ids))``, the tail arrays shard-major."""
+    n = ig.n_nodes
+    blk = n // n_shards
+    valid = np.asarray(ig.tail_valid)
+    src = np.asarray(ig.tail_src)[valid]          # ascending source rows
+    dst = np.asarray(ig.tail_dst)[valid]
+    hubs = np.asarray(ig.hub_ids)[:ig.n_hub]      # ascending hub rows
+    hub_shard = hubs // blk
+    per_shard = np.bincount(hub_shard, minlength=n_shards)
+    nh = int(per_shard.max()) if hubs.size else 0
+    first = np.concatenate([[0], np.cumsum(per_shard)[:-1]])
+    local = (np.arange(hubs.size) - first[hub_shard]).astype(np.int32)
+    hub_slot = np.full(n, nh, np.int32)
+    hub_slot[hubs] = local
+    hub_ids = np.repeat(np.arange(n_shards, dtype=np.int32) * blk,
+                        max(nh, 1))               # spare slots: any own row
+    hub_ids[hub_shard * max(nh, 1) + local] = hubs
+
+    e_shard = src // blk
+    e_count = np.bincount(e_shard, minlength=n_shards)
+    t = max(-(-int(e_count.max(initial=0)) // 8) * 8, 8)
+    e_first = np.concatenate([[0], np.cumsum(e_count)[:-1]])
+    pos = np.arange(src.size) - e_first[e_shard] + e_shard * t
+    slot = hub_slot[src]
+    tail_src = np.repeat(np.arange(n_shards, dtype=np.int32) * blk, t)
+    tail_dst = np.full(n_shards * t, n, np.int32)
+    tail_valid = np.zeros(n_shards * t, bool)
+    tail_slot = np.full(n_shards * t, nh, np.int32)
+    tail_src[pos], tail_dst[pos] = src, dst
+    tail_valid[pos], tail_slot[pos] = True, slot
+    bounds = np.concatenate([[0], np.cumsum(e_count)])
+    tail_start = np.concatenate([
+        np.searchsorted(slot[bounds[s]:bounds[s + 1]], np.arange(nh + 1))
+        for s in range(n_shards)]).astype(np.int32)
+
+    owned = np.bincount(src, minlength=n).reshape(n_shards, blk)
+    per = [ipgc._top_sums(o) for o in owned]
+    return (nh, tuple(max(b) for b in zip(*per)), hub_slot,
+            (tail_src, tail_dst, tail_valid, tail_slot, tail_start, hub_ids))
+
+
+def _bind(local_step, mesh, na: tuple, sg: ShardGraph, isb, *,
+          views: bool, sparse: bool):
+    """The jitted step around the shard_map'd ``local_step``; the graph
+    operands and boundary flags ride as arguments (see ``ShardGraph``)."""
+    cspec = P(na, None) if views else P()
+    in_specs = ((cspec, P(na), P(na)) + ((P(na),) if sparse else ())
+                + (P(na), sg.specs))
+    out_specs = (cspec, P(na), P(na), P(na), P()) + ((P(),) if views else ())
+    graph = sg.arrays
+    isb = jax.device_put(isb, NamedSharding(mesh, P(na)))
+
+    @partial(jax.jit, static_argnames=("bcap",))
+    def run(graph, isb, colors, base, wl: Worklist, bcap):
+        fn = jax.shard_map(partial(local_step, bcap=bcap), mesh=mesh,
+                           in_specs=in_specs, out_specs=out_specs,
+                           check_vma=False)
+        items = (wl.items,) if sparse else ()
+        outs = fn(colors, base, wl.mask, *items, isb, graph)
+        colors2, base2, mask, items, count = outs[:5]
+        return (colors2, base2,
+                Worklist(mask=mask, items=items, count=count)) + outs[5:]
+
+    if views:
+        def step(views_, base, wl: Worklist, *, bcap: int):
+            return run(graph, isb, views_, base, wl, bcap=bcap)
+    else:
+        def step(colors, base, wl: Worklist, *, bcap=None):
+            return run(graph, isb, colors, base, wl, bcap=None)
+    # the program for given state shapes, without running it
+    step.lower = lambda colors, base, wl, *, bcap=None: run.lower(
+        graph, isb, colors, base, wl, bcap=bcap)
+    return step
+
+
+def _local_graph_view(ig_local: ipgc.IPGCGraph, sg: ShardGraph, n: int,
+                      graph) -> ipgc.IPGCGraph:
+    """IPGCGraph over this shard's row block and its own hub tail."""
+    ell_l, hub_slot, prio, (tail_src, tail_dst, tail_valid, tail_slot,
+                            tail_start, hub_ids) = graph
     return ipgc.IPGCGraph(
-        n_nodes=n, ell_width=ig_local.ell_width, n_hub=ig_local.n_hub,
-        ell_idx=ell_l, degrees=deg_l, priority=prio,
+        n_nodes=n, ell_width=ig_local.ell_width, n_hub=sg.n_hub,
+        ell_idx=ell_l, degrees=jnp.zeros((0,), jnp.int32), priority=prio,
         tail_src=tail_src, tail_dst=tail_dst, tail_valid=tail_valid,
-        tail_slot=tail_slot, hub_slot=hubslot_l, hub_ids=hub_ids)
+        tail_slot=tail_slot, hub_slot=hub_slot, hub_ids=hub_ids,
+        tail_start=tail_start, seg_bound=sg.seg_bound)
+
+
+def _own_block(full: jax.Array, block: jax.Array, row_ids: jax.Array
+               ) -> jax.Array:
+    """``full`` with this shard's row block (``row_ids``, contiguous)
+    overwritten by ``block``: one slice update, not a scatter (an
+    unsorted scatter into a vector of millions takes the TPU compiler
+    seconds)."""
+    return jax.lax.dynamic_update_slice(full, block.astype(full.dtype),
+                                        (row_ids[0],))
+
+
+def _dense_hub_forbidden(ig: ipgc.IPGCGraph, colors, base_l, row_ids,
+                         window: int):
+    """(blk, W) tail forbidden bitmap of every owned row: a sweep of this
+    shard's tail."""
+    base_pad = _own_block(jnp.zeros((ig.n_nodes,), jnp.int32), base_l,
+                          row_ids)
+    return ipgc._hub_forbidden(ig, colors, base_pad, window)[
+        ig.hub_slot[row_ids]]
+
+
+def _dense_hub_lose(ig: ipgc.IPGCGraph, colors, flags, row_ids):
+    """(blk,) tail conflict flags of the owned rows flagged in ``flags``."""
+    full = _own_block(jnp.zeros((ig.n_nodes + 1,), bool), flags, row_ids)
+    return ipgc._hub_lose(ig, colors, full)[ig.hub_slot[row_ids]]
+
+
+def _sparse_hub(ig: ipgc.IPGCGraph, items_l, valid, base_rows, base_l,
+                row_ids, window: int):
+    """Hub-tail terms of a sparse step's rows, as ``(forbidden(colors),
+    lose(colors, flags, c_rows))``: packed over the tail entries of the
+    step's own hub rows when the bound allows (``ipgc._hub_packed``, as
+    the single-device sparse steps do), else a sweep of this shard's
+    tail. ``flags`` marks the rows that may lose, ``c_rows`` holds their
+    colors (negative elsewhere); both forms give the same bits."""
+    n, nh = ig.n_nodes, ig.n_hub
+    pk = ipgc._hub_packed(ig, items_l)
+    if pk is not None:
+        def forbidden(colors):
+            return ipgc._packed_forbidden(pk, colors[pk.dst], base_rows,
+                                          window)
+
+        def lose(colors, flags, c_rows):
+            return ipgc._packed_lose(pk, colors[pk.dst], c_rows, base_rows,
+                                     window) & valid
+        return forbidden, lose
+    slot_c = jnp.where(valid, ig.hub_slot[jnp.minimum(items_l, n - 1)], nh)
+    base_pad = _own_block(jnp.zeros((n,), jnp.int32), base_l, row_ids)
+
+    def forbidden(colors):
+        return ipgc._hub_forbidden(ig, colors, base_pad, window)[slot_c]
+
+    def lose(colors, flags, c_rows):
+        full = jnp.zeros((n + 1,), bool).at[
+            jnp.where(flags, items_l, n)].set(flags, mode="drop")
+        return ipgc._hub_lose(ig, colors, full)[slot_c] & valid
+    return forbidden, lose
 
 
 # ---------------------------------------------------------------------------
@@ -193,12 +385,13 @@ def _local_graph_view(ig_local: ipgc.IPGCGraph, n: int, ell_l, deg_l,
 def make_dist_dense_step(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
                          *, window: int = 128, n_global: int | None = None,
                          fused: bool = False, exchange: str = "dense",
-                         boundary=None, thresh: int | None = None):
+                         boundary=None, thresh: int | None = None,
+                      sg: "ShardGraph | None" = None):
     """Build a shard_map'd dense step.
 
     ig_local: the IPGCGraph whose per-shard row blocks will be fed in
-    (arrays sharded over ``node_axes`` on the row dim; `priority`,
-    tail arrays replicated).
+    (laid out over the mesh by ``shard_graph``; ``sg`` passes a layout
+    the caller already made, so a step pair shares one).
     Returns step(colors_global, base, wl) -> (colors_global, base, wl)
     where colors_global is the replicated int32[N+1] vector and
     base/mask/items are node-sharded.
@@ -220,207 +413,100 @@ def make_dist_dense_step(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
     threshold from ``policy.exchange_threshold``.
     """
     n = n_global or ig_local.n_nodes
+    if sg is None:
+        sg = shard_graph(ig_local, mesh, node_axes)
+    na = node_axes
+    views = exchange != "dense"
+    if views:
+        isb = boundary.is_boundary
+        th = int(thresh)
+    else:
+        isb = np.zeros((ig_local.n_nodes,), bool)     # unread
 
-    if exchange != "dense":
-        return _make_dense_boundary_step(
-            ig_local, mesh, node_axes, n=n, window=window, fused=fused,
-            boundary=boundary, thresh=thresh)
-
-    def local_step(colors, base_l, mask_l, ell_l, deg_l, hubslot_l,
-                   prio, tail_src, tail_dst, tail_valid, tail_slot, hub_ids):
+    def local_step(colors, base_l, mask_l, isb_l, graph, *, bcap=None):
         idx = _shard_offset(mesh, node_axes)
+        ell_l = graph[0]
         blk = ell_l.shape[0]
         row_ids = idx * blk + jnp.arange(blk, dtype=jnp.int32)
-        ig = _local_graph_view(ig_local, n, ell_l, deg_l, hubslot_l, prio,
-                               tail_src, tail_dst, tail_valid, tail_slot,
-                               hub_ids)
+        if views:
+            colors = colors[0]              # this shard's (n+1,) view
+            pub = partial(_publish_packed, n=n, node_axes=node_axes,
+                          idx=idx, blk=blk, bcap=bcap, thresh=th)
+        ig = _local_graph_view(ig_local, sg, n, graph)
+        hubs = sg.n_hub > 0
         active = mask_l
         nc = colors[ell_l]                              # local gather
-        slot_c = jnp.minimum(hubslot_l, ig_local.n_hub)
+        prio = ig.priority
 
         if fused:
             cu = colors[row_ids]
             pu = prio[row_ids]
             pending = active & (cu >= 0)
             npr = prio[ell_l]
-            if ig_local.n_hub > 0:
-                base_pad = jnp.zeros((n,), jnp.int32).at[row_ids].set(base_l)
-                extra = ipgc._hub_forbidden(ig, colors, base_pad,
-                                            window)[slot_c]
-                # only owned hub slots are read, and their tail_src rows are
-                # owned too — a local scatter of pending suffices (no psum)
-                pending_full = jnp.zeros((n + 1,), bool).at[row_ids].set(
-                    pending)
-                hub_lose = ipgc._hub_lose(ig, colors, pending_full)[slot_c]
-            else:
-                extra = None
-                hub_lose = None
+            extra = (_dense_hub_forbidden(ig, colors, base_l, row_ids,
+                                          window) if hubs else None)
             lose, first, has = ipgc._fused_rows(
                 ig, nc, npr, ell_l, base_l, cu, pu, row_ids, pending, extra,
                 window, "jnp")
-            if hub_lose is not None:
-                lose = lose | (hub_lose & pending)
+            if hubs:
+                # owned hub slots read owned tail_src rows only — a local
+                # scatter of pending suffices (no psum)
+                lose = lose | (_dense_hub_lose(ig, colors, pending, row_ids)
+                               & pending)
             need = lose | (active & (cu < 0))
             new_c = jnp.where(need & has, base_l + first,
                               jnp.where(lose, NO_COLOR, cu))
             new_base = jnp.where(need & ~has, base_l + window, base_l)
             # ONE exchange publishes speculated colors AND uncolorings
-            delta = jnp.zeros((n + 1,), jnp.int32).at[row_ids].set(new_c - cu)
-            colors_out = _exchange_colors(colors, delta, node_axes)
-            still = need
-        else:
-            # --- assign ---
-            if ig_local.n_hub > 0:
-                base_pad = jnp.zeros((n,), jnp.int32).at[row_ids].set(base_l)
-                hub_forb = ipgc._hub_forbidden(ig, colors, base_pad, window)
-                extra = hub_forb[slot_c]
+            if views:
+                colors_out, npk, mx = pub(colors, row_ids, cu, new_c, isb_l)
             else:
-                extra = None
-            new_c, new_base, newly = ipgc._mex_rows(
-                ig, nc, base_l, active, colors[row_ids], extra, window, "jnp")
-            # exchange 1: publish the speculative colors of owned rows
-            delta = jnp.zeros((n + 1,), jnp.int32).at[row_ids].set(
-                jnp.where(active, new_c, colors[row_ids]) - colors[row_ids])
-            colors2 = _exchange_colors(colors, delta, node_axes)
-            # --- resolve ---
-            lose = ipgc._lose_rows(ig, ell_l, row_ids, colors2, newly, "jnp")
-            if ig_local.n_hub > 0:
-                # local scatter: owned slots only read owned tail_src rows
-                newly_g = jnp.zeros((n + 1,), bool).at[row_ids].set(newly)
-                hub_l = ipgc._hub_lose(ig, colors2, newly_g)
-                lose = lose | hub_l[slot_c]
-            # exchange 2: uncolor losers (their writes were in colors2)
-            undo = jnp.zeros((n + 1,), jnp.int32).at[row_ids].set(
-                jnp.where(lose, NO_COLOR - colors2[row_ids], 0))
-            colors_out = _exchange_colors(colors2, undo, node_axes)
-            still = lose | (active & ~newly)
-
-        (items_l,) = jnp.nonzero(still, size=blk, fill_value=blk)
-        items_l = jnp.where(items_l < blk, idx * blk + items_l, n)
-        count = jax.lax.psum(still.sum(dtype=jnp.int32), node_axes)
-        return colors_out, new_base, still, items_l.astype(jnp.int32), count
-
-    na = node_axes
-    fn = jax.shard_map(
-        local_step, mesh=mesh,
-        in_specs=(P(), P(na), P(na), P(na, None), P(na), P(na),
-                  P(), P(), P(), P(), P(), P()),
-        out_specs=(P(), P(na), P(na), P(na), P()),
-        check_vma=False)
-
-    @jax.jit
-    def step(colors, base, wl: Worklist):
-        colors3, base2, mask, items, count = fn(
-            colors, base, wl.mask, ig_local.ell_idx, ig_local.degrees,
-            ig_local.hub_slot, ig_local.priority, ig_local.tail_src,
-            ig_local.tail_dst, ig_local.tail_valid, ig_local.tail_slot,
-            ig_local.hub_ids)
-        return colors3, base2, Worklist(mask=mask, items=items, count=count)
-
-    step.exchanges_per_iter = 1 if fused else 2
-    return step
-
-
-def _make_dense_boundary_step(ig_local: ipgc.IPGCGraph, mesh,
-                              node_axes: tuple, *, n: int, window: int,
-                              fused: bool, boundary, thresh: int):
-    """View-state variant of the dense step (see make_dist_dense_step)."""
-    isb = jnp.asarray(boundary.is_boundary)
-    th = int(thresh)
-    na = node_axes
-
-    def local_step(views_l, base_l, mask_l, isb_l, ell_l, deg_l, hubslot_l,
-                   prio, tail_src, tail_dst, tail_valid, tail_slot,
-                   hub_ids, *, bcap):
-        idx = _shard_offset(mesh, node_axes)
-        blk = ell_l.shape[0]
-        row_ids = idx * blk + jnp.arange(blk, dtype=jnp.int32)
-        colors = views_l[0]             # this shard's (n+1,) view
-        ig = _local_graph_view(ig_local, n, ell_l, deg_l, hubslot_l, prio,
-                               tail_src, tail_dst, tail_valid, tail_slot,
-                               hub_ids)
-        active = mask_l
-        nc = colors[ell_l]
-        slot_c = jnp.minimum(hubslot_l, ig_local.n_hub)
-        pub = partial(_publish_packed, n=n, node_axes=node_axes, idx=idx,
-                      blk=blk, bcap=bcap, thresh=th)
-
-        if fused:
-            cu = colors[row_ids]
-            pu = prio[row_ids]
-            pending = active & (cu >= 0)
-            npr = prio[ell_l]
-            if ig_local.n_hub > 0:
-                base_pad = jnp.zeros((n,), jnp.int32).at[row_ids].set(base_l)
-                extra = ipgc._hub_forbidden(ig, colors, base_pad,
-                                            window)[slot_c]
-                pending_full = jnp.zeros((n + 1,), bool).at[row_ids].set(
-                    pending)
-                hub_lose = ipgc._hub_lose(ig, colors, pending_full)[slot_c]
-            else:
-                extra = None
-                hub_lose = None
-            lose, first, has = ipgc._fused_rows(
-                ig, nc, npr, ell_l, base_l, cu, pu, row_ids, pending, extra,
-                window, "jnp")
-            if hub_lose is not None:
-                lose = lose | (hub_lose & pending)
-            need = lose | (active & (cu < 0))
-            new_c = jnp.where(need & has, base_l + first,
-                              jnp.where(lose, NO_COLOR, cu))
-            new_base = jnp.where(need & ~has, base_l + window, base_l)
-            colors_out, npk, mx = pub(colors, row_ids, cu, new_c, isb_l)
+                delta = _own_block(jnp.zeros((n + 1,), jnp.int32),
+                                   new_c - cu, row_ids)
+                colors_out = _exchange_colors(colors, delta, node_axes)
             still = need
         else:
             # --- assign ---
             cu0 = colors[row_ids]
-            if ig_local.n_hub > 0:
-                base_pad = jnp.zeros((n,), jnp.int32).at[row_ids].set(base_l)
-                hub_forb = ipgc._hub_forbidden(ig, colors, base_pad, window)
-                extra = hub_forb[slot_c]
-            else:
-                extra = None
+            extra = (_dense_hub_forbidden(ig, colors, base_l, row_ids,
+                                          window) if hubs else None)
             new_c, new_base, newly = ipgc._mex_rows(
                 ig, nc, base_l, active, cu0, extra, window, "jnp")
-            colors2, npk1, b1 = pub(colors, row_ids, cu0,
-                                    jnp.where(active, new_c, cu0), isb_l)
+            # exchange 1: publish the speculative colors of owned rows
+            spec_c = jnp.where(active, new_c, cu0)
+            if views:
+                colors2, npk1, b1 = pub(colors, row_ids, cu0, spec_c, isb_l)
+            else:
+                delta = _own_block(jnp.zeros((n + 1,), jnp.int32),
+                                   spec_c - cu0, row_ids)
+                colors2 = _exchange_colors(colors, delta, node_axes)
             # --- resolve ---
             lose = ipgc._lose_rows(ig, ell_l, row_ids, colors2, newly, "jnp")
-            if ig_local.n_hub > 0:
-                newly_g = jnp.zeros((n + 1,), bool).at[row_ids].set(newly)
-                hub_l = ipgc._hub_lose(ig, colors2, newly_g)
-                lose = lose | hub_l[slot_c]
+            if hubs:
+                lose = lose | _dense_hub_lose(ig, colors2, newly, row_ids)
+            # exchange 2: uncolor losers (their writes were in colors2)
             c2r = colors2[row_ids]
-            colors_out, npk2, b2 = pub(colors2, row_ids, c2r,
-                                       jnp.where(lose, NO_COLOR, c2r), isb_l)
+            if views:
+                colors_out, npk2, b2 = pub(colors2, row_ids, c2r,
+                                           jnp.where(lose, NO_COLOR, c2r),
+                                           isb_l)
+                npk, mx = npk1 + npk2, jnp.maximum(b1, b2)
+            else:
+                undo = _own_block(jnp.zeros((n + 1,), jnp.int32),
+                                  jnp.where(lose, NO_COLOR - c2r, 0), row_ids)
+                colors_out = _exchange_colors(colors2, undo, node_axes)
             still = lose | (active & ~newly)
-            npk = npk1 + npk2
-            mx = jnp.maximum(b1, b2)
 
-        (items_l,) = jnp.nonzero(still, size=blk, fill_value=blk)
+        items_l, _ = compact_mask(still, blk, blk)
         items_l = jnp.where(items_l < blk, idx * blk + items_l, n)
         count = jax.lax.psum(still.sum(dtype=jnp.int32), node_axes)
-        xstats = jnp.stack([npk, mx]).astype(jnp.int32)
-        return (colors_out[None], new_base, still, items_l.astype(jnp.int32),
-                count, xstats)
+        out = (colors_out, new_base, still, items_l.astype(jnp.int32), count)
+        if views:
+            return (colors_out[None],) + out[1:] + (
+                jnp.stack([npk, mx]).astype(jnp.int32),)
+        return out
 
-    in_specs = (P(na, None), P(na), P(na), P(na), P(na, None), P(na), P(na),
-                P(), P(), P(), P(), P(), P())
-    out_specs = (P(na, None), P(na), P(na), P(na), P(), P())
-
-    @partial(jax.jit, static_argnames=("bcap",))
-    def step(views, base, wl: Worklist, *, bcap: int):
-        fn = jax.shard_map(partial(local_step, bcap=bcap), mesh=mesh,
-                           in_specs=in_specs, out_specs=out_specs,
-                           check_vma=False)
-        views2, base2, mask, items, count, xstats = fn(
-            views, base, wl.mask, isb, ig_local.ell_idx, ig_local.degrees,
-            ig_local.hub_slot, ig_local.priority, ig_local.tail_src,
-            ig_local.tail_dst, ig_local.tail_valid, ig_local.tail_slot,
-            ig_local.hub_ids)
-        return (views2, base2, Worklist(mask=mask, items=items, count=count),
-                xstats)
-
+    step = _bind(local_step, mesh, na, sg, isb, views=views, sparse=False)
     step.exchanges_per_iter = 1 if fused else 2
     return step
 
@@ -432,13 +518,15 @@ def _make_dense_boundary_step(ig_local: ipgc.IPGCGraph, mesh,
 def make_dist_sparse_step(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
                           *, window: int = 128, n_global: int | None = None,
                           fused: bool = False, exchange: str = "dense",
-                          boundary=None, thresh: int | None = None):
+                          boundary=None, thresh: int | None = None,
+                       sg: "ShardGraph | None" = None):
     """Build a shard_map'd data-driven step over shard-local worklists.
 
     Each shard gathers only its own compacted items block (global node ids
     it owns, padded with N), so per-iteration cost tracks the shard's
-    active-set slice, not its block size. The color exchange is the same
-    additive all-gather as the dense step; the worklist filter
+    active-set slice, not its block size; its hub rows read only their
+    own tail entries (packed, ``_sparse_hub``). The color exchange is the
+    same additive all-gather as the dense step; the worklist filter
     (``compact_items``) and the ``mask`` write-back stay O(C) and
     shard-local. The returned ``step(colors, base, wl)`` expects
     ``wl.items`` of global shape ``n_shards * C`` (per-shard blocks) and
@@ -449,81 +537,99 @@ def make_dist_sparse_step(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
     extra ``xstats`` output).
     """
     n = n_global or ig_local.n_nodes
+    if sg is None:
+        sg = shard_graph(ig_local, mesh, node_axes)
+    na = node_axes
+    views = exchange != "dense"
+    if views:
+        isb = boundary.is_boundary
+        th = int(thresh)
+    else:
+        isb = np.zeros((ig_local.n_nodes,), bool)     # unread
 
-    if exchange != "dense":
-        return _make_sparse_boundary_step(
-            ig_local, mesh, node_axes, n=n, window=window, fused=fused,
-            boundary=boundary, thresh=thresh)
-
-    def local_step(colors, base_l, mask_l, items_l, ell_l, deg_l, hubslot_l,
-                   prio, tail_src, tail_dst, tail_valid, tail_slot, hub_ids):
+    def local_step(colors, base_l, mask_l, items_l, isb_l, graph, *,
+                   bcap=None):
         idx = _shard_offset(mesh, node_axes)
+        ell_l = graph[0]
         blk = ell_l.shape[0]
         row_ids = idx * blk + jnp.arange(blk, dtype=jnp.int32)
-        ig = _local_graph_view(ig_local, n, ell_l, deg_l, hubslot_l, prio,
-                               tail_src, tail_dst, tail_valid, tail_slot,
-                               hub_ids)
+        if views:
+            colors = colors[0]
+            pub = partial(_publish_packed, n=n, node_axes=node_axes,
+                          idx=idx, blk=blk, bcap=bcap, thresh=th)
+        ig = _local_graph_view(ig_local, sg, n, graph)
+        hubs = sg.n_hub > 0
+        prio = ig.priority
         valid = items_l < n
         # local row index of each owned item (this shard only ever holds
         # ids from its own block; clip guards the pad lanes)
         local = jnp.clip(jnp.where(valid, items_l - idx * blk, 0), 0, blk - 1)
         ids = jnp.where(valid, items_l, n)              # global ids, pad n
+        if views:
+            isb_items = valid & isb_l[local]
         ell_rows = jnp.where(valid[:, None], ell_l[local], n)    # (C, K)
         nc = colors[ell_rows]
         base_rows = base_l[local]
         cu = colors[ids]                                # pad -> PAD_COLOR
-        if ig_local.n_hub > 0:
-            base_pad = jnp.zeros((n,), jnp.int32).at[row_ids].set(base_l)
-            hub_forb = ipgc._hub_forbidden(ig, colors, base_pad, window)
-            slot_c = jnp.minimum(jnp.where(valid, hubslot_l[local],
-                                           ig_local.n_hub), ig_local.n_hub)
-            extra = hub_forb[slot_c]
-        else:
-            slot_c = None
-            extra = None
+        if hubs:
+            hub_forbidden, hub_lose = _sparse_hub(
+                ig, items_l, valid, base_rows, base_l, row_ids, window)
 
         if fused:
             pu = prio[ids]
             npr = prio[ell_rows]
             pending = valid & (cu >= 0)
-            if ig_local.n_hub > 0:
-                pending_full = jnp.zeros((n + 1,), bool).at[
-                    jnp.where(pending, items_l, n)].set(pending, mode="drop")
-                hub_lose = (ipgc._hub_lose(ig, colors, pending_full)[slot_c]
-                            & valid)
-            else:
-                hub_lose = None
+            extra = hub_forbidden(colors) if hubs else None
             lose, first, has = ipgc._fused_rows(
                 ig, nc, npr, ell_rows, base_rows, cu, pu, ids, pending,
                 extra, window, "jnp")
-            if hub_lose is not None:
-                lose = lose | (hub_lose & pending)
+            if hubs:
+                lose = lose | (hub_lose(colors, pending,
+                                        jnp.where(pending, cu, NO_COLOR))
+                               & pending)
             need = lose | (valid & (cu < 0))
             new_c = jnp.where(need & has, base_rows + first,
                               jnp.where(lose, NO_COLOR, cu))
             new_base_rows = jnp.where(need & ~has, base_rows + window,
                                       base_rows)
             # ONE exchange (pad lanes contribute delta 0 at the sentinel)
-            delta = jnp.zeros((n + 1,), jnp.int32).at[ids].set(new_c - cu)
-            colors_out = _exchange_colors(colors, delta, node_axes)
+            if views:
+                colors_out, npk, mx = pub(colors, ids, cu,
+                                          jnp.where(valid, new_c, cu),
+                                          isb_items)
+            else:
+                delta = jnp.zeros((n + 1,), jnp.int32).at[ids].set(
+                    new_c - cu)
+                colors_out = _exchange_colors(colors, delta, node_axes)
             still = need
         else:
             # --- assign ---
+            extra = hub_forbidden(colors) if hubs else None
             new_c, new_base_rows, newly = ipgc._mex_rows(
                 ig, nc, base_rows, valid, cu, extra, window, "jnp")
-            delta = jnp.zeros((n + 1,), jnp.int32).at[ids].set(
-                jnp.where(valid, new_c - cu, 0))
-            colors2 = _exchange_colors(colors, delta, node_axes)
+            if views:
+                colors2, npk1, b1 = pub(colors, ids, cu,
+                                        jnp.where(valid, new_c, cu),
+                                        isb_items)
+            else:
+                delta = jnp.zeros((n + 1,), jnp.int32).at[ids].set(
+                    jnp.where(valid, new_c - cu, 0))
+                colors2 = _exchange_colors(colors, delta, node_axes)
             # --- resolve ---
             lose = ipgc._lose_rows(ig, ell_rows, ids, colors2, newly, "jnp")
-            if ig_local.n_hub > 0:
-                newly_full = jnp.zeros((n + 1,), bool).at[
-                    jnp.where(newly, items_l, n)].set(newly, mode="drop")
-                hub_l = ipgc._hub_lose(ig, colors2, newly_full)
-                lose = lose | (hub_l[slot_c] & valid)
-            undo = jnp.zeros((n + 1,), jnp.int32).at[ids].set(
-                jnp.where(lose, NO_COLOR - colors2[ids], 0))
-            colors_out = _exchange_colors(colors2, undo, node_axes)
+            if hubs:
+                lose = lose | hub_lose(colors2, newly,
+                                       jnp.where(newly, new_c, NO_COLOR))
+            c2 = colors2[ids]
+            if views:
+                colors_out, npk2, b2 = pub(colors2, ids, c2,
+                                           jnp.where(lose, NO_COLOR, c2),
+                                           isb_items)
+                npk, mx = npk1 + npk2, jnp.maximum(b1, b2)
+            else:
+                undo = jnp.zeros((n + 1,), jnp.int32).at[ids].set(
+                    jnp.where(lose, NO_COLOR - c2, 0))
+                colors_out = _exchange_colors(colors2, undo, node_axes)
             still = lose | (valid & ~newly)
 
         # --- maintain the worklist in O(C), shard-local ---
@@ -533,138 +639,12 @@ def make_dist_sparse_step(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
         base2 = base_l.at[jnp.where(valid, local, blk)].set(new_base_rows,
                                                             mode="drop")
         count = jax.lax.psum(local_count, node_axes)
+        if views:
+            xstats = jnp.stack([npk, mx]).astype(jnp.int32)
+            return colors_out[None], base2, mask2, new_items, count, xstats
         return colors_out, base2, mask2, new_items, count
 
-    na = node_axes
-    fn = jax.shard_map(
-        local_step, mesh=mesh,
-        in_specs=(P(), P(na), P(na), P(na), P(na, None), P(na), P(na),
-                  P(), P(), P(), P(), P(), P()),
-        out_specs=(P(), P(na), P(na), P(na), P()),
-        check_vma=False)
-
-    @jax.jit
-    def step(colors, base, wl: Worklist):
-        colors3, base2, mask, items, count = fn(
-            colors, base, wl.mask, wl.items, ig_local.ell_idx,
-            ig_local.degrees, ig_local.hub_slot, ig_local.priority,
-            ig_local.tail_src, ig_local.tail_dst, ig_local.tail_valid,
-            ig_local.tail_slot, ig_local.hub_ids)
-        return colors3, base2, Worklist(mask=mask, items=items, count=count)
-
-    step.exchanges_per_iter = 1 if fused else 2
-    return step
-
-
-def _make_sparse_boundary_step(ig_local: ipgc.IPGCGraph, mesh,
-                               node_axes: tuple, *, n: int, window: int,
-                               fused: bool, boundary, thresh: int):
-    """View-state variant of the sparse step (see make_dist_sparse_step)."""
-    isb = jnp.asarray(boundary.is_boundary)
-    th = int(thresh)
-    na = node_axes
-
-    def local_step(views_l, base_l, mask_l, items_l, isb_l, ell_l, deg_l,
-                   hubslot_l, prio, tail_src, tail_dst, tail_valid,
-                   tail_slot, hub_ids, *, bcap):
-        idx = _shard_offset(mesh, node_axes)
-        blk = ell_l.shape[0]
-        row_ids = idx * blk + jnp.arange(blk, dtype=jnp.int32)
-        colors = views_l[0]
-        ig = _local_graph_view(ig_local, n, ell_l, deg_l, hubslot_l, prio,
-                               tail_src, tail_dst, tail_valid, tail_slot,
-                               hub_ids)
-        valid = items_l < n
-        local = jnp.clip(jnp.where(valid, items_l - idx * blk, 0), 0, blk - 1)
-        ids = jnp.where(valid, items_l, n)
-        isb_items = valid & isb_l[local]
-        ell_rows = jnp.where(valid[:, None], ell_l[local], n)
-        nc = colors[ell_rows]
-        base_rows = base_l[local]
-        cu = colors[ids]
-        pub = partial(_publish_packed, n=n, node_axes=node_axes, idx=idx,
-                      blk=blk, bcap=bcap, thresh=th)
-        if ig_local.n_hub > 0:
-            base_pad = jnp.zeros((n,), jnp.int32).at[row_ids].set(base_l)
-            hub_forb = ipgc._hub_forbidden(ig, colors, base_pad, window)
-            slot_c = jnp.minimum(jnp.where(valid, hubslot_l[local],
-                                           ig_local.n_hub), ig_local.n_hub)
-            extra = hub_forb[slot_c]
-        else:
-            slot_c = None
-            extra = None
-
-        if fused:
-            pu = prio[ids]
-            npr = prio[ell_rows]
-            pending = valid & (cu >= 0)
-            if ig_local.n_hub > 0:
-                pending_full = jnp.zeros((n + 1,), bool).at[
-                    jnp.where(pending, items_l, n)].set(pending, mode="drop")
-                hub_lose = (ipgc._hub_lose(ig, colors, pending_full)[slot_c]
-                            & valid)
-            else:
-                hub_lose = None
-            lose, first, has = ipgc._fused_rows(
-                ig, nc, npr, ell_rows, base_rows, cu, pu, ids, pending,
-                extra, window, "jnp")
-            if hub_lose is not None:
-                lose = lose | (hub_lose & pending)
-            need = lose | (valid & (cu < 0))
-            new_c = jnp.where(need & has, base_rows + first,
-                              jnp.where(lose, NO_COLOR, cu))
-            new_base_rows = jnp.where(need & ~has, base_rows + window,
-                                      base_rows)
-            colors_out, npk, mx = pub(colors, ids, cu,
-                                      jnp.where(valid, new_c, cu), isb_items)
-            still = need
-        else:
-            # --- assign ---
-            new_c, new_base_rows, newly = ipgc._mex_rows(
-                ig, nc, base_rows, valid, cu, extra, window, "jnp")
-            colors2, npk1, b1 = pub(colors, ids, cu,
-                                    jnp.where(valid, new_c, cu), isb_items)
-            # --- resolve ---
-            lose = ipgc._lose_rows(ig, ell_rows, ids, colors2, newly, "jnp")
-            if ig_local.n_hub > 0:
-                newly_full = jnp.zeros((n + 1,), bool).at[
-                    jnp.where(newly, items_l, n)].set(newly, mode="drop")
-                hub_l = ipgc._hub_lose(ig, colors2, newly_full)
-                lose = lose | (hub_l[slot_c] & valid)
-            c2 = colors2[ids]
-            colors_out, npk2, b2 = pub(colors2, ids, c2,
-                                       jnp.where(lose, NO_COLOR, c2),
-                                       isb_items)
-            still = lose | (valid & ~newly)
-            npk = npk1 + npk2
-            mx = jnp.maximum(b1, b2)
-
-        new_items, local_count = compact_items(items_l, still, n)
-        mask2 = mask_l.at[jnp.where(valid, local, blk)].set(still,
-                                                            mode="drop")
-        base2 = base_l.at[jnp.where(valid, local, blk)].set(new_base_rows,
-                                                            mode="drop")
-        count = jax.lax.psum(local_count, node_axes)
-        xstats = jnp.stack([npk, mx]).astype(jnp.int32)
-        return colors_out[None], base2, mask2, new_items, count, xstats
-
-    in_specs = (P(na, None), P(na), P(na), P(na), P(na), P(na, None), P(na),
-                P(na), P(), P(), P(), P(), P(), P())
-    out_specs = (P(na, None), P(na), P(na), P(na), P(), P())
-
-    @partial(jax.jit, static_argnames=("bcap",))
-    def step(views, base, wl: Worklist, *, bcap: int):
-        fn = jax.shard_map(partial(local_step, bcap=bcap), mesh=mesh,
-                           in_specs=in_specs, out_specs=out_specs,
-                           check_vma=False)
-        views2, base2, mask, items, count, xstats = fn(
-            views, base, wl.mask, wl.items, isb, ig_local.ell_idx,
-            ig_local.degrees, ig_local.hub_slot, ig_local.priority,
-            ig_local.tail_src, ig_local.tail_dst, ig_local.tail_valid,
-            ig_local.tail_slot, ig_local.hub_ids)
-        return (views2, base2, Worklist(mask=mask, items=items, count=count),
-                xstats)
-
+    step = _bind(local_step, mesh, na, sg, isb, views=views, sparse=True)
     step.exchanges_per_iter = 1 if fused else 2
     return step
 

@@ -69,6 +69,9 @@ class IPGCGraph:
     tail_slot: jax.Array      # i32[T] hub slot of tail_src
     hub_slot: jax.Array       # i32[N], n_hub for non-hub nodes
     hub_ids: jax.Array        # i32[max(n_hub,1)]
+    # segment index of the tail: hub slot h owns tail entries
+    # [tail_start[h], tail_start[h+1])
+    tail_start: jax.Array     # i32[n_hub+1]
     # layout dispatch (static) + csr-segment edge arrays (None elsewhere)
     layout_kind: str = dataclasses.field(default="ell-tail",
                                          metadata=dict(static=True))
@@ -77,12 +80,10 @@ class IPGCGraph:
     # (csr_segment.WINS_BIT) set where it wins the (priority, id)
     # tie-break against the source — static, so no step gathers priorities
     edge_dst: jax.Array | None = None
-    # segment index of the tail (hub h owns tail entries
-    # [tail_start[h], tail_start[h+1])); None = derive from tail_slot
-    tail_start: jax.Array | None = None  # i32[n_hub+1]
-    # static bound for the packed sparse passes: seg_bound[k] is the most
-    # entries (csr edges / tail entries) any 2^k rows own; () = no bound,
-    # sparse steps sweep every entry
+    # static bound for the packed sparse passes: seg_bound[k] bounds the
+    # entries (csr edges / tail entries) any 2^k rows own, rounded up a
+    # coarse ladder (``_top_sums``); () = no bound, sparse steps sweep
+    # every entry
     seg_bound: tuple = dataclasses.field(default=(),
                                          metadata=dict(static=True))
 
@@ -142,22 +143,37 @@ def prepare(g: Graph, *, priority: str = "hash", plan=None) -> IPGCGraph:
         tail_slot=jnp.asarray(hub_slot[tail_src_safe]),
         hub_slot=jnp.asarray(hub_slot),
         hub_ids=jnp.asarray(hub_ids if n_hub else np.zeros(1, np.int32)),
+        tail_start=jnp.asarray(tail_start),
         layout_kind=kind,
         edge_src=edge_src,
         edge_dst=edge_dst,
-        tail_start=jnp.asarray(tail_start),
         seg_bound=_top_sums(owned),
     )
 
 
 def _top_sums(owned: np.ndarray) -> tuple:
-    """``out[k]`` = the most entries any ``2^k`` rows own, for
-    ``2^k < 2 * len(owned)`` (the packed sparse passes' static bound)."""
+    """``out[k]`` >= the most entries any ``2^k`` rows own, for
+    ``2^k < 2 * len(owned)`` (the packed sparse passes' static bound).
+
+    Each bound is rounded up to the ladder ``{8, ..., 15} * 2^j`` (at
+    most 12.5% above the exact sum, so a packed pass carries little
+    slack): the bound is a static step argument, so graphs whose arrays
+    share shapes and whose bounds share rungs share compiled steps;
+    exact sums would give nearly every graph its own programs."""
     top = np.cumsum(np.sort(np.asarray(owned, np.int64))[::-1])
     if top.size == 0:
         return ()
     ks = range(max(top.size - 1, 0).bit_length() + 1)
-    return tuple(int(top[min(2 ** k, top.size) - 1]) for k in ks)
+    return tuple(_ladder(int(top[min(2 ** k, top.size) - 1])) for k in ks)
+
+
+def _ladder(x: int) -> int:
+    """Smallest ``{8, ..., 15} * 2^j`` (x itself up to 16) >= ``x``: the
+    same power-of-two ceiling, so the same number of doubling passes."""
+    if x <= 16:
+        return x
+    j = x.bit_length() - 4
+    return -(-x // 2 ** j) * 2 ** j
 
 
 def pad_prepared(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
@@ -210,11 +226,13 @@ def pad_prepared(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
                        (0, n_pad - n), constant_values=nh_pad)
     hub_ids = jnp.pad(ig.hub_ids,
                       (0, max(nh_pad, 1) - ig.hub_ids.shape[0]))
+    # extra hub slots own empty ranges at the end of the real entries
+    tail_start = jnp.pad(ig.tail_start, (0, nh_pad - nh), mode="edge")
     return IPGCGraph(
         n_nodes=n_pad, ell_width=k_pad, n_hub=nh_pad, ell_idx=ell,
         degrees=deg, priority=prio, tail_src=tail_src, tail_dst=tail_dst,
         tail_valid=tail_valid, tail_slot=tail_slot, hub_slot=hub_slot,
-        hub_ids=hub_ids, layout_kind=ig.layout_kind)
+        hub_ids=hub_ids, tail_start=tail_start, layout_kind=ig.layout_kind)
 
 
 # Read the env var ONCE at import (it used to be re-read on every trace);
@@ -353,10 +371,6 @@ def _max_run(ig: IPGCGraph) -> int:
 def _tail_segments(ig: IPGCGraph) -> tuple[jax.Array, jax.Array]:
     """(first tail entry, owns-an-entry) per hub slot ``0..n_hub-1``."""
     ts = ig.tail_start
-    if ts is None:
-        ts = jnp.searchsorted(ig.tail_slot,
-                              jnp.arange(ig.n_hub + 1, dtype=jnp.int32))
-        ts = ts.astype(jnp.int32)
     return ts[:-1], ts[1:] > ts[:-1]
 
 
@@ -639,8 +653,6 @@ def _packed_lose(pk: _Packed, cv: jax.Array, c_rows: jax.Array,
 def _hub_packed(ig: IPGCGraph, items: jax.Array) -> "_Packed | None":
     """Pack the tail entries of the hub rows in ``items``."""
     ts, nh = ig.tail_start, ig.n_hub
-    if ts is None:
-        return None
 
     def slot(rows):
         return jnp.minimum(ig.hub_slot[rows], nh)
@@ -869,35 +881,35 @@ def _fused_compact_rows(ig: IPGCGraph, nc: jax.Array, npr: jax.Array,
                                    jax.Array, jax.Array]:
     """ONE-pass row-wise core (DESIGN.md §10): resolve + windowed mex +
     new-color/base selection in a single kernel, then the compacted
-    worklist emission. ``ids`` is the emitted value, so the dense caller
+    worklist emission (XLA's, after the kernel, for both impls). ``ids`` is the emitted value, so the dense caller
     passes row iota (emission == ``compact_mask``) and the sparse caller
     its items block (emission == ``compact_items``). Returns
     ``(new_colors, new_base, still, items, count)``.
     """
     LAUNCH_COUNTS["fused"] += 1
-    n = ig.n_nodes
     if impl == "pallas":
         from repro.kernels import ops as kops
-        return kops.fused_compact(nc, npr, nbr_ids, base_rows, cu, pu, ids,
-                                  active, pending, extra_forb, hub_lose,
-                                  window, capacity=capacity, n_sentinel=n,
-                                  tile_rows=tile_rows)
-    lose = _conflict_rows(nc, npr, nbr_ids, cu, pu, ids) & pending
-    if hub_lose is not None:
-        lose = lose | (hub_lose & pending)
-    forb = _ell_forbidden(nc, base_rows, window)
-    if extra_forb is not None:
-        forb = forb | extra_forb
-    free = ~forb
-    has = free.any(axis=1)
-    first = jnp.argmax(free, axis=1).astype(jnp.int32)
-    need = lose | (active & (cu < 0))
-    new_c = jnp.where(need & has, base_rows + first,
-                      jnp.where(lose, NO_COLOR, cu))
-    new_base = jnp.where(need & ~has, base_rows + window, base_rows)
+        new_c, new_base, need = kops.fused_compact(
+            nc, npr, nbr_ids, base_rows, cu, pu, ids, active, pending,
+            extra_forb, hub_lose, window, tile_rows=tile_rows)
+    else:
+        lose = _conflict_rows(nc, npr, nbr_ids, cu, pu, ids) & pending
+        if hub_lose is not None:
+            lose = lose | (hub_lose & pending)
+        forb = _ell_forbidden(nc, base_rows, window)
+        if extra_forb is not None:
+            forb = forb | extra_forb
+        free = ~forb
+        has = free.any(axis=1)
+        first = jnp.argmax(free, axis=1).astype(jnp.int32)
+        need = lose | (active & (cu < 0))
+        new_c = jnp.where(need & has, base_rows + first,
+                          jnp.where(lose, NO_COLOR, cu))
+        new_base = jnp.where(need & ~has, base_rows + window, base_rows)
     # emission — bit-identical to compact_mask/compact_items over
     # ``need``: surviving ids ascending, sentinel-n tail, count = popcount
-    items, count = compact_items(ids.astype(jnp.int32), need, n, capacity)
+    items, count = compact_items(ids.astype(jnp.int32), need, ig.n_nodes,
+                                 capacity)
     return new_c, new_base, need, items, count
 
 

@@ -130,6 +130,7 @@ def empty_lane(sc: ShapeClass) -> ipgc.IPGCGraph:
         tail_slot=jnp.full((sc.t_pad,), sc.nh_pad, jnp.int32),
         hub_slot=jnp.full((sc.n_pad,), sc.nh_pad, jnp.int32),
         hub_ids=jnp.zeros((max(sc.nh_pad, 1),), jnp.int32),
+        tail_start=jnp.zeros((sc.nh_pad + 1,), jnp.int32),
         layout_kind=sc.kind)
 
 

@@ -187,9 +187,12 @@ def edge_conflict(es: jax.Array, cv_e: jax.Array, wins_e: jax.Array,
     wins the tie-break (``wins_e``) holds it too — ``lose_flags``
     segment-ORed per row, evaluated as one bit of the window bitmap of
     the winners' colors. Valid for rows whose color lies in their window
-    ``[base, base + window)`` (or is negative: they cannot lose), which
-    holds for every row a step may uncolor: it picked that color from
-    that window."""
+    ``[base, base + window)`` (or is negative: they cannot lose).
+
+    Invariant the steps keep: every colored row's color lies in its
+    window, because base advances only for rows left uncolored. A step
+    that moved a colored row's base would make this test miss conflicts
+    (tests/test_kernels.py::test_colored_rows_stay_in_window)."""
     win_c = jnp.where(wins_e, cv_e, -1)
     words = row_windows(window_words(win_c, base_src, window), es,
                         row_start, row_deg > 0, max_run)

@@ -20,18 +20,19 @@ per (TILE_R,)-row grid step —
      need``) leave as one ``(TILE_R, 3)`` output block.
 
 The compaction of the survivors into the worklist runs after the kernel,
-as the same ``worklist.compact_items`` the jnp path uses. An in-kernel
+in the caller (``ipgc._fused_compact_rows``), as the same
+``worklist.compact_items`` the jnp path uses. An in-kernel
 compaction needs each tile to store at a running offset, and Mosaic
 refuses a store into VMEM at an offset it cannot prove aligned; holding
 the whole items array in VMEM instead does not fit at real graph sizes.
 Per-tile compacted blocks would still need an XLA prefix sum and scatter
-to stitch, which is the work ``jnp.nonzero`` does on the survivor mask.
+to stitch, which is the work ``compact_items`` does on the survivor mask.
 
-The emitted value is the row's ``ids`` input (not a computed global
-index), so ONE kernel serves both worklist forms: the dense step passes
-``ids = iota(N)`` (emission == ``worklist.compact_mask``) and the sparse
-step passes its items block (emission == ``worklist.compact_items`` —
-invalid rows have ``active = False`` and can never emit).
+The caller emits the row's ``ids`` input (not a computed global index),
+so ONE kernel serves both worklist forms: the dense step passes ``ids =
+iota(N)`` (emission == ``worklist.compact_mask``) and the sparse step
+passes its items block (emission == ``worklist.compact_items`` — invalid
+rows have ``active = False`` and never survive).
 
 Grid specialisation by layout kind (DESIGN.md §10): pure-ell graphs call
 the no-hub variant (hub operands compiled out entirely, mirroring the
@@ -49,7 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.worklist import compact_items
 from repro.kernels.rowtile import first_free, lanes, loses, slab
 
 
@@ -88,17 +88,14 @@ def fused_compact_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
                          pending: jax.Array,
                          extra_forb: jax.Array | None,
                          hub_lose: jax.Array | None, window: int, *,
-                         capacity: int, n_sentinel: int,
                          tile_rows: int = 32, no_color: int = -1,
                          interpret: bool = False
-                         ) -> tuple[jax.Array, jax.Array, jax.Array,
-                                    jax.Array, jax.Array]:
-    """Fused step kernel + worklist emission over R rows.
+                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Fused step kernel over R rows.
 
     nc/npr/nbr_ids: (R, K) int32 neighbour color/priority/id tiles
     base:           (R,)  int32 window base per row
-    cu/pu/ids:      (R,)  int32 own color / priority / id (``ids`` is the
-                    value emitted into the compacted worklist)
+    cu/pu/ids:      (R,)  int32 own color / priority / id
     active:         (R,)  bool  row is in the worklist (valid, for sparse)
     pending:        (R,)  bool  speculated-last-round flag
     extra_forb:     (R, W) bool hub-tail forbidden bitmap, or None (the
@@ -106,10 +103,9 @@ def fused_compact_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
     hub_lose:       (R,)  bool hub-tail conflict flags, or None with
                     ``extra_forb``
 
-    Returns ``(new_colors (R,), new_base (R,), still bool(R,),
-    items int32(capacity,) padded with n_sentinel, count int32[])`` —
-    bit-identical to the jnp fused step followed by
-    ``worklist.compact_mask``/``compact_items`` over ``still``.
+    Returns ``(new_colors (R,), new_base (R,), still bool(R,))`` —
+    bit-identical to the jnp fused step; the survivors ``still`` are the
+    rows the caller compacts into the next worklist.
     """
     r, k = nc.shape
     with_hub = extra_forb is not None
@@ -146,7 +142,4 @@ def fused_compact_pallas(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
         out_shape=jax.ShapeDtypeStruct((rp, 3), jnp.int32),
         interpret=interpret,
     )(*operands)
-    still = out[:r, 2] != 0
-    items, count = compact_items(ids.astype(jnp.int32), still, n_sentinel,
-                                 capacity)
-    return out[:r, 0], out[:r, 1], still, items, count
+    return out[:r, 0], out[:r, 1], out[:r, 2] != 0

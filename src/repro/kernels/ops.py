@@ -71,11 +71,10 @@ def fused_compact(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
                   ids: jax.Array, active: jax.Array, pending: jax.Array,
                   extra_forb: "jax.Array | None",
                   hub_lose: "jax.Array | None", window: int, *,
-                  capacity: int, n_sentinel: int,
                   tile_rows: "int | None" = None
-                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
-                             jax.Array]:
-    """Fused step kernel + worklist compaction (DESIGN.md §10).
+                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Fused step kernel with new-color/base selection (DESIGN.md §10);
+    the caller compacts the survivors.
 
     Not independently jitted: the optional hub operands change the
     traced signature, and every caller (the ipgc step impls) already
@@ -83,9 +82,7 @@ def fused_compact(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
     """
     return fused_compact_pallas(nc, npr, nbr_ids, base, cu, pu, ids,
                                 active, pending, extra_forb, hub_lose,
-                                window, capacity=capacity,
-                                n_sentinel=n_sentinel,
-                                tile_rows=_tile(tile_rows),
+                                window, tile_rows=_tile(tile_rows),
                                 interpret=interpret())
 
 

@@ -139,8 +139,6 @@ def _time_candidate(case, tile_rows: int) -> float:
         else:
             extra = hub_lose = None
         return fused_compact_pallas(*arrs, extra, hub_lose, _SWEEP_WINDOW,
-                                    capacity=_SWEEP_ROWS,
-                                    n_sentinel=_SWEEP_ROWS,
                                     tile_rows=tile_rows,
                                     interpret=interpret())
 

@@ -236,9 +236,14 @@ def test_fused_step_matches_ref(r, k):
 
 
 def _assert_fused_compact_parity(case, n, *, capacity, tile_rows=32, w=64):
+    """The kernel, then the caller's emission (``compact_items`` of the
+    survivors), against the reference step + compaction."""
+    from repro.core.worklist import compact_items
     from repro.kernels.fused_compact import fused_compact_pallas
-    got = fused_compact_pallas(*case, w, capacity=capacity, n_sentinel=n,
-                               tile_rows=tile_rows, interpret=True)
+    new_c, new_b, still = fused_compact_pallas(*case, w, tile_rows=tile_rows,
+                                               interpret=True)
+    ids = case[6]
+    got = (new_c, new_b, still) + compact_items(ids, still, n, capacity)
     want = ref.fused_compact_ref(*case, w, capacity=capacity, n_sentinel=n)
     for g, x, name in zip(got, want,
                           ("new_c", "new_base", "still", "items", "count")):
@@ -391,6 +396,104 @@ def test_packed_sparse_steps_match_full_sweep(layout, ell_cap, fused):
     np.testing.assert_array_equal(got.colors, want.colors)
     assert (got.iterations, got.mode_trace) == (want.iterations,
                                                 want.mode_trace)
+
+
+def _rgg(n, layout, ell_cap, edges=None):
+    from repro.graphs import generators, ingest
+    from repro.graphs.layout import run_pipeline
+    src, dst, n = edges if edges else generators.edges_rgg(n, 16, 0)
+    return run_pipeline(ingest.from_arrays(src, dst, n, name="rgg"),
+                        layout=layout, ell_cap=ell_cap)
+
+
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("layout,ell_cap", [("csr-segment", None),
+                                            ("ell-tail", 8)])
+def test_colored_rows_stay_in_window(layout, ell_cap, fused):
+    """The invariant the window-bit conflict tests rest on
+    (``csr_segment.edge_conflict``, ``ipgc._packed_lose``): after every
+    step a colored row's color lies in ``[base, base + W)``, because base
+    only advances for rows left uncolored. Dense, full-sweep sparse and
+    packed sparse steps over a whole run, with a window small enough
+    that bases advance."""
+    from repro.algos import get_algorithm
+    from repro.core import ipgc
+    from repro.core.worklist import resize_items
+
+    w = 8
+    ig = get_algorithm("ipgc").prepare(_rgg(8192, layout, ell_cap))
+    n = ig.n_nodes
+    dense, sparse = ipgc.step_fns(fused)
+    colors, base, wl = get_algorithm("ipgc").init_state(ig)
+    steps = advanced = 0
+    while int(wl.count) > 0:
+        if steps < 2:
+            step = dense
+        else:
+            step = sparse
+            if int(wl.count) <= 1024 < wl.capacity:
+                wl = resize_items(wl, 1024, n)      # the packed bucket
+        colors, base, wl = step(ig, colors, base, wl, window=w)
+        c, b = np.asarray(colors[:n]), np.asarray(base)
+        on = c >= 0
+        assert ((b[on] <= c[on]) & (c[on] < b[on] + w)).all(), steps
+        advanced = max(advanced, int(b.max()))
+        steps += 1
+    assert wl.capacity == 1024 and advanced >= 2 * w
+
+
+def test_same_shape_graphs_share_compiled_steps():
+    """``seg_bound`` is a static step argument: rounded up its ladder, two
+    graphs with the same shapes but different degrees trace each step
+    once; with exact bounds the second graph would retrace."""
+    import dataclasses
+
+    from repro.algos import get_algorithm
+    from repro.core import ipgc
+
+    g_a = _rgg(4096, "csr-segment", None)
+    # move one edge (u, v) to (u, w), w the top-degree node: same node
+    # and edge counts, another degree sequence
+    deg = np.asarray(g_a.arrays.degrees)
+    src = np.repeat(np.arange(g_a.n_nodes), deg)
+    dst = np.asarray(g_a.arrays.col_idx)
+    w = int(np.argmax(deg))
+    near = set(dst[src == w].tolist()) | {w}
+    i = next(i for i in range(src.size) if src[i] < dst[i]
+             and src[i] not in near and dst[i] != w)
+    keep = ~(((src == src[i]) & (dst == dst[i]))
+             | ((src == dst[i]) & (dst == src[i])))
+    s2 = np.concatenate([src[keep & (src < dst)], [src[i]]])
+    d2 = np.concatenate([dst[keep & (src < dst)], [w]])
+    g_b = _rgg(None, "csr-segment", None, edges=(s2, d2, g_a.n_nodes))
+    assert (g_b.n_nodes, g_b.n_edges) == (g_a.n_nodes, g_a.n_edges)
+    assert int(np.asarray(g_b.arrays.degrees).max()) == int(deg.max()) + 1
+
+    alg = get_algorithm("ipgc")
+    ig_a, ig_b = alg.prepare(g_a), alg.prepare(g_b)
+    assert ig_a.seg_bound == ig_b.seg_bound
+
+    def trace_all(ig):
+        colors, base, wl = alg.init_state(ig)
+        small = wl._replace(items=wl.items[:1024])
+        for fused in (False, True):
+            dense, sparse = ipgc.step_fns(fused)
+            dense(ig, colors, base, wl, window=32)
+            sparse(ig, colors, base, small, window=32)
+
+    def exact(g):
+        top = np.cumsum(np.sort(np.asarray(g.arrays.degrees))[::-1])
+        return tuple(int(top[min(2 ** k, top.size) - 1])
+                     for k in range(len(ig_a.seg_bound)))
+
+    assert exact(g_a) != exact(g_b)
+    trace_all(ig_a)
+    trace_all(dataclasses.replace(ig_a, seg_bound=exact(g_a)))
+    with ipgc.LAUNCH_COUNTS.scope() as traced:
+        trace_all(ig_b)
+        assert traced.total() == 0
+        trace_all(dataclasses.replace(ig_b, seg_bound=exact(g_b)))
+        assert traced.total() > 0
 
 
 @pytest.mark.parametrize("r,k", [(1, 1), (33, 8), (200, 40)])

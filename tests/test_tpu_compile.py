@@ -31,15 +31,14 @@ W = 128              # color window
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        described = topologies.get_topology_desc(platform="tpu",
+                                                 topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler to describe the chip with
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # a compile for a described chip is written to the persistent cache
@@ -47,8 +46,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield described
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _kernel(name: str, tile_rows: int):
@@ -79,8 +84,7 @@ def _kernel(name: str, tile_rows: int):
 
     def fc(*a):
         a = list(a) if with_hub else list(a) + [None, None]
-        return fused_compact_pallas(*a, W, capacity=R, n_sentinel=R,
-                                    tile_rows=tile_rows)
+        return fused_compact_pallas(*a, W, tile_rows=tile_rows)
     return fc, tile + [row] * 4 + [flag, flag] + (hub if with_hub else [])
 
 
@@ -104,3 +108,54 @@ def test_fused_compact_compiles_for_v5e(one_chip, name, tile_rows):
     and without the hub operands (pure-ell vs ell-tail/hub-split)."""
     fn, shapes = _kernel(name, tile_rows)
     assert "tpu_custom_call" in _compile_for_chip(fn, shapes, one_chip)
+
+
+@pytest.mark.parametrize("exchange", ["dense", "auto"])
+def test_dist_steps_compile_for_v5e_2x2(topo, monkeypatch, exchange):
+    """The dist-hybrid steps (one shard_map program each) compile for a
+    described four-chip v5e host, with the color exchange's collectives
+    in them. A kron graph at 2^14 nodes: this checks that the sharded
+    programs lower for the chip, not their memory at 2^21 (a dense step
+    at 2^19 takes the compiler about 45 s). The described chips hold no
+    data, so the steps receive the graph arrays as shapes."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.algos import get_algorithm
+    from repro.core import distributed
+    from repro.core.engine import resolve_plan
+    from repro.core.policy import exchange_threshold
+    from repro.core.worklist import Worklist, bucket_capacities
+    from repro.graphs import make_graph
+    from repro.graphs.partition import boundary_info, prepare_partition
+
+    s = 4
+    g = make_graph("kron_g500-logn21_s", scale=0.25)
+    g2, _ = prepare_partition(g, s)
+    alg = get_algorithm("ipgc")
+    ig = alg.prepare(g2, plan=resolve_plan(g, "ell-tail"))
+    n, blk = ig.n_nodes, ig.n_nodes // s
+    mesh = Mesh(np.array(topo.devices[:s]), ("data",))
+    monkeypatch.setattr(distributed.jax, "device_put",
+                        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                           sharding=sh))
+    binfo = boundary_info(g2, s) if exchange != "dense" else None
+    dense, sparse = alg.make_dist_steps(
+        ig, mesh, ("data",), window=32, fused=True, exchange=exchange,
+        boundary=binfo,
+        thresh=exchange_threshold(n, s, exchange) if binfo else None)
+
+    def shape(shp, dtype, *spec):
+        return jax.ShapeDtypeStruct(shp, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+    views = binfo is not None
+    colors = (shape((s, n + 1), jnp.int32, "data", None) if views
+              else shape((n + 1,), jnp.int32))
+    kw = {"bcap": binfo.capacities[-1]} if views else {}
+    for step, cap in ((dense, blk), (sparse, bucket_capacities(blk)[-1])):
+        wl = Worklist(mask=shape((n,), jnp.bool_, "data"),
+                      items=shape((s * cap,), jnp.int32, "data"),
+                      count=shape((), jnp.int32))
+        hlo = step.lower(colors, shape((n,), jnp.int32, "data"), wl,
+                         **kw).compile().as_text()
+        assert "all-reduce" in hlo or "all-gather" in hlo
