@@ -108,8 +108,18 @@ class Algorithm:
         raise NotImplementedError
 
     def step_fns(self, fused: bool):
-        """(dense, sparse) jitted step pair for the host-loop Pipe."""
+        """(dense, sparse) jitted step pair for the host-loop Pipe. The
+        sparse step is the ``ipgc.tallied`` form of the sparse impl: it
+        also returns ``int32[2]``, the worklist count after it and the
+        live entries of its rows, read back in one transfer."""
         raise NotImplementedError
+
+    def sparse_slots(self, ig: ipgc.IPGCGraph, capacity: int,
+                     force_hub: bool | None) -> int:
+        """Adjacency entries the sparse step gathers at worklist capacity
+        ``capacity``, live or not (static). The default is the IPGC
+        steps' rule, packing included (``ipgc.sparse_slots``)."""
+        return ipgc.sparse_slots(ig, capacity, force_hub)
 
     def resolve_fused(self, fused: bool | None, *, default: bool) -> bool:
         """Map the caller's ``fused`` request (None = engine default) to

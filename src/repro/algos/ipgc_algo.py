@@ -31,7 +31,7 @@ class IPGC(Algorithm):
                 if fused else (ipgc.dense_step_impl, ipgc.sparse_step_impl))
 
     def step_fns(self, fused: bool):
-        return ipgc.step_fns(fused)
+        return ipgc.step_fns(fused)[0], ipgc.tallied(self.step_impls(fused)[1])
 
     def make_dist_steps(self, ig_local, mesh, node_axes, *, window: int,
                         fused: bool, exchange: str = "dense", boundary=None,

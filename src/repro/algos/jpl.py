@@ -176,7 +176,6 @@ def jpl_sparse_step_impl(ig: ipgc.IPGCGraph, colors: jax.Array,
 
 _JPL_STATICS = ("window", "impl", "force_hub", "tile_rows")
 jpl_dense_step = jax.jit(jpl_dense_step_impl, static_argnames=_JPL_STATICS)
-jpl_sparse_step = jax.jit(jpl_sparse_step_impl, static_argnames=_JPL_STATICS)
 
 
 # ---------------------------------------------------------------------------
@@ -202,7 +201,8 @@ def make_jpl_dist_steps(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
                         *, exchange: str = "dense", boundary=None,
                         thresh: "int | None" = None):
     """(dense_round, sparse_round) shard_map'd JPL steps, bit-identical to
-    ``jpl_dense_step``/``jpl_sparse_step`` on the partitioned graph."""
+    ``jpl_dense_step_impl``/``jpl_sparse_step_impl`` on the partitioned
+    graph."""
     from functools import partial
 
     from jax.sharding import PartitionSpec as P
@@ -367,7 +367,12 @@ class JPL(Algorithm):
         return jpl_dense_step_impl, jpl_sparse_step_impl
 
     def step_fns(self, fused: bool):
-        return jpl_dense_step, jpl_sparse_step
+        return jpl_dense_step, ipgc.tallied(jpl_sparse_step_impl)
+
+    def sparse_slots(self, ig, capacity, force_hub):
+        # no packing: the ELL rows of the worklist, and the whole hub tail
+        tail = ig.tail_dst.shape[0] if ipgc._has_hubs(ig, force_hub) else 0
+        return capacity * ig.ell_width + tail
 
     def resolve_fused(self, fused, *, default):
         return False                      # single step family

@@ -101,6 +101,11 @@ class ColoringResult:
     # iteration) and the modeled bytes each iteration moved per device
     exchange_trace: str = ""
     exchange_bytes: list = dataclasses.field(default_factory=list)
+    # host regime only (empty elsewhere): per 'S' of mode_trace, the
+    # adjacency entries of the rows the sparse step ran (live) and the
+    # entries it gathered to do so, padding included (ipgc.sparse_slots)
+    sparse_entries: list = dataclasses.field(default_factory=list)
+    sparse_slots: list = dataclasses.field(default_factory=list)
 
 
 def resolve_plan(g, layout):

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import os
 
 import jax
@@ -602,19 +603,27 @@ class _Packed:
                                 self.max_run)
 
 
+def packed_cap(ig: IPGCGraph, capacity: int, m: int) -> "int | None":
+    """Slots of the static buffer into which a packed pass at worklist
+    capacity ``capacity`` lays its rows' entries, out of ``m`` entries;
+    None where the graph has no bound or the buffer would pass half of
+    the entries: the pass then sweeps all ``m``."""
+    if not ig.seg_bound:
+        return None
+    k = min(max(capacity - 1, 0).bit_length(), len(ig.seg_bound) - 1)
+    cap = max(-(-ig.seg_bound[k] // 1024) * 1024, 1024)
+    return None if 2 * cap > m else cap
+
+
 def _packed(ig: IPGCGraph, items: jax.Array, entry_dst: jax.Array,
             starts_of, counts_of, *, keyed: bool) -> "_Packed | None":
     """Pack the entries of the rows in ``items`` (``None``: no static
     bound small enough — sweep every entry). ``keyed`` entries carry the
     tie-break in their sign bit (csr-segment); otherwise it is computed
     from the priorities."""
-    c = items.shape[0]
-    if not ig.seg_bound:
-        return None
-    k = min(max(c - 1, 0).bit_length(), len(ig.seg_bound) - 1)
-    cap = max(-(-ig.seg_bound[k] // 1024) * 1024, 1024)
     m = entry_dst.shape[0]
-    if 2 * cap > m:
+    cap = packed_cap(ig, items.shape[0], m)
+    if cap is None:
         return None
     n = ig.n_nodes
     valid = items < n
@@ -1018,3 +1027,47 @@ def step_fns(fused: bool):
     """(dense, sparse) jitted step pair for the requested semantics."""
     return ((fused_dense_step, fused_sparse_step) if fused
             else (dense_step, sparse_step))
+
+
+# ---------------------------------------------------------------------------
+# what a sparse step gathers: the live entries of its rows, and its slots
+# ---------------------------------------------------------------------------
+
+def live_entries(ig: IPGCGraph, items: jax.Array) -> jax.Array:
+    """int32[] adjacency entries of the rows in a worklist items block
+    (the sum of their degrees): what a sparse step over it has to read."""
+    n = ig.n_nodes
+    valid = items < n
+    return jnp.sum(jnp.where(valid, ig.degrees[jnp.where(valid, items, 0)],
+                             0), dtype=jnp.int32)
+
+
+def sparse_slots(ig: IPGCGraph, capacity: int,
+                 force_hub: bool | None = None) -> int:
+    """Adjacency entries a sparse step at worklist capacity ``capacity``
+    gathers, live or not (static, so the host counts it): on csr-segment
+    the packed buffer, or every edge entry where the step sweeps them; on
+    the ELL path ``capacity`` rows of the ELL width, plus the hub tail's
+    packed buffer, or the whole tail where the step sweeps it."""
+    if ig.layout_kind == "csr-segment":
+        m = ig.edge_dst.shape[0]
+        return packed_cap(ig, capacity, m) or m
+    slots = capacity * ig.ell_width
+    if _has_hubs(ig, force_hub):
+        t = ig.tail_dst.shape[0]
+        slots += packed_cap(ig, capacity, t) or t
+    return slots
+
+
+@functools.cache
+def tallied(sparse_impl):
+    """The host loop's jitted form of a sparse step impl: the same step,
+    which also returns ``int32[2]`` = (the worklist count after the step,
+    the live entries of the rows it ran), read back in one transfer. The
+    program keeps the impl's name."""
+    @functools.wraps(sparse_impl)
+    def step(ig, colors, aux, wl, **statics):
+        live = live_entries(ig, wl.items)
+        colors, aux, wl = sparse_impl(ig, colors, aux, wl, **statics)
+        return colors, aux, wl, jnp.stack([wl.count, live])
+    return jax.jit(step, static_argnames=_STEP_STATICS)

@@ -90,41 +90,23 @@ class CacheStats:
 @dataclasses.dataclass
 class _DispatchMeter:
     """Per-run device-dispatch accounting, filled by the drivers when a
-    run is traced (DESIGN.md §12).
-
-    ``first - best`` is the report's *compile proxy*: the first dispatch
-    of a cold entry pays trace+compile, steady-state dispatches don't —
-    a proxy, exact only when steady-state dispatches are homogeneous.
-    ``statics`` snapshots the driver's resolved static arguments so the
-    work profiler replays exactly the resolution the run used.
+    run is traced (DESIGN.md §12). ``statics`` snapshots the driver's
+    resolved static arguments so the work profiler replays exactly the
+    resolution the run used.
     """
 
     dispatch_seconds: float = 0.0
-    first: "float | None" = None
-    best: "float | None" = None
     n: int = 0
     statics: "dict | None" = None
 
     def add(self, seconds: float) -> None:
         self.dispatch_seconds += seconds
-        if self.first is None:
-            self.first = seconds
-        self.best = seconds if self.best is None else min(self.best, seconds)
         self.n += 1
 
     def timing(self, total_seconds: float) -> dict:
-        first = self.first or 0.0
-        best = self.best or 0.0
-        return {
-            "total_seconds": total_seconds,
-            "dispatch_seconds": self.dispatch_seconds,
-            "dispatches": self.n,
-            "first_dispatch_seconds": first,
-            "best_dispatch_seconds": best,
-            "compile_proxy_seconds": max(0.0, first - best),
-            "host_overhead_seconds": max(
-                0.0, total_seconds - self.dispatch_seconds),
-        }
+        return {"total_seconds": total_seconds,
+                "dispatch_seconds": self.dispatch_seconds,
+                "dispatches": self.n}
 
 
 def _graph_key(g) -> tuple:
@@ -514,6 +496,8 @@ class Session:
         trace: list[str] = []
         counts: list[int] = []
         tti: list[float] = []
+        entries: list[int] = []
+        slots: list[int] = []
         t_start = time.perf_counter()
         it = 0
         while count > 0 and it < spec.max_iter:
@@ -523,17 +507,29 @@ class Session:
                     "session.iter", mode="D" if use_dense else "S",
                     count=count), Timer() as t:
                 if use_dense:
-                    colors, aux, wl = dense_fn(
-                        ig, colors, aux, wl, window=window, impl=spec.impl,
-                        force_hub=force_hub, tile_rows=tile_rows)
+                    with obs_trace.maybe_span("session.dispatch"):
+                        colors, aux, wl = dense_fn(
+                            ig, colors, aux, wl, window=window,
+                            impl=spec.impl, force_hub=force_hub,
+                            tile_rows=tile_rows)
+                    with obs_trace.maybe_span("session.readback"):
+                        count = int(wl.count)  # the Pipe's one read-back
                 else:
                     cap = pick_bucket(caps, count)
                     if wl.capacity > cap:
-                        wl = resize_items(wl, cap, n)
-                    colors, aux, wl = sparse_fn(
-                        ig, colors, aux, wl, window=window, impl=spec.impl,
-                        force_hub=force_hub, tile_rows=tile_rows)
-                count = int(wl.count)  # the Pipe's single scalar read-back
+                        with obs_trace.maybe_span("session.resize"):
+                            wl = resize_items(wl, cap, n)
+                    slots.append(alg.sparse_slots(ig, wl.capacity,
+                                                  force_hub))
+                    with obs_trace.maybe_span("session.dispatch"):
+                        colors, aux, wl, tally = sparse_fn(
+                            ig, colors, aux, wl, window=window,
+                            impl=spec.impl, force_hub=force_hub,
+                            tile_rows=tile_rows)
+                    with obs_trace.maybe_span("session.readback"):
+                        # count and live entries: the Pipe's one read-back
+                        count, live = (int(v) for v in np.asarray(tally))
+                    entries.append(live)
             trace.append("D" if use_dense else "S")
             if meter is not None:
                 meter.add(t.seconds)
@@ -544,11 +540,13 @@ class Session:
             it += 1
 
         total = time.perf_counter() - t_start
-        final, n_colors = alg.finalize(np.asarray(colors[:n]))
+        with obs_trace.maybe_span("session.finalize"):
+            final, n_colors = alg.finalize(np.asarray(colors[:n]))
         return ColoringResult(colors=final, n_colors=n_colors, iterations=it,
                               mode_trace="".join(trace), counts=counts,
                               tti=tti, total_seconds=total,
-                              host_dispatches=it)
+                              host_dispatches=it, sparse_entries=entries,
+                              sparse_slots=slots)
 
     # -- device-resident outlined Pipe ---------------------------------------
 

@@ -11,12 +11,11 @@ from repro.obs.metrics import (Counter, CounterGroup, DEPTH_EDGES, Gauge,
 from repro.obs.report import (RunReport, exchange_section,
                               totals_from_trace)
 from repro.obs.trace import (Event, Span, Trace, current_trace,
-                             maybe_event, maybe_span, tracing)
+                             maybe_span, tracing)
 
 __all__ = [
     "Counter", "CounterGroup", "DEPTH_EDGES", "Event", "Gauge",
     "Histogram", "LATENCY_EDGES", "MetricsRegistry", "RunReport", "Span",
     "Trace", "current_trace", "default_registry", "exchange_section",
-    "exp_edges", "maybe_event", "maybe_span", "totals_from_trace",
-    "tracing",
+    "exp_edges", "maybe_span", "totals_from_trace", "tracing",
 ]

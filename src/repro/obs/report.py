@@ -21,10 +21,8 @@ counter groups (``ipgc.LAUNCH_COUNTS``, ``ipgc.GATHER_COUNTS``,
     exchanged per iteration** — each ``color_psum`` moves one
     ``int32[N+1]`` delta per device, so ``bytes/iter = exchanges/iter
     x 4(N+1)`` (the ROADMAP's BENCH_dist accounting gap);
-  * a compile-vs-execute time split: ``dispatch_seconds`` sums the
-    per-dispatch timers; ``compile_proxy_seconds`` is first dispatch
-    minus best dispatch (clamped at 0) — a PROXY for compile+warmup
-    cost, exact only when steady-state dispatches are homogeneous;
+  * a time split: ``dispatch_seconds`` sums the per-dispatch timers,
+    against the run's ``total_seconds``;
   * a cache snapshot (``CacheStats.as_dict()`` of the owning session at
     report time, plus this run's delta).
 
@@ -137,7 +135,7 @@ class RunReport:
     host_dispatches: int = 0
     #: live worklist size entering each host dispatch
     counts: list = dataclasses.field(default_factory=list)
-    #: total / dispatch / first / best / compile proxy / host overhead
+    #: total seconds / dispatch seconds / dispatches
     timing: dict = dataclasses.field(default_factory=dict)
     #: {"per_iter": {"dense": {...}, "sparse": {...}}, "total": {...}}
     launches: dict = dataclasses.field(default_factory=dict)

@@ -7,11 +7,25 @@ the streaming service's latency stamps (serve/clock.py): the default is
 ``time.perf_counter``; tests inject a ``ManualClock`` and assert span
 durations against exact values instead of wall-clock noise.
 
+Every span also opens a ``jax.profiler.TraceAnnotation`` of the same
+name, with or without a ``Trace`` installed: while the JAX profiler
+runs, each span lands in its ``.xplane.pb`` on the calling thread's host
+line, on the clock of the device planes, so an idle stretch of the
+device can be put down to the span the host was in. The profiler sees
+the bare name only: names are a stable schema and carry no per-call
+values (those are attributes of the ``Span``), so a trace groups by
+name. With the profiler off an annotation costs about a microsecond.
+
 Span naming scheme (the contract DESIGN.md §12 documents):
 
-  session.run / session.prepare / session.iter / session.chunk —
-      the engine drivers; ``session.iter``/``.chunk`` carry
-      ``mode``/``count`` attrs per dispatch
+  session.run / session.prepare / session.chunk — the engine drivers;
+      ``session.chunk`` carries ``branch``/``count``/``cap`` attrs
+  session.iter — one iteration of the host loop or the sharded Pipe
+      (``mode``/``count`` attrs); in the host loop the parent of
+      session.resize (the worklist moves to a smaller capacity bucket),
+      session.dispatch (the jitted step call) and session.readback (the
+      one read-back); session.finalize — after the host loop, the copy
+      of the colors to the host and the algorithm's finalize
   batch.run / batch.dispatch — the barrier batch (exec/batch.py)
   stream.pump / stream.dispatch — the continuous-batching service
   tune.sweep / tune.candidate — the tile autotuner (kernels/tune.py)
@@ -25,15 +39,16 @@ loadable directly in Perfetto / ``chrome://tracing``.
 Deep code attaches spans without threading a trace argument through
 every signature via the AMBIENT trace: ``tracing(trace)`` installs a
 trace for the dynamic extent of a block, ``maybe_span(name, **attrs)``
-opens a span on the innermost installed trace — or no-ops (a shared
-null context) when none is installed, so instrumented hot loops cost
-one dict lookup per iteration when telemetry is off.
+opens a span on the innermost installed trace — or only the profiler
+annotation when none is installed.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -76,15 +91,16 @@ class Trace:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        sp = Span(name=name, start=self.clock(), attrs=attrs)
-        parent = self._stack[-1] if self._stack else None
-        (parent.children if parent else self.spans).append(sp)
-        self._stack.append(sp)
-        try:
-            yield sp
-        finally:
-            self._stack.pop()
-            sp.end = self.clock()
+        with TraceAnnotation(name):
+            sp = Span(name=name, start=self.clock(), attrs=attrs)
+            parent = self._stack[-1] if self._stack else None
+            (parent.children if parent else self.spans).append(sp)
+            self._stack.append(sp)
+            try:
+                yield sp
+            finally:
+                self._stack.pop()
+                sp.end = self.clock()
 
     def event(self, name: str, **attrs) -> Event:
         ev = Event(name=name, ts=self.clock(), attrs=attrs)
@@ -130,7 +146,6 @@ class Trace:
 # ---------------------------------------------------------------------------
 
 _AMBIENT: list[Trace] = []
-_NULL = contextlib.nullcontext()
 
 
 def current_trace() -> "Trace | None":
@@ -149,14 +164,25 @@ def tracing(trace: Trace):
         _AMBIENT.pop()
 
 
+class _Annotation:
+    """The profiler annotation alone, entered as a span that records
+    nothing: ``with maybe_span(...) as sp`` binds None, as callers that
+    attach attributes only to a real span expect."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self) -> None:
+        self._ann.__enter__()
+
+    def __exit__(self, *exc):
+        return self._ann.__exit__(*exc)
+
+
 def maybe_span(name: str, **attrs):
-    """A span on the ambient trace, or a shared no-op context manager
-    when no trace is installed (telemetry off: ~one list peek)."""
+    """A span on the ambient trace, or, when no trace is installed, the
+    profiler annotation alone (``attrs`` then go nowhere)."""
     tr = current_trace()
-    return _NULL if tr is None else tr.span(name, **attrs)
-
-
-def maybe_event(name: str, **attrs) -> None:
-    tr = current_trace()
-    if tr is not None:
-        tr.event(name, **attrs)
+    return _Annotation(name) if tr is None else tr.span(name, **attrs)
