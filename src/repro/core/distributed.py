@@ -186,7 +186,8 @@ def _shard_offset(mesh, node_axes: tuple):
 class ShardGraph:
     """A prepared graph laid out for the sharded steps, placed on the mesh.
 
-    ELL rows are row-sharded and priorities replicated. The hub tail is
+    ELL rows and their tie-break bits (``IPGCGraph.ell_wins``) are
+    row-sharded, priorities replicated. The hub tail is
     split by owner (owner computes): a hub's COO-tail entries live on the
     shard that owns the hub row, so a step sweeps or packs only its own
     entries, never the whole tail (50M entries at kron_g500-logn21
@@ -205,8 +206,8 @@ class ShardGraph:
 
     n_hub: int
     seg_bound: tuple      # ipgc.IPGCGraph.seg_bound over every shard
-    # (ell_idx, hub_slot, priority, (tail_src, tail_dst, tail_valid,
-    #  tail_slot, tail_start, hub_ids)); tail arrays shard-major
+    # (ell_idx, ell_wins, hub_slot, priority, (tail_src, tail_dst,
+    #  tail_valid, tail_slot, tail_start, hub_ids)); tail arrays shard-major
     arrays: tuple
     specs: tuple          # shard_map specs of ``arrays``
 
@@ -217,15 +218,15 @@ def shard_graph(ig: ipgc.IPGCGraph, mesh, node_axes: tuple) -> ShardGraph:
     n_hub, seg_bound, hub_slot, tails = split_tail(
         ig, math.prod(mesh.shape[a] for a in node_axes))
     na = node_axes
-    specs = (P(na, None), P(), P(), P(na))
+    specs = (P(na, None), P(na, None), P(), P(), P(na))
 
     def placed(spec, x):
         return jax.device_put(x, NamedSharding(mesh, spec))
     return ShardGraph(
         n_hub=n_hub, seg_bound=seg_bound,
-        arrays=(placed(specs[0], ig.ell_idx), placed(P(), hub_slot),
-                placed(P(), ig.priority),
-                tuple(placed(specs[3], a) for a in tails)),
+        arrays=(placed(specs[0], ig.ell_idx), placed(specs[1], ig.ell_wins),
+                placed(P(), hub_slot), placed(P(), ig.priority),
+                tuple(placed(specs[4], a) for a in tails)),
         specs=specs)
 
 
@@ -310,14 +311,14 @@ def _bind(local_step, mesh, na: tuple, sg: ShardGraph, isb, *,
 def _local_graph_view(ig_local: ipgc.IPGCGraph, sg: ShardGraph, n: int,
                       graph) -> ipgc.IPGCGraph:
     """IPGCGraph over this shard's row block and its own hub tail."""
-    ell_l, hub_slot, prio, (tail_src, tail_dst, tail_valid, tail_slot,
-                            tail_start, hub_ids) = graph
+    ell_l, wins_l, hub_slot, prio, (tail_src, tail_dst, tail_valid,
+                                    tail_slot, tail_start, hub_ids) = graph
     return ipgc.IPGCGraph(
         n_nodes=n, ell_width=ig_local.ell_width, n_hub=sg.n_hub,
         ell_idx=ell_l, degrees=jnp.zeros((0,), jnp.int32), priority=prio,
         tail_src=tail_src, tail_dst=tail_dst, tail_valid=tail_valid,
         tail_slot=tail_slot, hub_slot=hub_slot, hub_ids=hub_ids,
-        tail_start=tail_start, seg_bound=sg.seg_bound)
+        tail_start=tail_start, ell_wins=wins_l, seg_bound=sg.seg_bound)
 
 
 def _own_block(full: jax.Array, block: jax.Array, row_ids: jax.Array
@@ -481,11 +482,12 @@ def make_dist_dense_step(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
                                    spec_c - cu0, row_ids)
                 colors2 = _exchange_colors(colors, delta, node_axes)
             # --- resolve ---
-            lose = ipgc._lose_rows(ig, ell_l, row_ids, colors2, newly, "jnp")
+            c2r = colors2[row_ids]
+            lose = ipgc._lose_rows(ig, ell_l, row_ids, ig.ell_wins, c2r,
+                                   colors2, newly, "jnp")
             if hubs:
                 lose = lose | _dense_hub_lose(ig, colors2, newly, row_ids)
             # exchange 2: uncolor losers (their writes were in colors2)
-            c2r = colors2[row_ids]
             if views:
                 colors_out, npk2, b2 = pub(colors2, row_ids, c2r,
                                            jnp.where(lose, NO_COLOR, c2r),
@@ -616,11 +618,12 @@ def make_dist_sparse_step(ig_local: ipgc.IPGCGraph, mesh, node_axes: tuple,
                     jnp.where(valid, new_c - cu, 0))
                 colors2 = _exchange_colors(colors, delta, node_axes)
             # --- resolve ---
-            lose = ipgc._lose_rows(ig, ell_rows, ids, colors2, newly, "jnp")
+            c2 = colors2[ids]
+            lose = ipgc._lose_rows(ig, ell_rows, ids, ig.ell_wins[local], c2,
+                                   colors2, newly, "jnp")
             if hubs:
                 lose = lose | hub_lose(colors2, newly,
                                        jnp.where(newly, new_c, NO_COLOR))
-            c2 = colors2[ids]
             if views:
                 colors_out, npk2, b2 = pub(colors2, ids, c2,
                                            jnp.where(lose, NO_COLOR, c2),

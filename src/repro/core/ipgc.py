@@ -81,6 +81,11 @@ class IPGCGraph:
     # (csr_segment.WINS_BIT) set where it wins the (priority, id)
     # tie-break against the source — static, so no step gathers priorities
     edge_dst: jax.Array | None = None
+    # the same static tie-break for the ELL-family kinds (None on
+    # csr-segment): u32[N, ceil(K/32)], bit k % 32 of word k // 32 of row
+    # u set where the neighbour in ELL slot k wins against u; pad slots
+    # clear. The two-phase ELL resolve reads it in place of priorities
+    ell_wins: jax.Array | None = None
     # static bound for the packed sparse passes: seg_bound[k] bounds the
     # entries (csr edges / tail entries) any 2^k rows own, rounded up a
     # coarse ladder (``_top_sums``); () = no bound, sparse steps sweep
@@ -114,7 +119,7 @@ def prepare(g: Graph, *, priority: str = "hash", plan=None) -> IPGCGraph:
     tail_src_safe = np.minimum(tail_src, n - 1)
     pr = np.asarray(a.priority) if priority == "hash" else np.arange(n, dtype=np.int32)
     prio = np.concatenate([pr, np.full(1, -1, np.int32)])
-    edge_src = edge_dst = None
+    edge_src = edge_dst = ell_wins = None
     real = tail_src[tail_valid]                     # sorted by source row
     tail_start = np.searchsorted(hub_slot[real],
                                  np.arange(n_hub + 1)).astype(np.int32)
@@ -125,11 +130,11 @@ def prepare(g: Graph, *, priority: str = "hash", plan=None) -> IPGCGraph:
         ed = np.full(ep, n, dtype=np.int32)              # (ec < 0)
         es[:e] = np.repeat(np.arange(n, dtype=np.int32), deg)
         ed[:e] = np.asarray(a.col_idx)
-        wins = (prio[ed] > prio[es]) | ((prio[ed] == prio[es]) & (ed > es))
-        ed[wins] |= np.int32(kcsr.WINS_BIT)
+        ed[kcsr.wins(prio[es], prio[ed], es, ed)] |= np.int32(kcsr.WINS_BIT)
         edge_src, edge_dst = jnp.asarray(es), jnp.asarray(ed)
         owned = deg
     else:
+        ell_wins = jnp.asarray(_ell_wins(np.asarray(a.ell_idx), prio))
         owned = np.bincount(real, minlength=n)
     return IPGCGraph(
         n_nodes=n,
@@ -148,8 +153,34 @@ def prepare(g: Graph, *, priority: str = "hash", plan=None) -> IPGCGraph:
         layout_kind=kind,
         edge_src=edge_src,
         edge_dst=edge_dst,
+        ell_wins=ell_wins,
         seg_bound=_top_sums(owned),
     )
+
+
+def wins_words(k: int) -> int:
+    """Words of ``IPGCGraph.ell_wins`` a row of ``k`` ELL slots takes."""
+    return -(-k // 32)
+
+
+def _ell_wins(ell: np.ndarray, prio: np.ndarray) -> np.ndarray:
+    """``IPGCGraph.ell_wins`` of an ELL tile (pad = N, ``prio[N]`` the
+    pad priority): ``kcsr.wins`` per slot, packed 32 slots a word."""
+    n, k = ell.shape
+    rows = np.arange(n, dtype=np.int32)[:, None]
+    wins = kcsr.wins(prio[:n, None], prio[ell], rows, ell) & (ell < n)
+    packed = np.zeros((n, 4 * wins_words(k)), np.uint8)
+    packed[:, :-(-k // 8)] = np.packbits(wins, axis=1, bitorder="little")
+    return packed.view("<u4").astype(np.uint32)
+
+
+def slot_wins(words: jax.Array, k: int) -> jax.Array:
+    """(R, k) bool tie-break bits of ``ell_wins`` rows ``words``: slot j
+    is bit j % 32 of word j // 32 (a broadcast, no gather)."""
+    r, nw = words.shape
+    w = jnp.broadcast_to(words[:, :, None], (r, nw, 32)).reshape(r, 32 * nw)
+    shift = jnp.asarray(np.arange(k) % 32, jnp.uint32)
+    return ((w[:, :k] >> shift) & 1) == 1
 
 
 def _top_sums(owned: np.ndarray) -> tuple:
@@ -195,6 +226,7 @@ def pad_prepared(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
       * the old gather sentinel ``n`` (whose color slot held
         ``PAD_COLOR``) is remapped to the new sentinel ``n_pad`` in
         ``ell_idx``/``tail_dst``, preserving pad-lane semantics;
+      * ``ell_wins`` pads with clear words: no pad row or slot wins;
       * extra tail entries are ``tail_valid=False``; extra hub slots have
         no tail edges, so their forbidden/conflict rows are all-False
         (the same neutral row non-hub nodes already gather);
@@ -217,6 +249,8 @@ def pad_prepared(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
     deg = jnp.pad(ig.degrees, (0, n_pad - n))
     prio = jnp.concatenate([ig.priority[:n],
                             jnp.full((n_pad + 1 - n,), -1, jnp.int32)])
+    wins = jnp.pad(ig.ell_wins, ((0, n_pad - n),
+                                 (0, wins_words(k_pad) - wins_words(k))))
     tail_src = jnp.pad(ig.tail_src, (0, t_pad - t))        # clipped rows
     tail_dst = jnp.pad(jnp.where(ig.tail_dst == n, n_pad, ig.tail_dst),
                        (0, t_pad - t), constant_values=n_pad)
@@ -233,7 +267,8 @@ def pad_prepared(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
         n_nodes=n_pad, ell_width=k_pad, n_hub=nh_pad, ell_idx=ell,
         degrees=deg, priority=prio, tail_src=tail_src, tail_dst=tail_dst,
         tail_valid=tail_valid, tail_slot=tail_slot, hub_slot=hub_slot,
-        hub_ids=hub_ids, tail_start=tail_start, layout_kind=ig.layout_kind)
+        hub_ids=hub_ids, tail_start=tail_start, layout_kind=ig.layout_kind,
+        ell_wins=wins)
 
 
 # Read the env var ONCE at import (it used to be re-read on every trace);
@@ -412,33 +447,38 @@ def _mex_rows(ig: IPGCGraph, nc: jax.Array, base_rows: jax.Array,
 # conflict helpers
 # ---------------------------------------------------------------------------
 
+def _won_rows(nc: jax.Array, wins: jax.Array, cu: jax.Array) -> jax.Array:
+    """Row u conflicts iff some neighbour v that wins the (priority, id)
+    tie-break (``kcsr.wins``, per slot in ``wins``) has u's color."""
+    same = (nc == cu[:, None]) & (cu >= 0)[:, None]
+    return (same & wins).any(axis=1)
+
+
 def _conflict_rows(nc: jax.Array, npr: jax.Array, nbr_ids: jax.Array,
                    cu: jax.Array, pu: jax.Array, ids: jax.Array) -> jax.Array:
-    """Row u conflicts iff some neighbour v has the same color and a higher
-    (priority, id) pair — THE tie-break predicate (jnp reference; the
-    Pallas kernels and kernels/ref.py mirror it)."""
-    same = (nc == cu[:, None]) & (cu >= 0)[:, None]
-    higher = (npr > pu[:, None]) | ((npr == pu[:, None]) &
-                                    (nbr_ids > ids[:, None]))
-    return (same & higher).any(axis=1)
+    """``_won_rows`` with the tie-break evaluated from the priorities (the
+    fused steps; the Pallas kernels and kernels/ref.py mirror it)."""
+    return _won_rows(nc, kcsr.wins(pu[:, None], npr, ids[:, None], nbr_ids),
+                     cu)
 
 
 def _lose_rows(ig: IPGCGraph, ell_rows: jax.Array, row_ids: jax.Array,
-               colors: jax.Array, newly: jax.Array, impl: str,
+               wins: jax.Array, cu: jax.Array, colors: jax.Array,
+               newly: jax.Array, impl: str,
                tile_rows: int | None = None) -> jax.Array:
-    """Row u loses iff it conflicts (see ``_conflict_rows``). Only
+    """Row u (ELL row ``ell_rows``, id ``row_ids``, ``ell_wins`` words
+    ``wins``, color ``cu`` in ``colors``) loses iff it conflicts. Only
     newly-colored rows can conflict (mex excluded all surviving older
-    colors)."""
+    colors). The jnp path reads the static tie-break bits; the Pallas
+    kernel takes the priorities."""
     LAUNCH_COUNTS["conflict"] += 1
-    cu = colors[row_ids]
-    pu = ig.priority[row_ids]
     nc = _gather_neighbor_colors(colors, ell_rows)
-    npr = ig.priority[ell_rows]
     if impl == "pallas":
         from repro.kernels import ops as kops
-        return kops.conflict(nc, npr, ell_rows, cu, pu, row_ids,
+        return kops.conflict(nc, ig.priority[ell_rows], ell_rows, cu,
+                             ig.priority[row_ids], row_ids,
                              tile_rows) & newly
-    return _conflict_rows(nc, npr, ell_rows, cu, pu, row_ids) & newly
+    return _won_rows(nc, slot_wins(wins, nc.shape[1]), cu) & newly
 
 
 def _hub_lose(ig: IPGCGraph, colors: jax.Array, newly_full: jax.Array) -> jax.Array:
@@ -742,8 +782,8 @@ def dense_step_impl(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
     colors2 = colors.at[:n].set(new_c)
 
     # --- resolve (uncolor exactly one endpoint per conflict edge) ---
-    lose = _lose_rows(ig, ig.ell_idx, row_ids, colors2, newly, impl,
-                      tile_rows)
+    lose = _lose_rows(ig, ig.ell_idx, row_ids, ig.ell_wins, new_c, colors2,
+                      newly, impl, tile_rows)
     if has_hubs:
         newly_full = jnp.concatenate([newly, jnp.zeros((1,), bool)])
         hub_l = _hub_lose(ig, colors2, newly_full)
@@ -799,8 +839,9 @@ def sparse_step_impl(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
                                                     mode="drop")
 
     # --- resolve ---
-    lose = _lose_rows(ig, ell_rows, jnp.where(valid, items, n), colors2,
-                      newly, impl, tile_rows)
+    lose = _lose_rows(ig, ell_rows, jnp.where(valid, items, n),
+                      ig.ell_wins[safe], new_c, colors2, newly, impl,
+                      tile_rows)
     if pk is not None:
         lose = lose | (_packed_lose(pk, colors2[pk.dst],
                                     jnp.where(newly, new_c, NO_COLOR),

@@ -131,7 +131,9 @@ def empty_lane(sc: ShapeClass) -> ipgc.IPGCGraph:
         hub_slot=jnp.full((sc.n_pad,), sc.nh_pad, jnp.int32),
         hub_ids=jnp.zeros((max(sc.nh_pad, 1),), jnp.int32),
         tail_start=jnp.zeros((sc.nh_pad + 1,), jnp.int32),
-        layout_kind=sc.kind)
+        layout_kind=sc.kind,
+        ell_wins=jnp.zeros((sc.n_pad, ipgc.wins_words(sc.k_pad)),
+                           jnp.uint32))
 
 
 # ---------------------------------------------------------------------------

@@ -127,13 +127,16 @@ def lose_flags(cu_e: jax.Array, cv_e: jax.Array, pu_e: jax.Array,
                pv_e: jax.Array, src_e: jax.Array,
                dst_e: jax.Array) -> jax.Array:
     """Entry (u, v) makes u lose iff ``c_v == c_u >= 0`` and v wins the
-    (priority, id) tie-break — THE predicate of ``ipgc._conflict_rows``."""
+    (priority, id) tie-break — per entry, what ``ipgc._won_rows`` does
+    per row."""
     return (cu_e >= 0) & (cu_e == cv_e) & wins(pu_e, pv_e, src_e, dst_e)
 
 
 def wins(pu_e: jax.Array, pv_e: jax.Array, src_e: jax.Array,
          dst_e: jax.Array) -> jax.Array:
-    """The destination wins the (priority, id) tie-break."""
+    """The destination wins the (priority, id) tie-break: THE predicate.
+    Static, so ``ipgc.prepare`` stores it per entry (``WINS_BIT`` on
+    csr-segment, ``IPGCGraph.ell_wins`` on the ELL kinds)."""
     return (pv_e > pu_e) | ((pv_e == pu_e) & (dst_e > src_e))
 
 

@@ -43,7 +43,7 @@ def any_rows(x: jax.Array) -> jax.Array:
 
 def loses(nc, npr, nid, cu, pu, uid) -> jax.Array:
     """(TR, 1) bool: some neighbour holds the row's color with a higher
-    (priority, id) pair — the tie-break of ``ipgc._conflict_rows``."""
+    (priority, id) pair — the tie-break ``csr_segment.wins``."""
     same = (nc == cu) & (cu >= 0)
     higher = (npr > pu) | ((npr == pu) & (nid > uid))
     return any_rows(same & higher)

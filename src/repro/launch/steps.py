@@ -495,7 +495,8 @@ def ipgc_case(arch: ArchSpec, shape: ShapeSpec, mesh, rules) -> Case:
         priority=_sds((n + 1,), jnp.int32), tail_src=_sds((t_pad,), jnp.int32),
         tail_dst=_sds((t_pad,), jnp.int32), tail_valid=_sds((t_pad,), bool),
         tail_slot=_sds((t_pad,), jnp.int32), hub_slot=_sds((n,), jnp.int32),
-        hub_ids=_sds((nh,), jnp.int32), tail_start=_sds((nh + 1,), jnp.int32))
+        hub_ids=_sds((nh,), jnp.int32), tail_start=_sds((nh + 1,), jnp.int32),
+        ell_wins=_sds((n, ipgc_mod.wins_words(k)), jnp.uint32))
     colors = _sds((n + 1,), jnp.int32)
     base = _sds((n,), jnp.int32)
     wl = Worklist(mask=_sds((n,), bool), items=_sds((n,), jnp.int32),
@@ -510,7 +511,7 @@ def ipgc_case(arch: ArchSpec, shape: ShapeSpec, mesh, rules) -> Case:
         ell_idx=_ns(mesh, dn, None), degrees=_ns(mesh, dn),
         priority=_ns(mesh), tail_src=_ns(mesh), tail_dst=_ns(mesh),
         tail_valid=_ns(mesh), tail_slot=_ns(mesh), hub_slot=_ns(mesh, dn),
-        hub_ids=_ns(mesh), tail_start=_ns(mesh))
+        hub_ids=_ns(mesh), tail_start=_ns(mesh), ell_wins=_ns(mesh, dn, None))
     wl_shard = Worklist(mask=_ns(mesh, dn), items=_ns(mesh, dn),
                         count=_ns(mesh))
     shards = (ig_shard, _ns(mesh), _ns(mesh, dn), wl_shard)

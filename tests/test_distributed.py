@@ -122,6 +122,32 @@ print("DIST_ENGINE_OK")
     assert "DIST_ENGINE_OK" in _run_forced_devices(code)
 
 
+def test_dist_two_phase_ell_kinds_multishard_subprocess():
+    """The two-phase dist steps read each shard's rows of the static
+    tie-break bits (``IPGCGraph.ell_wins``): on 4 simulated devices,
+    every ELL kind, under both exchange paths, reproduces the host
+    two-phase engine (colors, iterations, mode trace)."""
+    code = """
+import numpy as np
+from repro.core import color, color_distributed, verify_coloring
+from repro.graphs import get_dataset
+from repro.graphs.partition import prepare_partition
+for kind in ("pure-ell", "ell-tail", "hub-split"):
+    g = get_dataset("kron_g500-logn21_s", scale=0.01, layout=kind,
+                    **({} if kind == "pure-ell" else {"ell_cap": 16}))
+    g2, relabel = prepare_partition(g, 4)
+    r_h = color(g2, mode="hybrid", fused=False, outline=False)
+    for ex in ("dense", "auto"):
+        r = color_distributed(g, n_shards=4, fused=False, exchange=ex)
+        verify_coloring(g, r.colors, context=f"{kind}/{ex}")
+        np.testing.assert_array_equal(r.colors, r_h.colors[relabel[:g.n_nodes]])
+        assert (r.iterations, r.mode_trace) == (r_h.iterations,
+                                                r_h.mode_trace), (kind, ex)
+print("ELL_KINDS_OK")
+"""
+    assert "ELL_KINDS_OK" in _run_forced_devices(code, n_devices=4)
+
+
 def test_dist_engine_full_run_valid():
     g = make_graph("hollywood-2009_s", scale=0.02)
     ig = ipgc.prepare(g)
@@ -319,9 +345,9 @@ g2, relabel = prepare_partition(g, 4)
 ig = ipgc.prepare(g2)
 sg = shard_graph(ig, jax.make_mesh((4,), ("data",)), ("data",))
 # shard 0's operands, as its local step sees them
-ell, hub_slot, prio, tails = jax.tree.map(np.asarray, sg.arrays)
+ell, wins, hub_slot, prio, tails = jax.tree.map(np.asarray, sg.arrays)
 view = _local_graph_view(ig, sg, ig.n_nodes, jax.tree.map(jnp.asarray, (
-    ell[:ig.n_nodes // 4], hub_slot, prio,
+    ell[:ig.n_nodes // 4], wins[:ig.n_nodes // 4], hub_slot, prio,
     tuple(a[:a.shape[0] // 4] for a in tails))))
 assert ipgc._hub_packed(view, jnp.zeros((1024,), jnp.int32)) is not None
 for fused in (True, False):

@@ -159,3 +159,43 @@ def test_dist_steps_compile_for_v5e_2x2(topo, monkeypatch, exchange):
         hlo = step.lower(colors, shape((n,), jnp.int32, "data"), wl,
                          **kw).compile().as_text()
         assert "all-reduce" in hlo or "all-gather" in hlo
+
+
+@pytest.mark.parametrize("step", ["dense", "sparse"])
+def test_ell_two_phase_steps_gather_no_priorities_for_v5e(one_chip, step):
+    """The two-phase pure-ELL steps, compiled for the chip at R rows of
+    width 8 (the road graph's ELL width), gather neighbour colors and no
+    priorities: the tie-break is the static ``ell_wins`` bits. The dense
+    step holds exactly its two (R, 8) tile gathers (assign, resolve); the
+    sparse step at a 2^19-row bucket three (C, 8) ones (its ELL rows,
+    assign, resolve) and no priority tile."""
+    import re
+
+    from repro.core import ipgc
+    from repro.core.worklist import Worklist
+
+    k, cap = 8, 2 ** 19
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    i32 = jnp.int32
+    ig = ipgc.IPGCGraph(
+        n_nodes=R, ell_width=k, n_hub=0, ell_idx=sds((R, k), i32),
+        degrees=sds((R,), i32), priority=sds((R + 1,), i32),
+        tail_src=sds((8,), i32), tail_dst=sds((8,), i32),
+        tail_valid=sds((8,), jnp.bool_), tail_slot=sds((8,), i32),
+        hub_slot=sds((R,), i32), hub_ids=sds((1,), i32),
+        tail_start=sds((1,), i32), layout_kind="pure-ell",
+        ell_wins=sds((R, 1), jnp.uint32))
+    rows = R if step == "dense" else cap
+    wl = Worklist(mask=sds((R,), jnp.bool_), items=sds((rows,), i32),
+                  count=sds((), i32))
+    fn = ipgc.dense_step_impl if step == "dense" else ipgc.sparse_step_impl
+    hlo = jax.jit(fn, static_argnames=("window", "impl")).lower(
+        ig, sds((R + 1,), i32), sds((R,), i32), wl, window=32,
+        impl="jnp").compile().as_text()
+    gathers = re.findall(r"= \w+\[([\d,]*)\]\{[^}]*\} gather\(", hlo)
+    if step == "dense":
+        assert gathers == [f"{R},{k}"] * 2, gathers
+    else:
+        assert gathers.count(f"{cap},{k}") == 3, gathers
