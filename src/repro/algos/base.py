@@ -108,11 +108,18 @@ class Algorithm:
         raise NotImplementedError
 
     def step_fns(self, fused: bool):
-        """(dense, sparse) jitted step pair for the host-loop Pipe. The
-        sparse step is the ``ipgc.tallied`` form of the sparse impl: it
-        also returns ``int32[2]``, the worklist count after it and the
-        live entries of its rows, read back in one transfer."""
+        """(dense, sparse) jitted step pair for the host-loop Pipe: the
+        ``ipgc.tallied`` forms of the step impls. Each also returns
+        ``int32[2]``, the worklist count after it and the live entries
+        of its rows, read back in one transfer."""
         raise NotImplementedError
+
+    def dense_slots(self, ig: ipgc.IPGCGraph,
+                    force_hub: bool | None) -> int:
+        """Adjacency entries the dense step gathers, live or not
+        (static). The default is the IPGC steps' rule
+        (``ipgc.dense_slots``)."""
+        return ipgc.dense_slots(ig, force_hub)
 
     def sparse_slots(self, ig: ipgc.IPGCGraph, capacity: int,
                      force_hub: bool | None) -> int:

@@ -31,7 +31,8 @@ class IPGC(Algorithm):
                 if fused else (ipgc.dense_step_impl, ipgc.sparse_step_impl))
 
     def step_fns(self, fused: bool):
-        return ipgc.step_fns(fused)[0], ipgc.tallied(self.step_impls(fused)[1])
+        dense, sparse = self.step_impls(fused)
+        return ipgc.tallied(dense, dense=True), ipgc.tallied(sparse)
 
     def make_dist_steps(self, ig_local, mesh, node_axes, *, window: int,
                         fused: bool, exchange: str = "dense", boundary=None,

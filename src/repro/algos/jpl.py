@@ -174,10 +174,6 @@ def jpl_sparse_step_impl(ig: ipgc.IPGCGraph, colors: jax.Array,
     return colors2, rnd + 1, Worklist(mask=mask, items=new_items, count=count)
 
 
-_JPL_STATICS = ("window", "impl", "force_hub", "tile_rows")
-jpl_dense_step = jax.jit(jpl_dense_step_impl, static_argnames=_JPL_STATICS)
-
-
 # ---------------------------------------------------------------------------
 # distributed (shard_map) JPL rounds
 # ---------------------------------------------------------------------------
@@ -367,7 +363,12 @@ class JPL(Algorithm):
         return jpl_dense_step_impl, jpl_sparse_step_impl
 
     def step_fns(self, fused: bool):
-        return jpl_dense_step, ipgc.tallied(jpl_sparse_step_impl)
+        return (ipgc.tallied(jpl_dense_step_impl, dense=True),
+                ipgc.tallied(jpl_sparse_step_impl))
+
+    def dense_slots(self, ig, force_hub):
+        # the ELL path under every plan (csr-segment too): all N rows
+        return self.sparse_slots(ig, ig.n_nodes, force_hub)
 
     def sparse_slots(self, ig, capacity, force_hub):
         # no packing: the ELL rows of the worklist, and the whole hub tail

@@ -51,7 +51,8 @@ class SpecGreedy(Algorithm):
         return ipgc.fused_dense_step_impl, ipgc.fused_sparse_step_impl
 
     def step_fns(self, fused: bool):
-        return ipgc.step_fns(True)[0], ipgc.tallied(self.step_impls(True)[1])
+        dense, sparse = self.step_impls(True)
+        return ipgc.tallied(dense, dense=True), ipgc.tallied(sparse)
 
     def resolve_fused(self, fused, *, default):
         return True                       # deferred repair IS the algorithm

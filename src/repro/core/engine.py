@@ -103,9 +103,12 @@ class ColoringResult:
     exchange_bytes: list = dataclasses.field(default_factory=list)
     # host regime only (empty elsewhere): per 'S' of mode_trace, the
     # adjacency entries of the rows the sparse step ran (live) and the
-    # entries it gathered to do so, padding included (ipgc.sparse_slots)
+    # entries it gathered to do so, padding included (ipgc.sparse_slots);
+    # per 'D' the same of the dense step (ipgc.dense_slots)
     sparse_entries: list = dataclasses.field(default_factory=list)
     sparse_slots: list = dataclasses.field(default_factory=list)
+    dense_entries: list = dataclasses.field(default_factory=list)
+    dense_slots: list = dataclasses.field(default_factory=list)
 
 
 def resolve_plan(g, layout):

@@ -1071,7 +1071,7 @@ def step_fns(fused: bool):
 
 
 # ---------------------------------------------------------------------------
-# what a sparse step gathers: the live entries of its rows, and its slots
+# what a step gathers: the live entries of its rows, and its slots
 # ---------------------------------------------------------------------------
 
 def live_entries(ig: IPGCGraph, items: jax.Array) -> jax.Array:
@@ -1081,6 +1081,12 @@ def live_entries(ig: IPGCGraph, items: jax.Array) -> jax.Array:
     valid = items < n
     return jnp.sum(jnp.where(valid, ig.degrees[jnp.where(valid, items, 0)],
                              0), dtype=jnp.int32)
+
+
+def mask_entries(ig: IPGCGraph, mask: jax.Array) -> jax.Array:
+    """int32[] adjacency entries of the rows set in a worklist mask: what
+    a dense step, which runs exactly those rows, has to read."""
+    return jnp.sum(jnp.where(mask, ig.degrees, 0), dtype=jnp.int32)
 
 
 def sparse_slots(ig: IPGCGraph, capacity: int,
@@ -1100,15 +1106,27 @@ def sparse_slots(ig: IPGCGraph, capacity: int,
     return slots
 
 
+def dense_slots(ig: IPGCGraph, force_hub: bool | None = None) -> int:
+    """Adjacency entries a dense step gathers, live or not (static): on
+    csr-segment the padded edge array; on the ELL path every row's ELL
+    slots, plus the whole hub tail where the step reads it."""
+    if ig.layout_kind == "csr-segment":
+        return ig.edge_dst.shape[0]
+    tail = ig.tail_dst.shape[0] if _has_hubs(ig, force_hub) else 0
+    return ig.n_nodes * ig.ell_width + tail
+
+
 @functools.cache
-def tallied(sparse_impl):
-    """The host loop's jitted form of a sparse step impl: the same step,
-    which also returns ``int32[2]`` = (the worklist count after the step,
-    the live entries of the rows it ran), read back in one transfer. The
-    program keeps the impl's name."""
-    @functools.wraps(sparse_impl)
+def tallied(step_impl, *, dense: bool = False):
+    """The host loop's jitted form of a step impl: the same step, which
+    also returns ``int32[2]`` = (the worklist count after the step, the
+    live entries of the rows it ran: those of ``wl.mask`` for a
+    ``dense`` step, of ``wl.items`` for a sparse one), read back in one
+    transfer. The program keeps the impl's name."""
+    @functools.wraps(step_impl)
     def step(ig, colors, aux, wl, **statics):
-        live = live_entries(ig, wl.items)
-        colors, aux, wl = sparse_impl(ig, colors, aux, wl, **statics)
+        live = (mask_entries(ig, wl.mask) if dense
+                else live_entries(ig, wl.items))
+        colors, aux, wl = step_impl(ig, colors, aux, wl, **statics)
         return colors, aux, wl, jnp.stack([wl.count, live])
     return jax.jit(step, static_argnames=_STEP_STATICS)

@@ -496,41 +496,38 @@ class Session:
         trace: list[str] = []
         counts: list[int] = []
         tti: list[float] = []
-        entries: list[int] = []
-        slots: list[int] = []
+        # per mode, the live entries and the slots of each step
+        entries: dict[str, list[int]] = {"D": [], "S": []}
+        slots: dict[str, list[int]] = {"D": [], "S": []}
+        d_slots = alg.dense_slots(ig, force_hub)
         t_start = time.perf_counter()
         it = 0
         while count > 0 and it < spec.max_iter:
             use_dense = bool(pol(count, n))
+            mode = "D" if use_dense else "S"
             counts.append(count)
-            with obs_trace.maybe_span(
-                    "session.iter", mode="D" if use_dense else "S",
-                    count=count), Timer() as t:
+            with obs_trace.maybe_span("session.iter", mode=mode,
+                                      count=count), Timer() as t:
                 if use_dense:
-                    with obs_trace.maybe_span("session.dispatch"):
-                        colors, aux, wl = dense_fn(
-                            ig, colors, aux, wl, window=window,
-                            impl=spec.impl, force_hub=force_hub,
-                            tile_rows=tile_rows)
-                    with obs_trace.maybe_span("session.readback"):
-                        count = int(wl.count)  # the Pipe's one read-back
+                    step_fn = dense_fn
+                    slots["D"].append(d_slots)
                 else:
+                    step_fn = sparse_fn
                     cap = pick_bucket(caps, count)
                     if wl.capacity > cap:
                         with obs_trace.maybe_span("session.resize"):
                             wl = resize_items(wl, cap, n)
-                    slots.append(alg.sparse_slots(ig, wl.capacity,
-                                                  force_hub))
-                    with obs_trace.maybe_span("session.dispatch"):
-                        colors, aux, wl, tally = sparse_fn(
-                            ig, colors, aux, wl, window=window,
-                            impl=spec.impl, force_hub=force_hub,
-                            tile_rows=tile_rows)
-                    with obs_trace.maybe_span("session.readback"):
-                        # count and live entries: the Pipe's one read-back
-                        count, live = (int(v) for v in np.asarray(tally))
-                    entries.append(live)
-            trace.append("D" if use_dense else "S")
+                    slots["S"].append(alg.sparse_slots(ig, wl.capacity,
+                                                       force_hub))
+                with obs_trace.maybe_span("session.dispatch"):
+                    colors, aux, wl, tally = step_fn(
+                        ig, colors, aux, wl, window=window, impl=spec.impl,
+                        force_hub=force_hub, tile_rows=tile_rows)
+                with obs_trace.maybe_span("session.readback"):
+                    # count and live entries: the Pipe's one read-back
+                    count, live = (int(v) for v in np.asarray(tally))
+                entries[mode].append(live)
+            trace.append(mode)
             if meter is not None:
                 meter.add(t.seconds)
             if collect_tti:
@@ -545,8 +542,11 @@ class Session:
         return ColoringResult(colors=final, n_colors=n_colors, iterations=it,
                               mode_trace="".join(trace), counts=counts,
                               tti=tti, total_seconds=total,
-                              host_dispatches=it, sparse_entries=entries,
-                              sparse_slots=slots)
+                              host_dispatches=it,
+                              sparse_entries=entries["S"],
+                              sparse_slots=slots["S"],
+                              dense_entries=entries["D"],
+                              dense_slots=slots["D"])
 
     # -- device-resident outlined Pipe ---------------------------------------
 

@@ -1,5 +1,6 @@
 """The host loop's counter of what each sparse step gathers
-(``ColoringResult.sparse_entries`` / ``sparse_slots``), recomputed by
+(``ColoringResult.sparse_entries`` / ``sparse_slots``; the dense steps'
+``dense_entries`` / ``dense_slots`` where JPL's rule differs), recomputed by
 stepping the same coloring by hand: the live entries are the degrees of
 the worklist's rows before the step, the slots the size of the buffers
 the step builds (``ipgc._packed``), or every entry where it sweeps."""
@@ -126,8 +127,24 @@ def test_jpl_counts_the_whole_tail():
     assert all(0 < e <= s for e, s in zip(got.sparse_entries, want))
 
 
+@pytest.mark.parametrize("layout,ell_cap", [("csr-segment", None),
+                                            ("ell-tail", 8)])
+def test_jpl_dense_steps_read_the_ell_path(layout, ell_cap):
+    """JPL's dense round reads every row's ELL slots and the whole hub
+    tail under any plan, csr-segment included."""
+    alg = get_algorithm("jpl")
+    ig = alg.prepare(_rgg(layout, ell_cap))
+    got = Session().run(ExecutionSpec(regime="host", algo="jpl"), ig)
+    tail = ig.tail_dst.shape[0] if ig.n_hub else 0
+    want = ig.n_nodes * ig.ell_width + tail
+    assert got.dense_slots == [want] * got.mode_trace.count("D") != []
+    assert got.dense_entries[0] == int(np.asarray(ig.degrees).sum())
+    assert all(0 < e <= want for e in got.dense_entries)
+
+
 def test_other_regimes_leave_the_counter_empty():
     g = _rgg("ell-tail", 8)
     got = Session().run(ExecutionSpec(regime="outlined", window=W), g)
-    assert "S" in got.mode_trace
+    assert "S" in got.mode_trace and "D" in got.mode_trace
     assert got.sparse_entries == [] and got.sparse_slots == []
+    assert got.dense_entries == [] and got.dense_slots == []
