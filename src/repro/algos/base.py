@@ -121,12 +121,13 @@ class Algorithm:
         (``ipgc.dense_slots``)."""
         return ipgc.dense_slots(ig, force_hub)
 
-    def sparse_slots(self, ig: ipgc.IPGCGraph, capacity: int,
+    def sparse_slots(self, ig: ipgc.IPGCGraph, capacity: int, live: int,
                      force_hub: bool | None) -> int:
         """Adjacency entries the sparse step gathers at worklist capacity
-        ``capacity``, live or not (static). The default is the IPGC
-        steps' rule, packing included (``ipgc.sparse_slots``)."""
-        return ipgc.sparse_slots(ig, capacity, force_hub)
+        ``capacity`` over rows that own ``live`` entries, live or not.
+        The default is the IPGC steps' rule, packing included
+        (``ipgc.sparse_slots``)."""
+        return ipgc.sparse_slots(ig, capacity, live, force_hub)
 
     def resolve_fused(self, fused: bool | None, *, default: bool) -> bool:
         """Map the caller's ``fused`` request (None = engine default) to

@@ -368,9 +368,9 @@ class JPL(Algorithm):
 
     def dense_slots(self, ig, force_hub):
         # the ELL path under every plan (csr-segment too): all N rows
-        return self.sparse_slots(ig, ig.n_nodes, force_hub)
+        return self.sparse_slots(ig, ig.n_nodes, 0, force_hub)
 
-    def sparse_slots(self, ig, capacity, force_hub):
+    def sparse_slots(self, ig, capacity, live, force_hub):
         # no packing: the ELL rows of the worklist, and the whole hub tail
         tail = ig.tail_dst.shape[0] if ipgc._has_hubs(ig, force_hub) else 0
         return capacity * ig.ell_width + tail

@@ -602,12 +602,10 @@ def _csr_step(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
         LAUNCH_COUNTS["conflict"] += 1
         LAUNCH_COUNTS["compact"] += 1
     if sparse:
-        pk = _packed(ig, wl.items, ig.edge_dst,
-                     lambda rows: _row_start(ig.degrees)[rows],
-                     lambda rows: ig.degrees[rows], keyed=True)
-        if pk is not None:
-            return _packed_csr_step(ig, colors, base, wl, pk, window=window,
-                                    fused=fused)
+        cap = packed_cap(ig, wl.capacity, ig.edge_dst.shape[0])
+        if cap is not None:
+            return _packed_csr_step(ig, colors, base, wl, window=window,
+                                    fused=fused, chunk=packed_chunk(cap))
     core = _csr_fused_core if fused else _csr_two_phase_core
     colors2, base2, still = core(ig, colors, base, wl.mask, window=window)
     emit = _csr_emit_sparse if sparse else _csr_emit_dense
@@ -618,12 +616,22 @@ def _csr_step(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
 # packed sparse passes — only the entries of the worklist's rows
 # ---------------------------------------------------------------------------
 # A data-driven step at capacity C needs the edge entries (csr-segment) or
-# tail entries (hub side-channel) of its C rows only. ``_packed`` lays them
-# out contiguously in a static buffer sized by the graph's ``seg_bound``
-# (the most entries any C rows own); when that bound is over half of all
-# entries the step sweeps them all instead. Per row the result is the
-# same segment reduction over the same entries, so the two forms are
-# bit-identical — only the amount of gathered data differs.
+# tail entries (hub side-channel) of its C rows only. The graph's
+# ``seg_bound`` (the most entries any C rows own) decides whether the step
+# packs them (``packed_cap``); when that bound is over half of all entries
+# the step sweeps them all instead. The csr-segment step reads its rows'
+# edges in chunks of ``PACKED_CHUNK`` slots, as many as their live entries
+# fill (``kcsr.packed_row_words``); the hub side-channel lays its rows'
+# tail entries out in one static buffer of ``packed_cap`` slots
+# (``_hub_packed``). Per row the result is the same segment reduction over
+# the same entries, so every form is bit-identical — only the amount of
+# gathered data differs.
+
+# Slots a packed csr-segment pass reads per trip of its chunk loop. On a
+# v5e a trip costs about 45 us besides 22 ns a slot, and a step wastes
+# half a chunk past its live entries on average: 2^15-2^17 colored the
+# kron cell within 3% of each other, 2^16 fastest (PERF.md section 6).
+PACKED_CHUNK = 2 ** 16
 
 @dataclasses.dataclass(frozen=True)
 class _Packed:
@@ -644,10 +652,11 @@ class _Packed:
 
 
 def packed_cap(ig: IPGCGraph, capacity: int, m: int) -> "int | None":
-    """Slots of the static buffer into which a packed pass at worklist
-    capacity ``capacity`` lays its rows' entries, out of ``m`` entries;
-    None where the graph has no bound or the buffer would pass half of
-    the entries: the pass then sweeps all ``m``."""
+    """Static bound on the entries the rows of a packed pass at worklist
+    capacity ``capacity`` own, out of ``m`` entries: the size of the hub
+    side-channel's buffer, and the most slots the csr-segment chunk loop
+    reads. None where the graph has no bound or the bound would pass half
+    of the entries: the pass then sweeps all ``m``."""
     if not ig.seg_bound:
         return None
     k = min(max(capacity - 1, 0).bit_length(), len(ig.seg_bound) - 1)
@@ -655,31 +664,10 @@ def packed_cap(ig: IPGCGraph, capacity: int, m: int) -> "int | None":
     return None if 2 * cap > m else cap
 
 
-def _packed(ig: IPGCGraph, items: jax.Array, entry_dst: jax.Array,
-            starts_of, counts_of, *, keyed: bool) -> "_Packed | None":
-    """Pack the entries of the rows in ``items`` (``None``: no static
-    bound small enough — sweep every entry). ``keyed`` entries carry the
-    tie-break in their sign bit (csr-segment); otherwise it is computed
-    from the priorities."""
-    m = entry_dst.shape[0]
-    cap = packed_cap(ig, items.shape[0], m)
-    if cap is None:
-        return None
-    n = ig.n_nodes
-    valid = items < n
-    rows = jnp.where(valid, items, 0)
-    counts = jnp.where(valid, counts_of(rows), 0)
-    pos, off, live = kcsr.packed_ranges(starts_of(rows), counts, cap)
-    src = kcsr.spread(rows, off, cap)
-    dst = entry_dst[jnp.clip(pos, 0, m - 1)]
-    if keyed:
-        dst, wins = kcsr.split_key(dst)
-    else:
-        wins = kcsr.wins(kcsr.spread(ig.priority[rows], off, cap),
-                         ig.priority[dst], src, dst)
-    return _Packed(rows=rows, off=off, nonempty=counts > 0,
-                   dst=jnp.where(live, dst, n), wins=wins, src=src,
-                   max_run=_max_run(ig))
+def packed_chunk(cap: int) -> int:
+    """Slots a trip of the csr-segment chunk loop reads, for a step whose
+    rows own at most ``cap`` entries: no more than ``cap`` itself."""
+    return min(PACKED_CHUNK, cap)
 
 
 def _packed_forbidden(pk: _Packed, ec: jax.Array, base_rows: jax.Array,
@@ -700,33 +688,76 @@ def _packed_lose(pk: _Packed, cv: jax.Array, c_rows: jax.Array,
 
 
 def _hub_packed(ig: IPGCGraph, items: jax.Array) -> "_Packed | None":
-    """Pack the tail entries of the hub rows in ``items``."""
-    ts, nh = ig.tail_start, ig.n_hub
-
-    def slot(rows):
-        return jnp.minimum(ig.hub_slot[rows], nh)
-
-    return _packed(ig, items, ig.tail_dst, lambda rows: ts[slot(rows)],
-                   lambda rows: (ts[jnp.minimum(slot(rows) + 1, nh)]
-                                 - ts[slot(rows)]), keyed=False)
+    """Pack the tail entries of the hub rows in ``items`` into a static
+    buffer of ``packed_cap`` slots (``None``: no static bound small
+    enough — sweep the whole tail). The tie-break comes from the
+    priorities."""
+    m = ig.tail_dst.shape[0]
+    cap = packed_cap(ig, items.shape[0], m)
+    if cap is None:
+        return None
+    n, ts, nh = ig.n_nodes, ig.tail_start, ig.n_hub
+    valid = items < n
+    rows = jnp.where(valid, items, 0)
+    slot = jnp.minimum(ig.hub_slot[rows], nh)
+    counts = jnp.where(valid, ts[jnp.minimum(slot + 1, nh)] - ts[slot], 0)
+    pos, off, live = kcsr.packed_ranges(ts[slot], counts, cap)
+    src = kcsr.spread(rows, off, cap)
+    dst = ig.tail_dst[jnp.clip(pos, 0, m - 1)]
+    wins = kcsr.wins(kcsr.spread(ig.priority[rows], off, cap),
+                     ig.priority[dst], src, dst)
+    return _Packed(rows=rows, off=off, nonempty=counts > 0,
+                   dst=jnp.where(live, dst, n), wins=wins, src=src,
+                   max_run=_max_run(ig))
 
 
 def _packed_csr_step(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
-                     wl: Worklist, pk: _Packed, *, window: int, fused: bool
+                     wl: Worklist, *, window: int, fused: bool, chunk: int
                      ) -> tuple[jax.Array, jax.Array, Worklist]:
-    """The csr-segment sparse step over the packed edges of its rows."""
+    """The csr-segment sparse step over the edges of its rows only, read
+    in chunks of ``chunk`` slots of their packed order: one chunk loop
+    for the forbidden words, which keeps the edges it gathered, and one,
+    after the new colors land, for the winners' words over the kept
+    edges (one loop with both where ``fused``)."""
     n = ig.n_nodes
+    m = ig.edge_dst.shape[0]
     items = wl.items
     valid = items < n
+    rows = jnp.where(valid, items, 0)
     ids = jnp.where(valid, items, n)
-    base_rows = base[pk.rows]
-    cu = jnp.where(valid, colors[pk.rows], PAD_COLOR)
-    ec = _gather_neighbor_colors(colors, pk.dst)
+    counts = jnp.where(valid, ig.degrees[rows], 0)
+    starts = _row_start(ig.degrees)[rows]
+    base_rows = base[rows]
+    cu = jnp.where(valid, colors[rows], PAD_COLOR)
+    nw = -(-window // 32)
+
+    def edge_of(pos, live):
+        return jnp.where(live, ig.edge_dst[jnp.clip(pos, 0, m - 1)], n)
+
+    def words_of(colors_now, forbidden: bool, winners: bool):
+        def words(key, base_e):
+            dst, wins = kcsr.split_key(key)
+            c = _gather_neighbor_colors(colors_now, dst)
+            return ((kcsr.window_words(c, base_e, window) if forbidden
+                     else ())
+                    + (kcsr.window_words(jnp.where(wins, c, -1), base_e,
+                                         window) if winners else ()))
+        return words
+
+    def lose_of(words, c_rows):
+        return (c_rows >= 0) & kcsr.window_bit(words, c_rows - base_rows)
+
+    def packed(colors_now, forbidden: bool, winners: bool, keep: int = 0):
+        return kcsr.packed_row_words(
+            starts, counts, (base_rows,), edge_of,
+            words_of(colors_now, forbidden, winners),
+            nw * (forbidden + winners), chunk, _max_run(ig), keep)
+
     if fused:
         pending = valid & (cu >= 0)
-        lose = _packed_lose(pk, ec, jnp.where(pending, cu, NO_COLOR),
-                            base_rows, window)
-        free = ~_packed_forbidden(pk, ec, base_rows, window)
+        words = packed(colors, True, True)
+        lose = lose_of(words[nw:], jnp.where(pending, cu, NO_COLOR))
+        free = ~kcsr.words_bitmap(words[:nw], window)
         has = free.any(axis=1)
         first = jnp.argmax(free, axis=1).astype(jnp.int32)
         still = lose | (valid & (cu < 0))
@@ -734,13 +765,15 @@ def _packed_csr_step(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
                           jnp.where(lose, NO_COLOR, cu))
         new_base = jnp.where(still & ~has, base_rows + window, base_rows)
     else:
-        forb = _packed_forbidden(pk, ec, base_rows, window)
-        new_c, new_base, newly = _mex_from_forbidden(forb, valid, base_rows,
-                                                     cu, window)
+        # the rows own at most packed_cap entries: keep that many slots
+        keep = -(-packed_cap(ig, wl.capacity, m) // chunk) * chunk
+        forb, kept = packed(colors, True, False, keep)
+        new_c, new_base, newly = _mex_from_forbidden(
+            kcsr.words_bitmap(forb, window), valid, base_rows, cu, window)
         colors2 = colors.at[ids].set(jnp.where(valid, new_c, PAD_COLOR))
-        cv = _gather_neighbor_colors(colors2, pk.dst)
-        lose = _packed_lose(pk, cv, jnp.where(newly, new_c, NO_COLOR),
-                            base_rows, window)
+        lose = lose_of(kcsr.kept_row_words(
+            counts, kept, words_of(colors2, False, True), nw, chunk,
+            _max_run(ig)), jnp.where(newly, new_c, NO_COLOR))
         new_c = jnp.where(lose, NO_COLOR, new_c)
         still = lose | (valid & ~newly)
     colors2 = colors.at[ids].set(jnp.where(valid, new_c, PAD_COLOR))
@@ -1089,16 +1122,21 @@ def mask_entries(ig: IPGCGraph, mask: jax.Array) -> jax.Array:
     return jnp.sum(jnp.where(mask, ig.degrees, 0), dtype=jnp.int32)
 
 
-def sparse_slots(ig: IPGCGraph, capacity: int,
+def sparse_slots(ig: IPGCGraph, capacity: int, live: int,
                  force_hub: bool | None = None) -> int:
     """Adjacency entries a sparse step at worklist capacity ``capacity``
-    gathers, live or not (static, so the host counts it): on csr-segment
-    the packed buffer, or every edge entry where the step sweeps them; on
-    the ELL path ``capacity`` rows of the ELL width, plus the hub tail's
-    packed buffer, or the whole tail where the step sweeps it."""
+    whose rows own ``live`` entries gathers, live or not: on csr-segment
+    the chunks its live entries fill (``ceil(live / chunk) * chunk``), or
+    every edge entry where the step sweeps them; on the ELL path
+    ``capacity`` rows of the ELL width, plus the hub tail's packed
+    buffer, or the whole tail where the step sweeps it."""
     if ig.layout_kind == "csr-segment":
         m = ig.edge_dst.shape[0]
-        return packed_cap(ig, capacity, m) or m
+        cap = packed_cap(ig, capacity, m)
+        if cap is None:
+            return m
+        chunk = packed_chunk(cap)
+        return -(-live // chunk) * chunk
     slots = capacity * ig.ell_width
     if _has_hubs(ig, force_hub):
         t = ig.tail_dst.shape[0]

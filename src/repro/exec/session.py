@@ -510,15 +510,13 @@ class Session:
                                       count=count), Timer() as t:
                 if use_dense:
                     step_fn = dense_fn
-                    slots["D"].append(d_slots)
                 else:
                     step_fn = sparse_fn
                     cap = pick_bucket(caps, count)
                     if wl.capacity > cap:
                         with obs_trace.maybe_span("session.resize"):
                             wl = resize_items(wl, cap, n)
-                    slots["S"].append(alg.sparse_slots(ig, wl.capacity,
-                                                       force_hub))
+                capacity = wl.capacity
                 with obs_trace.maybe_span("session.dispatch"):
                     colors, aux, wl, tally = step_fn(
                         ig, colors, aux, wl, window=window, impl=spec.impl,
@@ -527,6 +525,10 @@ class Session:
                     # count and live entries: the Pipe's one read-back
                     count, live = (int(v) for v in np.asarray(tally))
                 entries[mode].append(live)
+                # a sparse step's slots follow its live entries (csr-segment)
+                slots[mode].append(
+                    d_slots if use_dense
+                    else alg.sparse_slots(ig, capacity, live, force_hub))
             trace.append(mode)
             if meter is not None:
                 meter.add(t.seconds)

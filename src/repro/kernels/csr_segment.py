@@ -19,9 +19,12 @@ per update on a v5e at E = 63.5M) and XLA's ``cumsum`` and bool
 scatter-max take 10-25 s each to compile at that size; the doubling passes
 stream at memory bandwidth and compile in about a second.
 
-``packed_ranges`` lays the entries of a *subset* of owners (a sparse
-step's worklist) out contiguously in a static-capacity buffer, so a
-data-driven step touches only the entries of its active rows.
+A data-driven step touches only the entries of a *subset* of owners
+(its worklist). ``packed_row_words`` walks them in their packed order, in
+fixed-size chunks, as many as the live entries fill, and can keep what
+it read for a second pass (``kept_row_words``; the csr-segment sparse
+step); ``packed_ranges`` lays them out in one static-capacity buffer
+(the hub side-channel).
 
 Padding contract: pad entries point at the sentinel slot ``N`` (``dst ==
 N``), whose color is ``PAD_COLOR`` (-2): it never lands in a window and
@@ -60,7 +63,14 @@ def _doubling(body, init, length: int):
 
 def prefix_sum(x: jax.Array) -> jax.Array:
     """Inclusive prefix sum of a 1-D int32 array (exact modulo 2^32)."""
-    return _doubling(lambda s, y: y + _shift(y, s), x, x.shape[0])
+    (out,) = _prefix_sums((x,))
+    return out
+
+
+def _prefix_sums(xs: tuple) -> tuple:
+    """``prefix_sum`` of equal-length arrays in one doubling loop."""
+    return _doubling(lambda s, ys: tuple(y + _shift(y, s) for y in ys),
+                     xs, xs[0].shape[0])
 
 
 def spread(v: jax.Array, starts: jax.Array, m: int) -> jax.Array:
@@ -70,15 +80,31 @@ def spread(v: jax.Array, starts: jax.Array, m: int) -> jax.Array:
     ascending from 0: an exclusive prefix sum of the owners' entry
     counts; empty owners are allowed). Entry ``j`` gets the value of the
     last owner starting at or before ``j``; entries past the last owner's
-    range keep its value. Each owner adds the difference to the previous
-    owner's value at its start (a sorted scatter: XLA compiles an
-    unsorted one into an array this large in 13 s for a TPU), and a
-    prefix sum adds them up: exact for int32 (differences wrap)."""
-    v = v.astype(jnp.int32)
-    d = v - jnp.concatenate([jnp.zeros((1,), v.dtype), v[:-1]])
-    marks = jnp.zeros((m,), jnp.int32).at[starts].add(
-        d, mode="drop", indices_are_sorted=True)
-    return prefix_sum(marks)
+    range keep its value."""
+    (out,) = spread_chunk((v,), starts, m, 0)
+    return out
+
+
+def spread_chunk(vals: tuple, starts: jax.Array, m: int, s0) -> tuple:
+    """``spread`` of each of ``vals`` restricted to entries
+    ``[s0, s0 + m)`` (``s0`` may be traced): entry ``s0 + j`` of the
+    whole spread lands at ``j``.
+
+    Each owner adds the difference to the previous owner's value at its
+    start (a sorted scatter: XLA compiles an unsorted one into an array
+    of millions in 13 s for a TPU), and one prefix sum adds them up:
+    exact for int32 (differences wrap). Owners starting at or before
+    ``s0`` all land on entry 0, where their differences add up to the
+    value of the owner that straddles ``s0``; owners starting past the
+    chunk drop out."""
+    at = jnp.clip(starts - s0, 0, m)
+
+    def marks(v):
+        v = v.astype(jnp.int32)
+        d = v - jnp.concatenate([jnp.zeros((1,), v.dtype), v[:-1]])
+        return jnp.zeros((m,), jnp.int32).at[at].add(
+            d, mode="drop", indices_are_sorted=True)
+    return _prefix_sums(tuple(marks(v) for v in vals))
 
 
 def segment_or(vals: tuple, seg: jax.Array, max_run: int = 0) -> tuple:
@@ -217,6 +243,94 @@ def edge_fused(es: jax.Array, ec: jax.Array, wins_e: jax.Array,
         es, row_start, row_deg > 0, max_run)
     lose = (c_rows >= 0) & window_bit(words[nw:], c_rows - base_rows)
     return lose, words_bitmap(words[:nw], window)
+
+
+def packed_row_words(starts: jax.Array, counts: jax.Array, vals: tuple,
+                     entry_of, words_of, n_words: int, chunk: int,
+                     max_run: int = 0, keep: int = 0):
+    """Per-owner words: the OR of ``words_of`` over each owner's entries,
+    read in chunks of ``chunk`` slots of their packed order.
+
+    Owner ``i`` contributes ``counts[i]`` entries starting at entry
+    ``starts[i]``; packed in owner order they fill ``total =
+    sum(counts)`` slots, and the loop reads ``ceil(total / chunk)``
+    chunks of them, whatever bound the caller holds on ``total``. In a
+    chunk, ``entry_of(pos, live)`` maps each slot's entry index, and
+    whether the slot holds an entry (slots past ``total`` do not), to an
+    int32 per slot (say, the gathered entry), and ``words_of(entry,
+    *spread_vals)`` makes ``n_words`` uint32 words per slot of it and of
+    ``vals`` (per-owner int32 arrays) spread over the chunk. The words
+    are segment-ORed within the chunk, and each owner the chunk meets ORs
+    in the word at its first slot there. ``max_run`` bounds ``counts``
+    (see ``segment_or``).
+
+    ``keep`` > 0 (a multiple of ``chunk`` no smaller than ``total``)
+    also returns what the chunks read, ``(entry, owner, *spread_vals)``
+    in ``keep``-slot buffers, for ``kept_row_words`` to read again
+    without packing: ``(words, kept)``.
+
+    Per owner this is the same OR over the same entries as one pass over
+    a static buffer of every slot (``packed_ranges``), so the results are
+    bit-identical; only the slots read differ."""
+    off = prefix_sum(counts) - counts
+    slot = jnp.arange(chunk, dtype=jnp.int32)
+    owner = jnp.arange(counts.shape[0], dtype=jnp.int32)
+    total = off[-1] + counts[-1]
+
+    def read(s0, kept):
+        rel, seg, *vs = spread_chunk((starts - off, owner) + tuple(vals),
+                                     off, chunk, s0)
+        at = s0 + slot
+        entry = entry_of(at + rel, at < total)
+        if keep:
+            kept = tuple(jax.lax.dynamic_update_slice(b, x, (s0,))
+                         for b, x in zip(kept, [entry, seg] + vs))
+        return words_of(entry, *vs), seg, kept
+
+    kept = tuple(jnp.zeros((keep,), jnp.int32)
+                 for _ in range(2 + len(vals))) if keep else ()
+    words, kept = _chunk_loop(counts, off, chunk, n_words, max_run, read,
+                              kept)
+    return (words, kept) if keep else words
+
+
+def kept_row_words(counts: jax.Array, kept: tuple, words_of, n_words: int,
+                   chunk: int, max_run: int = 0) -> tuple:
+    """``packed_row_words`` once more over the slots an earlier pass
+    kept (its ``keep``): the same chunks, read from the buffers."""
+    off = prefix_sum(counts) - counts
+
+    def read(s0, _):
+        entry, seg, *vs = (jax.lax.dynamic_slice(b, (s0,), (chunk,))
+                           for b in kept)
+        return words_of(entry, *vs), seg, ()
+
+    words, _ = _chunk_loop(counts, off, chunk, n_words, max_run, read, ())
+    return words
+
+
+def _chunk_loop(counts, off, chunk: int, n_words: int, max_run: int, read,
+                carry):
+    """The chunk loop of ``packed_row_words``: ``read(s0, carry)`` gives
+    the chunk's per-slot words and owners; the owners' ORs accumulate
+    over ``ceil(sum(counts) / chunk)`` trips."""
+    total = off[-1] + counts[-1]
+
+    def body(c):
+        s0, acc, carry = c
+        words, seg, carry = read(s0, carry)
+        words = segment_or(words, seg, max_run)
+        first = jnp.clip(off - s0, 0, chunk - 1)
+        meets = (counts > 0) & (off < s0 + chunk) & (off + counts > s0)
+        return s0 + chunk, tuple(
+            a | jnp.where(meets, w[first], jnp.uint32(0))
+            for a, w in zip(acc, words)), carry
+
+    init = (jnp.int32(0),
+            tuple(jnp.zeros(counts.shape, jnp.uint32)
+                  for _ in range(n_words)), carry)
+    _, words, carry = jax.lax.while_loop(lambda c: c[0] < total, body, init)
+    return words, carry
 
 
 def packed_ranges(starts: jax.Array, counts: jax.Array, cap: int

@@ -382,12 +382,10 @@ def test_packed_sparse_steps_match_full_sweep(layout, ell_cap, fused):
     g = run_pipeline(ingest.from_arrays(src, dst, n, name="rgg"),
                      layout=layout, ell_cap=ell_cap)
     ig = get_algorithm("ipgc").prepare(g)
-    probe = jnp.zeros((1024,), jnp.int32)
     if layout == "csr-segment":
-        packed = ipgc._packed(ig, probe, ig.edge_dst, lambda r: r,
-                              lambda r: r, keyed=True)
+        packed = ipgc.packed_cap(ig, 1024, ig.edge_dst.shape[0])
     else:
-        packed = ipgc._hub_packed(ig, probe)
+        packed = ipgc._hub_packed(ig, jnp.zeros((1024,), jnp.int32))
     assert packed is not None          # the floor bucket does pack
     spec = ExecutionSpec(regime="host", fused=fused, window=32)
     sess = Session()
@@ -396,6 +394,98 @@ def test_packed_sparse_steps_match_full_sweep(layout, ell_cap, fused):
     np.testing.assert_array_equal(got.colors, want.colors)
     assert (got.iterations, got.mode_trace) == (want.iterations,
                                                 want.mode_trace)
+
+
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("regime", ["host", "outlined"])
+def test_chunked_csr_sparse_steps_match_full_sweep(packed_chunk, regime,
+                                                   fused):
+    """The csr-segment packed sparse step, reading its rows' edges in
+    chunks of 16 slots (rows of up to 45 edges span up to four chunks),
+    colors exactly like sweeps over every edge, in the host loop and in
+    the outlined regime's device loop."""
+    import dataclasses
+
+    from repro.algos import get_algorithm
+    from repro.core import ipgc
+    from repro.exec import ExecutionSpec, Session
+
+    packed_chunk(16)
+    ig = get_algorithm("ipgc").prepare(_rgg(8192, "csr-segment", None))
+    assert int(np.asarray(ig.degrees).max()) > 2 * 16
+    assert ipgc.packed_cap(ig, 1024, ig.edge_dst.shape[0]) is not None
+    spec = ExecutionSpec(regime=regime, fused=fused, window=32)
+    sess = Session()
+    got = sess.run(spec, ig)
+    want = sess.run(spec, dataclasses.replace(ig, seg_bound=()))
+    assert "S" in got.mode_trace
+    np.testing.assert_array_equal(got.colors, want.colors)
+    assert (got.iterations, got.mode_trace) == (want.iterations,
+                                                want.mode_trace)
+
+
+def _hub_rgg():
+    """A geometric graph with a 400-edge hub (node 0) and 64 isolated
+    nodes (the last ids), on the csr-segment layout."""
+    from repro.graphs import generators
+    src, dst, n = generators.edges_rgg(8192, 16, 0)
+    star = np.arange(1, 401)
+    return _rgg(None, "csr-segment", None, edges=(
+        np.concatenate([src, np.zeros_like(star)]),
+        np.concatenate([dst, star]), n + 64))
+
+
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("case", ["spans", "live0"])
+def test_chunk_loop_cases_match_full_sweep(case, fused):
+    """One csr-segment sparse step on a hand-made worklist, read in
+    chunks, against the same step sweeping every edge: colors, bases and
+    worklist agree. ``spans``: the hub spans four chunks, a chunk
+    boundary falls on the next row's start, an owner has no entries and
+    the worklist ends in pad rows; ``live0``: its rows own no entries."""
+    import dataclasses
+
+    from repro.algos import get_algorithm
+    from repro.core import ipgc
+    from repro.core.worklist import Worklist
+
+    alg = get_algorithm("ipgc")
+    ig = alg.prepare(_hub_rgg())
+    n = ig.n_nodes
+    deg = np.asarray(ig.degrees)
+    assert deg[0] == 400 and (deg[-64:] == 0).all()
+    colors, base, wl = alg.init_state(ig)
+    dense, _ = ipgc.step_fns(fused)
+    colors, base, _ = dense(ig, colors, base, wl, window=32)
+    if case == "spans":
+        # hub, a row of degree 16, an isolated row, rows of degree 12+
+        rest = [r for r in range(1, n - 64) if deg[r] >= 12][:300]
+        items = [0, int(np.flatnonzero(deg == 16)[0]), n - 1] + rest
+        chunk = 100                     # off[1] = 400 = 4 chunks
+    else:
+        items = list(range(n - 64, n))
+        chunk = 64
+    items = np.asarray(items + [n] * (1024 - len(items)), np.int32)
+    live = items[items < n]
+    counts = np.where(items < n, deg[np.minimum(items, n - 1)], 0)
+    off = np.cumsum(counts) - counts
+    if case == "spans":
+        assert counts[0] > 2 * chunk and off[1] % chunk == 0 < counts[1]
+        assert (counts[:live.size] == 0).any() and live.size < items.size
+        assert counts.sum() > 3 * chunk
+    else:
+        assert counts.sum() == 0
+    mask = np.zeros(n, bool)
+    mask[live] = True
+    wl = Worklist(mask=jnp.asarray(mask), items=jnp.asarray(items),
+                  count=jnp.int32(live.size))
+    got = ipgc._packed_csr_step(ig, colors, base, wl, window=32,
+                                fused=fused, chunk=chunk)
+    want = ipgc._csr_step(dataclasses.replace(ig, seg_bound=()), colors,
+                          base, wl, window=32, fused=fused, sparse=True)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def _rgg(n, layout, ell_cap, edges=None):

@@ -2,8 +2,9 @@
 (``ColoringResult.sparse_entries`` / ``sparse_slots``; the dense steps'
 ``dense_entries`` / ``dense_slots`` where JPL's rule differs), recomputed by
 stepping the same coloring by hand: the live entries are the degrees of
-the worklist's rows before the step, the slots the size of the buffers
-the step builds (``ipgc._packed``), or every entry where it sweeps."""
+the worklist's rows before the step, the slots the chunks its live
+entries fill (csr-segment) or the size of the buffers the step builds
+(``ipgc._hub_packed``), or every entry where it sweeps."""
 import dataclasses
 
 import jax.numpy as jnp
@@ -27,14 +28,18 @@ def _rgg(layout, ell_cap):
                         layout=layout, ell_cap=ell_cap)
 
 
-def _gathered(ig, items) -> tuple[int, int]:
-    """(entries the sparse step over ``items`` gathers, read off the
-    buffers it builds; entries it would gather sweeping)."""
+def _gathered(ig, items, live: int) -> tuple[int, int]:
+    """(entries the sparse step over ``items``, whose rows own ``live``
+    entries, gathers; entries it would gather sweeping). On csr-segment
+    a packed step reads whole chunks of ``PACKED_CHUNK`` slots (of the
+    packed cap where that is smaller), as many as ``live`` fills."""
     if ig.layout_kind == "csr-segment":
-        pk = ipgc._packed(ig, items, ig.edge_dst, lambda r: r, lambda r: r,
-                          keyed=True)
         m = ig.edge_dst.shape[0]
-        return (m if pk is None else pk.dst.shape[0]), m
+        cap = ipgc.packed_cap(ig, items.shape[0], m)
+        if cap is None:
+            return m, m
+        chunk = min(ipgc.PACKED_CHUNK, cap)
+        return -(-live // chunk) * chunk, m
     assert ig.n_hub > 0
     pk = ipgc._hub_packed(ig, items)
     rows = items.shape[0] * ig.ell_width
@@ -44,9 +49,14 @@ def _gathered(ig, items) -> tuple[int, int]:
 
 @pytest.mark.parametrize("bound", [True, False], ids=["packed", "swept"])
 @pytest.mark.parametrize("fused", [False, True])
-@pytest.mark.parametrize("layout,ell_cap", [("csr-segment", None),
-                                            ("ell-tail", 8)])
-def test_counter_matches_hand_stepping(layout, ell_cap, fused, bound):
+@pytest.mark.parametrize("layout,ell_cap,chunk", [
+    pytest.param("csr-segment", None, None, id="csr-segment-None"),
+    pytest.param("csr-segment", None, 1000, id="csr-segment-chunk1000"),
+    pytest.param("ell-tail", 8, None, id="ell-tail-8")])
+def test_counter_matches_hand_stepping(packed_chunk, layout, ell_cap, chunk,
+                                       fused, bound):
+    if chunk:
+        packed_chunk(chunk)
     alg = get_algorithm("ipgc")
     ig = alg.prepare(_rgg(layout, ell_cap))
     if not bound:                       # no packing bound: every step sweeps
@@ -72,7 +82,7 @@ def test_counter_matches_hand_stepping(layout, ell_cap, fused, bound):
             wl = resize_items(wl, cap, n)
         items = np.asarray(wl.items)
         live.append(int(deg[items[items < n]].sum()))
-        packed, swept = _gathered(ig, wl.items)
+        packed, swept = _gathered(ig, wl.items, live[-1])
         slots.append(packed)
         sweeps.append(swept)
         colors, base, wl = sparse(ig, colors, base, wl, window=W)
@@ -80,6 +90,7 @@ def test_counter_matches_hand_stepping(layout, ell_cap, fused, bound):
     np.testing.assert_array_equal(got.colors, np.asarray(colors[:n]))
     assert got.sparse_entries == live
     assert got.sparse_slots == slots
+    assert all(e <= s for e, s in zip(live, slots))
     # with the bound, the small buckets pack: fewer slots than a sweep
     assert any(s < w for s, w in zip(slots, sweeps)) == bound
 

@@ -199,3 +199,44 @@ def test_ell_two_phase_steps_gather_no_priorities_for_v5e(one_chip, step):
         assert gathers == [f"{R},{k}"] * 2, gathers
     else:
         assert gathers.count(f"{cap},{k}") == 3, gathers
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_csr_packed_sparse_step_reads_chunks_for_v5e(one_chip, fused):
+    """The csr-segment packed sparse step, compiled for the chip at the
+    kron cell's size (2^17 rows, 3.73M edge slots, the floor bucket of
+    1,024 rows whose bound packs 1.44M slots), gathers edges and
+    neighbour colors one chunk of ``PACKED_CHUNK`` slots at a time, and
+    nothing of the packed cap's size: edges once (the two-phase resolve
+    reads the edges its assign loop kept), neighbour colors once per
+    loop (one loop fused, two in the two-phase step)."""
+    import re
+
+    from repro.core import ipgc
+    from repro.core.worklist import Worklist
+
+    n, m, rows = 2 ** 17, 3_728_792, 1024
+    bound = (16384, 24576, 40960, 73728, 122880, 180224, 294912, 458752,
+             655360, 983040, 1441792, 1835008, 2359296, 2883584)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    ig = ipgc.IPGCGraph(
+        n_nodes=n, ell_width=1, n_hub=0, ell_idx=sds((n, 1)),
+        degrees=sds((n,)), priority=sds((n + 1,)), tail_src=sds((8,)),
+        tail_dst=sds((8,)), tail_valid=sds((8,), jnp.bool_),
+        tail_slot=sds((8,)), hub_slot=sds((n,)), hub_ids=sds((1,)),
+        tail_start=sds((1,)), layout_kind="csr-segment",
+        edge_src=sds((m,)), edge_dst=sds((m,)), seg_bound=bound)
+    cap = ipgc.packed_cap(ig, rows, m)
+    assert cap == 1441792 and ipgc.packed_chunk(cap) == ipgc.PACKED_CHUNK
+    wl = Worklist(mask=sds((n,), jnp.bool_), items=sds((rows,)),
+                  count=sds(()))
+    fn = ipgc.fused_sparse_step_impl if fused else ipgc.sparse_step_impl
+    hlo = jax.jit(fn, static_argnames=("window", "impl")).lower(
+        ig, sds((n + 1,)), sds((n,)), wl, window=128,
+        impl="jnp").compile().as_text()
+    sizes = [int(s) for s in
+             re.findall(r"= \w+\[(\d+)\]\{[^}]*\} gather\(", hlo)]
+    assert sizes.count(ipgc.PACKED_CHUNK) == (2 if fused else 3), sizes
+    assert max(sizes) == ipgc.PACKED_CHUNK, sizes
