@@ -110,8 +110,9 @@ def phase_host_outlined(g, devices) -> None:
 def phase_pallas(g, devices, on_chip: bool) -> None:
     from repro.algos import get_algorithm
     from repro.core import verify_coloring
-    from repro.core.engine import adaptive_window, resolve_plan
+    from repro.core.policy import adaptive_window
     from repro.exec import ExecutionSpec, Session
+    from repro.graphs.layout import resolve_plan
     from repro.kernels import ops, tune
 
     alg = get_algorithm("ipgc")
@@ -190,8 +191,9 @@ def phase_dist(g, devices) -> None:
 
     from repro.algos import get_algorithm
     from repro.core import verify_coloring
-    from repro.core.engine import adaptive_window, resolve_plan
+    from repro.core.policy import adaptive_window
     from repro.exec import ExecutionSpec, Session
+    from repro.graphs.layout import resolve_plan
     from repro.graphs.partition import prepare_partition
 
     n_shards = len(devices)
@@ -209,7 +211,7 @@ def phase_dist(g, devices) -> None:
          **summary(ref))
     for exchange in ("dense", "auto"):
         t = time.perf_counter()
-        r = sess.run(ExecutionSpec(regime="dist", mode="dist-hybrid",
+        r = sess.run(ExecutionSpec(regime="dist", mode="hybrid",
                                    layout="ell-tail", exchange=exchange,
                                    n_shards=n_shards), g)
         verify_coloring(g, r.colors, context=f"dist {exchange}")

@@ -3,44 +3,42 @@ over the synthetic suite, via the pluggable-algorithm registry.
 
 Each run is VERIFIED — an invalid or incomplete coloring raises
 ``InvalidColoringError`` and exits non-zero instead of printing a wrong
-number. With ``--tables`` the paper's original experiment tables
-(Tables III & IV, Fig. 4) are reproduced as before.
+number. Timings are those of the machine it runs on; the chip
+benchmark is ``bench/run.py``.
 
   PYTHONPATH=src python examples/color_suite.py [--scale 0.1]
-  PYTHONPATH=src python examples/color_suite.py --algo jpl --outline
-  PYTHONPATH=src python examples/color_suite.py --tables
+  PYTHONPATH=src python examples/color_suite.py --algo jpl --regime outlined
 """
 import argparse
 import json
 
 from repro.algos import algorithm_names, get_algorithm
 from repro.core import verify_coloring
-from repro.exec import Session, spec_for
+from repro.exec import ExecutionSpec, Session
 from repro.graphs import LAYOUT_KINDS, REORDERINGS, SUITE_SPECS, get_dataset
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--scale", type=float, default=0.1)
 ap.add_argument("--algo", action="append", choices=algorithm_names(),
                 help="algorithm(s) to run (default: all registered)")
+ap.add_argument("--regime", default="host",
+                choices=["host", "outlined", "dist"],
+                help="dispatch regime: host loop, device-resident outlined "
+                     "chunks, or the sharded Pipe")
 ap.add_argument("--mode", default="hybrid",
-                help="policy mode (hybrid / topology / data / hybrid-auto "
-                     "/ dist-hybrid)")
+                help="policy mode (hybrid / topology / data / hybrid-auto)")
 ap.add_argument("--shards", type=int, default=None,
-                help="dist modes: shard count (default: all devices)")
+                help="dist regime: shard count (default: all devices)")
 ap.add_argument("--exchange", default="dense",
                 choices=["dense", "boundary", "auto"],
-                help="dist modes: cross-shard color publication path "
+                help="dist regime: cross-shard color publication path "
                      "(DESIGN.md §13)")
-ap.add_argument("--outline", action="store_true",
-                help="use the device-resident outlined Pipe")
 ap.add_argument("--layout", default="auto",
                 choices=list(LAYOUT_KINDS) + ["auto"],
                 help="graph pipeline layout plan (DESIGN.md §8)")
 ap.add_argument("--reorder", default="identity",
                 choices=sorted(REORDERINGS),
                 help="graph pipeline node reordering")
-ap.add_argument("--tables", action="store_true",
-                help="also reproduce the paper's Tables III & IV")
 ap.add_argument("--json", action="store_true",
                 help="run traced (DESIGN.md §12) and emit one RunReport "
                      "JSON object per (graph, algo) row on stdout instead "
@@ -56,7 +54,7 @@ session = Session()
 
 if not args.json:
     print(f"== registry sweep: {', '.join(algos)} "
-          f"(mode={args.mode}, outline={args.outline}, "
+          f"(regime={args.regime}, mode={args.mode}, "
           f"layout={args.layout}, reorder={args.reorder}) ==")
     print("graph,layout,algo,ms,iterations,colors")
 for name in SUITE_SPECS:
@@ -69,11 +67,9 @@ for name in SUITE_SPECS:
         # --json runs traced: the same run returns a full RunReport
         # (launches/iter, timing split, cache hit-rate) at the cost of
         # span bookkeeping; the CSV path stays untraced
-        r = session.run(spec_for(mode=args.mode, algo=alg,
-                                 outline=args.outline,
-                                 n_shards=args.shards,
-                                 exchange=args.exchange), g,
-                        trace=True if args.json else None)
+        spec = ExecutionSpec(regime=args.regime, mode=args.mode, algo=alg,
+                             n_shards=args.shards, exchange=args.exchange)
+        r = session.run(spec, g, trace=True if args.json else None)
         # fail loudly: a conflict or uncolored node raises, the script
         # exits non-zero, and no misleading row is printed; reordered
         # graphs verify on the ORIGINAL ids via the inverse permutation
@@ -91,7 +87,7 @@ for name in SUITE_SPECS:
                   f"{r.iterations},{r.n_colors}")
             res = getattr(r, "result", None) or r
             if getattr(res, "exchange_trace", ""):
-                # dist modes: which publication path each iteration took
+                # dist regime: which publication path each iteration took
                 # ('d' dense, 'b' packed boundary, 'm' mixed) + the
                 # modeled per-device traffic it moved (DESIGN.md §13)
                 kb = sum(res.exchange_bytes) / 1e3
@@ -100,20 +96,3 @@ for name in SUITE_SPECS:
 
 if not args.json:
     print(f"# session cache after sweep: {session.stats.as_dict()}")
-
-if args.tables:
-    from benchmarks.bench_table3_speedup import bench as bench_speed
-    from benchmarks.bench_table4_colors import bench as bench_colors
-
-    print()
-    print("== Table III / Fig 4: time (ms) per engine ==")
-    print("graph,plain_ms,topology_ms,hybrid_ms,vb_ms,jpl_ms,speedup")
-    res = bench_speed(scale=args.scale, runs=3)
-    print()
-    print("== Table IV: colors used ==")
-    print("graph,hybrid,jpl_cusparse,ratio")
-    bench_colors(scale=args.scale, seeds=(0,))
-    print()
-    print(f"geomean hybrid speedup over Plain: "
-          f"{res['geomean_vs_plain']:.2f}x (paper: 2.13x); "
-          f"over VB/Kokkos: {res['geomean_vs_vb']:.2f}x (paper: 1.36x)")

@@ -8,17 +8,18 @@ behaviour, demonstrated by the cache stats below.
 
   PYTHONPATH=src python examples/quickstart.py
 """
-from repro.exec import default_session, spec_for
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import get_dataset, validate_coloring
 
 g = get_dataset("kron_g500-logn21_s", scale=0.05, layout="auto")
 print(f"graph: {g.name}  nodes={g.n_nodes:,}  edges={g.n_edges:,}  "
       f"layout={g.layout.kind} (K={g.ell_width})")
 
-session = default_session()          # the cache engine.color also shares
-# spec_for resolves the regime like engine.color: host loop by default,
-# the outlined Pipe under REPRO_OUTLINE_HYBRID=1 / engine.outlined(True)
-spec = spec_for(mode="hybrid", h=0.6)
+session = default_session()          # the process-wide session
+# the spec names everything static about the run; regime="host" is the
+# host loop (one read-back an iteration), "outlined" the device-resident
+# while_loop chunks, "dist" the sharded Pipe
+spec = ExecutionSpec(regime="host", mode="hybrid", h=0.6)
 print(f"regime: {spec.regime}")
 
 result = session.run(spec, g)        # cold: prepares + compiles

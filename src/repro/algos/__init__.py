@@ -9,7 +9,7 @@ shard-safe — the sharded ``shard_map`` Pipe).
 Built-ins registered at import:
 
   ipgc         the paper's engine (bit-identical to the pre-subsystem
-               ``engine.color``); speculative windowed mex + same-iteration
+               engine); speculative windowed mex + same-iteration
                resolve; shard-safe.
   jpl          Jones–Plassmann–Luby random-priority independent sets; no
                resolve phase; fast rounds, many colors; host+outlined only.

@@ -34,16 +34,16 @@ ignores ``window``.
 Shard-safety declaration contract (DESIGN.md §7): an algorithm that sets
 ``shard_safe=True`` promises its ``make_dist_steps`` returns shard_map'd
 steps whose worklist state stays shard-local and whose only cross-shard
-value is the color vector — the invariants ``color_distributed`` is built
-on. Algorithms that cannot (yet) honor that declare ``shard_safe=False``
-with a human-readable ``shard_unsafe_reason``; ``engine.color(
-mode="dist-hybrid", algo=...)`` fails fast with that reason rather than
-silently producing wrong colorings.
+value is the color vector — the invariants the dist regime is built on.
+Algorithms that cannot (yet) honor that declare ``shard_safe=False``
+with a human-readable ``shard_unsafe_reason``; ``Session.run(
+ExecutionSpec(regime="dist", algo=...), g)`` fails fast with that reason
+rather than silently producing wrong colorings.
 
 Registry semantics: algorithms register under a unique name at import time
 (``repro.algos`` registers the three built-ins); ``get_algorithm`` accepts
-a name or an ``Algorithm`` instance (passthrough), so every engine entry
-point takes ``algo="ipgc" | "jpl" | "spec-greedy" | <instance>``.
+a name or an ``Algorithm`` instance (passthrough), so
+``ExecutionSpec.algo`` takes ``"ipgc" | "jpl" | "spec-greedy" | <instance>``.
 Instances are frozen dataclasses — hashable, so they ride through ``jit``
 static args (the outlined chunk is specialised per algorithm).
 """
@@ -64,9 +64,9 @@ class Algorithm:
     """Base protocol; concrete algorithms subclass and override."""
 
     name: str = "abstract"
-    #: may this algorithm run under ``mode="dist-hybrid"``?
+    #: may this algorithm run under ``regime="dist"``?
     shard_safe: bool = False
-    #: surfaced by the engine when a dist mode is requested anyway
+    #: surfaced by the Session when the dist regime is requested anyway
     shard_unsafe_reason: str = ""
     #: may this algorithm run under ``Session.run_batch``? ``True``
     #: promises the step impls are batch-axis safe — shape-static jnp
@@ -140,8 +140,8 @@ class Algorithm:
                         node_axes: tuple, *, window: int, fused: bool,
                         exchange: str = "dense", boundary=None,
                         thresh: int | None = None):
-        """(dense_step, sparse_step) shard_map'd closures for
-        ``color_distributed``; only called when ``shard_safe``.
+        """(dense_step, sparse_step) shard_map'd closures for the dist
+        regime; only called when ``shard_safe``.
         ``exchange``/``boundary``/``thresh`` select the cross-shard color
         publication path (DESIGN.md §13): with ``exchange != "dense"``
         the returned steps take per-shard color *views* plus a static

@@ -2,9 +2,9 @@
 
 Pure delegation to ``core/ipgc.py``: the step impls, jitted step pair,
 state initialisation and finalize are exactly the functions the engine
-called before the subsystem existed, so ``engine.color(g, algo="ipgc")``
-is bit-identical (colors, iteration count, mode trace) to the
-pre-refactor engine in host-loop, outlined and dist-hybrid modes.
+called before the subsystem existed, so ``algo="ipgc"`` is bit-identical
+(colors, iteration count, mode trace) to the pre-refactor engine in the
+host, outlined and dist regimes.
 """
 from __future__ import annotations
 
@@ -37,7 +37,7 @@ class IPGC(Algorithm):
     def make_dist_steps(self, ig_local, mesh, node_axes, *, window: int,
                         fused: bool, exchange: str = "dense", boundary=None,
                         thresh: int | None = None):
-        # local import: distributed.py imports the engine (result type)
+        # local import: only the dist regime needs the shard_map steps
         from repro.core.distributed import (make_dist_dense_step,
                                             make_dist_sparse_step,
                                             shard_graph)

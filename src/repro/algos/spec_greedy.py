@@ -19,8 +19,8 @@ regardless of the engine's per-backend default, making the algorithm's
 identity independent of how the caller tuned the IPGC fast path.
 
 Tie-break: random hash priority (Rokos' deterministic vertex-id repair
-order degenerates to O(N) sweeps on chain graphs — same reason
-``baselines.vb_color`` hashes; see its docstring). Because repaired
+order degenerates to O(N) sweeps on chain graphs — the same reason the
+Kokkos vertex-based baseline hashes its tie-break). Because repaired
 vertices re-run first-fit against an advancing window base, the final
 palette can carry gaps; ``finalize`` compacts it and reports the true
 distinct count (quality sits between IPGC and JPL).

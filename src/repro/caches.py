@@ -20,7 +20,7 @@ def use_compile_cache() -> str:
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
     it stands; otherwise the cache goes to ``<checkout>/.cache/jax``.
-    Entry points (``chip_smoke.py``, the benchmark mains) call this before
+    Entry points (``bench/run.py``, ``chip_smoke.py``) call this before
     their first compile; importing the library never does.
     """
     import jax

@@ -1,12 +1,9 @@
 """The paper's primary contribution: hybrid (topology+data-driven) worklist
-scheduling with a persistent worklist — applied to IPGC by default, and to
-any colorer registered with the pluggable algorithm subsystem
-(``repro.algos``; pass ``algo=`` to the engine entry points)."""
-from repro.core.engine import (ColoringResult, color,  # noqa: F401
-                               color_outlined, color_outlined_hybrid,
-                               outlined, set_outline_default)
-from repro.core.distributed import color_distributed  # noqa: F401
-from repro.core.baselines import jpl_color, vb_color  # noqa: F401
+scheduling with a persistent worklist — the IPGC steps, the worklist and
+its capacity ladder, the result type and the verifier. Runs go through
+``repro.exec.Session.run(ExecutionSpec(...), g)``."""
+from repro.core.result import ColoringResult  # noqa: F401
+from repro.core.baselines import jpl_color  # noqa: F401
 from repro.core.worklist import Worklist, full_worklist, bucket_capacities  # noqa: F401
 from repro.core.verify import InvalidColoringError, verify_coloring  # noqa: F401
 from repro.core import ipgc  # noqa: F401

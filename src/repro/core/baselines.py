@@ -5,14 +5,12 @@
   (plus the two-sided trick: local max AND local min get colors 2r / 2r+1),
   very fast per round but uses many more colors — reproducing the paper's
   Table IV gap.
-* ``vb_color`` — Deveci et al. vertex-based speculative coloring (what the
-  Kokkos implementation in the paper runs): same speculative
-  assign/resolve structure as IPGC with a small forbidden window and
-  node-id tie-break, data-driven with a worklist.
+
+The Kokkos vertex-based baseline (Deveci et al.) is the data-driven
+policy with a 32-wide window: ``ExecutionSpec(mode="data", window=32)``.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
 from functools import partial
 
@@ -21,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ipgc
-from repro.core.engine import ColoringResult, color
+from repro.core.result import ColoringResult
 from repro.graphs.csr import Graph, NO_COLOR
 
 
@@ -86,12 +84,3 @@ def jpl_color(g: Graph, *, max_rounds: int = 10_000) -> ColoringResult:
     return ColoringResult(colors=final, n_colors=n_colors, iterations=rounds,
                           mode_trace="J" * rounds, counts=counts, tti=[],
                           total_seconds=time.perf_counter() - t0)
-
-
-def vb_color(g: Graph, **kw) -> ColoringResult:
-    """Kokkos-style (Deveci VB): data-driven speculative coloring with a
-    32-wide forbidden window. Tie-break is hash-random like Kokkos's
-    ``rand(v)`` comparison (a monotonic id tie-break degenerates to O(N)
-    rounds on chain graphs)."""
-    return color(g, mode="data", window=kw.pop("window", 32),
-                 priority="hash", **kw)

@@ -22,11 +22,12 @@ boundaries:
     sliced down a per-shard capacity ladder (``bucket_capacities(block)``)
     at bucket boundaries. The hybrid switch decision needs one scalar
     psum (= IrGL Pipe's size check), read back by the host driver
-    (``color_distributed``) exactly like the host-loop Pipe.
+    (``Session.run`` with ``ExecutionSpec(regime="dist")``) exactly like
+    the host-loop Pipe.
 
 The dense two-phase step is bit-identical to the reference engine on any
 shard count; the fused steps are bit-identical to ``ipgc.fused_*_step``
-(so ``color_distributed`` reproduces ``engine.color(fused=True)``'s
+(so the dist regime reproduces the host regime's ``fused=True``
 colors, iteration count and mode trace for fixed-H policies —
 DESIGN.md §6).
 
@@ -51,11 +52,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import ipgc
-from repro.core.engine import ColoringResult
-from repro.core.policy import Policy
 from repro.core.worklist import (Worklist, compact_items, compact_mask,
                                  resize_block)
-from repro.graphs.csr import Graph, NO_COLOR
+from repro.graphs.csr import NO_COLOR
 from repro.obs.metrics import default_registry
 
 # --- exchange instrumentation (trace-time) ---------------------------------
@@ -668,76 +667,3 @@ def make_dist_resize(mesh, node_axes: tuple, n_global: int):
         return Worklist(mask=wl.mask, items=fn(wl.items), count=wl.count)
 
     return resize
-
-
-# ---------------------------------------------------------------------------
-# the distributed hybrid Pipe driver
-# ---------------------------------------------------------------------------
-
-def color_distributed(
-    g: Graph,
-    *,
-    n_shards: int | None = None,
-    mesh=None,
-    node_axes: tuple = ("data",),
-    mode: str = "hybrid",
-    algo: str | object = "ipgc",
-    h: float = 0.6,
-    window: int | str = "auto",
-    bucket_ratio: int = 2,
-    max_iter: int = 10_000,
-    priority: str = "hash",
-    policy: Policy | None = None,
-    collect_tti: bool = False,
-    fused: bool | None = True,    # fused = ONE color exchange per iteration
-    balance: bool = True,
-    steps_cache: dict | None = None,
-    layout: "str | object | None" = None,
-    exchange: str = "dense",
-) -> ColoringResult:
-    """Sharded hybrid Pipe: the host-loop driver over the shard_map steps.
-
-    The graph is padded + degree-balanced into equal owner blocks
-    (``prepare_partition``); the driver then runs the exact host-Pipe
-    control flow — policy on the psum'd global count, per-shard capacity
-    ladder with slices at bucket boundaries — over the distributed steps.
-    With the default ``fused=True`` the steps are bit-identical to
-    ``ipgc.fused_*_step`` on the repartitioned graph, so for fixed-H
-    policies the result matches ``engine.color(g2, fused=True)`` exactly
-    (colors, iteration count, mode trace) on ANY shard count
-    (tests/test_distributed.py). Colors are returned in ``g``'s original
-    node labeling.
-
-    ``fused=None`` resolves to the distributed default (True).
-    ``algo`` must name a shard-safe algorithm (the declaration contract,
-    DESIGN.md §7); its ``make_dist_steps`` supplies the shard_map'd step
-    pair and its ``init_state``/``finalize`` bracket the run.
-    ``steps_cache``: legacy compile-cache argument, still accepted — the
-    dict becomes the backing store of the ``Session`` the call runs on,
-    so passing the same dict across calls reuses the partitioned graph
-    and the jitted shard_map steps exactly as before. ``None`` runs on
-    the process-default session (DESIGN.md §9), which amortizes the same
-    artifacts across ALL entry points instead of per caller-dict.
-    ``layout``: engine-level plan override (``engine.resolve_plan``);
-    the sharded steps are the ELL-family tile steps, so ``csr-segment``
-    execution is rejected — pass ``layout="ell-tail"`` to run a
-    csr-segment-planned graph here (its ELL+tail arrays are complete).
-    ``exchange``: cross-shard color publication path (DESIGN.md §13) —
-    ``"dense"`` (additive psum of int32[N+1], the historical path),
-    ``"boundary"`` (packed changed-boundary buffers whenever they fit),
-    or ``"auto"`` (packed only below the byte break-even threshold).
-    Static knob: it rides the compile-cache key. All three are
-    bit-identical (tests/test_boundary.py).
-    """
-    # thin dispatcher over the unified session (driver loop + cache live
-    # in repro.exec.session; lazy import — repro.exec imports this module)
-    from repro.exec import ExecutionSpec, Session, default_session
-    spec = ExecutionSpec(
-        regime="dist", mode=mode, algo=algo, layout=layout, h=h,
-        window=window, bucket_ratio=bucket_ratio, max_iter=max_iter,
-        priority=priority, fused=fused, n_shards=n_shards, balance=balance,
-        exchange=exchange)
-    session = (default_session() if steps_cache is None
-               else Session(cache=steps_cache))
-    return session.run(spec, g, policy=policy, collect_tti=collect_tti,
-                       mesh=mesh, node_axes=node_axes)

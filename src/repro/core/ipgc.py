@@ -19,7 +19,8 @@ Both phases maintain the full worklist state — the paper's contribution.
 
 ``impl="pallas"`` routes the per-row window/mex and conflict computations
 through the Pallas TPU kernels (validated in interpret mode on CPU);
-``impl="jnp"`` is the pure-jnp reference path used for CPU benchmarks.
+``impl="jnp"`` is the pure-jnp path, the default and the one the chip
+benchmark runs.
 
 Hub (degree > ELL width) bookkeeping: ELL rows cover the first K
 neighbours; the COO tail covers the rest. Tail contributions are folded in
@@ -28,10 +29,8 @@ phase stays O(C·K + T + C·W) — see DESIGN.md §2.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -271,51 +270,8 @@ def pad_prepared(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
         ell_wins=wins)
 
 
-# Read the env var ONCE at import (it used to be re-read on every trace);
-# benchmarks that A/B the hub side-channel use set_force_hub() instead of
-# mutating os.environ, which also keeps the jit cache honest: the engine
-# passes the resolved value down as a *static* step argument.
-_FORCE_HUB_ENV = os.environ.get("REPRO_IPGC_FORCE_HUB", "0") == "1"
-_force_hub_override: bool | None = None
-
-
-def set_force_hub(value: bool | None) -> None:
-    """Override (or with ``None`` reset) the hub side-channel forcing."""
-    global _force_hub_override
-    _force_hub_override = value
-
-
-def force_hub_enabled() -> bool:
-    return _FORCE_HUB_ENV if _force_hub_override is None else _force_hub_override
-
-
-@contextlib.contextmanager
-def forced_hub(value: bool | None):
-    """Scoped hub-side-channel forcing — the context-manager form of
-    ``set_force_hub`` (restores the *previous* override on exit,
-    including the no-override ``None`` state), so A/B tests and
-    benchmarks never leak the toggle::
-
-        with ipgc.forced_hub(True):
-            r = color(g)          # hub side-channel unconditionally on
-    """
-    global _force_hub_override
-    prev = _force_hub_override
-    set_force_hub(value)
-    try:
-        yield
-    finally:
-        _force_hub_override = prev
-
-
-def _force_hub() -> bool:  # kept for back-compat with direct callers
-    return force_hub_enabled()
-
-
 def _has_hubs(ig: IPGCGraph, force_hub: bool | None) -> bool:
-    if force_hub is None:
-        force_hub = force_hub_enabled()
-    return ig.n_hub > 0 or force_hub
+    return ig.n_hub > 0 or bool(force_hub)
 
 
 # --- gather instrumentation (trace-time) -----------------------------------
@@ -800,7 +756,7 @@ def dense_step_impl(ig: IPGCGraph, colors: jax.Array, base: jax.Array,
     active = wl.mask
     row_ids = jnp.arange(n, dtype=jnp.int32)
     # static: hub side-channel compiled out entirely for regular graphs
-    # (force_hub restores the unconditional path for A/B runs)
+    # (force_hub=True forces the side-channel on, for tests)
     has_hubs = _has_hubs(ig, force_hub)
 
     # --- assign (speculative windowed mex) ---

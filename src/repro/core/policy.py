@@ -7,8 +7,8 @@ estimates the crossover from two timed probes — the "analytical H" the
 paper lists as future work.
 
 Every built-in policy also has a *device-side form*: an int32 count
-threshold such that ``count > threshold`` means dense. The outlined hybrid
-engine (engine.color_outlined_hybrid) feeds this threshold into the
+threshold such that ``count > threshold`` means dense. The outlined
+regime (``ExecutionSpec(regime="outlined")``) feeds this threshold into the
 on-device ``lax.cond`` so the H decision never re-enters Python;
 ``device_threshold`` derives it for arbitrary monotone callables by
 bisection. AutoTuned refreshes its threshold between chunks via the
@@ -20,6 +20,8 @@ from __future__ import annotations
 import dataclasses
 import time
 from typing import Callable
+
+import numpy as np
 
 # A policy maps (count, n_nodes) -> True for dense (topology) mode.
 Policy = Callable[[int, int], bool]
@@ -360,11 +362,8 @@ def make_admission_policy(admission
 
 
 def make_policy(mode: str, h: float = 0.6) -> Policy:
-    # "dist-hybrid" etc. select the sharded engine at the dispatch layer;
-    # the switching policy itself is the same — the distributed driver
-    # feeds it the psum'd global count (DESIGN.md §6)
-    if mode.startswith("dist-"):
-        mode = mode[len("dist-"):]
+    # every regime uses the same switching policies — the sharded Pipe
+    # feeds them the psum'd global count (DESIGN.md §6)
     if mode == "hybrid":
         return fixed_h(h)
     if mode == "hybrid-auto":
@@ -374,6 +373,24 @@ def make_policy(mode: str, h: float = 0.6) -> Policy:
     if mode in ("data", "sparse", "plain"):
         return always_sparse()
     raise ValueError(f"unknown mode {mode!r}")
+
+
+def adaptive_window(g, *, lo: int = 32, hi: int = 128) -> int:
+    """Color-window heuristic (beyond-paper optimisation, EXPERIMENTS.md
+    §Perf): mex(v) <= deg(v), and IPGC's chromatic number tracks the
+    *typical* degree, so a window ~2x the median degree covers almost all
+    assignments in one pass while hub nodes advance their base. Cuts the
+    O(C*W) per-iteration mex term up to 4x on low-degree graphs.
+
+    Degenerate histograms clamp cleanly (tests/test_policy.py): a graph
+    with no nodes has no median — return ``lo``; an all-hub graph's
+    median blows past the window budget — clamp to ``hi``.
+    """
+    deg = np.asarray(g.arrays.degrees)
+    if deg.size == 0:
+        return lo
+    med = int(np.median(deg))
+    return int(min(max(-(-2 * (med + 1) // 32) * 32, lo), hi))
 
 
 class Timer:
@@ -394,8 +411,8 @@ def measure_launches(step_impl, ig, colors, aux, wl, **step_kw) -> dict:
     ``mex`` / ``conflict`` / ``compact``); a fused iteration is
     ``{"fused": 1}`` with every other bucket 0 — one pass over the
     neighbour tile, whose worklist compaction counts with it — which is
-    how the engine's launch contract is asserted in tests and reported
-    by ``bench_engine_modes --kernels``.
+    how the engine's launch contract is asserted in tests and in the
+    traced run's report.
 
     The measurement runs inside ``LAUNCH_COUNTS.scope()`` (obs/
     metrics.py): the group is zeroed for the trace and the caller's

@@ -2,13 +2,13 @@
 
 ``ExecutionSpec`` freezes the full static configuration of a coloring
 run; ``Session`` owns the ONE keyed compile cache behind the host,
-outlined and distributed Pipes and adds the batched multi-graph
-workload (``Session.run_batch``). The legacy engine entry points are
-thin dispatchers over ``default_session()``.
+outlined and distributed Pipes. ``Session.run(spec, g)`` is the one way
+to color a graph; ``Session.run_batch`` and ``Session.stream`` color
+many graphs under the same cache.
 """
-from repro.exec.spec import ExecutionSpec, spec_for
+from repro.exec.spec import ExecutionSpec
 from repro.exec.session import (CacheStats, Session, default_session,
                                 reset_default_session)
 
-__all__ = ["ExecutionSpec", "spec_for", "CacheStats", "Session",
-           "default_session", "reset_default_session"]
+__all__ = ["ExecutionSpec", "CacheStats", "Session", "default_session",
+           "reset_default_session"]

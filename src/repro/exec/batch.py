@@ -46,8 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ipgc
-from repro.core.engine import ColoringResult
 from repro.core.policy import Timer, device_threshold, make_policy
+from repro.core.result import ColoringResult
 from repro.core.worklist import (bucket_capacities, pick_bucket,
                                  stacked_worklist)
 from repro.exec.spec import ExecutionSpec
@@ -324,7 +324,7 @@ def _run_batch_pinned(session, spec, alg, graphs, *, map_to_original):
     from repro.algos.ipgc_algo import IPGC
     algo_static = None if alg == IPGC() else alg
     fused = alg.resolve_fused(spec.fused, default=False)  # host-loop default
-    force_hub = ipgc.force_hub_enabled()
+    force_hub = False   # as the host regime: hub paths only on hub graphs
     # run_batch is jnp-only, so "auto" resolves to None (no tile grid);
     # an explicit int still rides the static key like every other regime
     tile_rows = spec.tile_rows if isinstance(spec.tile_rows, int) else None

@@ -1,37 +1,29 @@
 """Unified execution sessions — ONE executor behind the three Pipes.
 
 The paper's contribution is a single persistent-worklist Pipe whose
-dispatch regime varies per iteration; the repo grew three regimes as
-separate drivers with three disjoint compile caches (the host loop's
-per-call step jits, the outlined chunk jit, and the distributed driver's
-caller-threaded ``steps_cache`` dict). A ``Session`` owns ONE keyed
-compile cache for all of them (DESIGN.md §9):
+dispatch regime varies per iteration. A ``Session`` owns ONE keyed
+compile cache for every regime (DESIGN.md §9):
 
   * ``Session.run(spec, g)`` executes an ``ExecutionSpec`` (spec.py) in
     its declared regime — host loop, device-resident outlined chunks, or
     the sharded Pipe — reusing every prepared/compiled artifact the
-    session has seen for the same ``spec.static_key() x graph`` pair.
-    The legacy entry points (``engine.color``, ``color_outlined_hybrid``,
-    ``color_distributed``) are thin dispatchers over this method and stay
-    bit-identical: same colors, iterations, mode trace, host-dispatch and
-    exchange counts (tests/test_exec.py re-runs the equivalence suites'
-    contracts through the session layer).
+    session has seen for the same ``spec.static_key() x graph`` pair. It
+    is the one way to color a graph.
   * ``Session.run_batch(spec, graphs)`` colors MANY graphs in one device
     dispatch (exec/batch.py): graphs are padded into shape-class buckets
     and the step runs ``vmap``-ed over lanes inside a single
     ``lax.while_loop`` until every lane drains — the serving-scale
     workload the unified cache exists for.
   * ``Session.stats`` counts cache hits/misses so warm-vs-cold behaviour
-    is observable (``bench_engine_modes --serve`` records it).
+    is observable.
 
 Cache-key discipline: an entry is keyed on the spec's static fields plus
 the graph's identity (``id(g)`` + static shape fields — the entry pins
 the graph object, so ids cannot be recycled while the entry lives).
 Prepare entries are shared across the host and outlined regimes (same
 prepared ``IPGCGraph``); distributed entries carry the partitioned graph
-and the shard_map'd step closures that ``color_distributed`` used to
-stash in its ad-hoc ``steps_cache`` dict — passing that legacy dict still
-works: it simply becomes the backing store of a Session.
+and the shard_map'd step closures, keyed by the graph's content. A
+caller that wants its own store passes it as ``Session(cache=d)``.
 """
 from __future__ import annotations
 
@@ -47,15 +39,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ipgc
-from repro.core.engine import (ColoringResult, adaptive_window,
-                               resolve_plan)
-from repro.core.policy import (AutoTuned, Policy, Timer, device_threshold,
-                               exchange_threshold, make_policy,
-                               measure_launches)
+from repro.core.policy import (AutoTuned, Policy, Timer, adaptive_window,
+                               device_threshold, exchange_threshold,
+                               make_policy, measure_launches)
+from repro.core.result import ColoringResult
 from repro.core.worklist import (bucket_capacities, chunk_lower_bounds,
                                  pick_bucket, resize_items)
 from repro.exec.spec import ExecutionSpec
 from repro.graphs.csr import Graph
+from repro.graphs.layout import resolve_plan
 from repro.kernels.tune import resolve_tile_rows
 from repro.obs import trace as obs_trace
 from repro.obs.report import (RunReport, dense_exchange_bytes,
@@ -133,16 +125,15 @@ class Session:
     ``max_entries`` bounds the cache FIFO-style (oldest entry evicted
     first): entries pin their graph objects, so an unbounded session
     serving an endless stream of *distinct* graphs would grow without
-    limit. ``None`` (the default for explicitly-constructed sessions and
-    legacy ``steps_cache`` dicts) keeps every entry, matching the
-    historical caching contracts; ``default_session()`` — the store
-    behind plain ``engine.color`` calls — is bounded.
+    limit. ``None`` (the default for explicitly-constructed sessions)
+    keeps every entry; ``default_session()`` — the process-wide store —
+    is bounded.
     """
 
     def __init__(self, cache: dict | None = None,
                  max_entries: int | None = None):
-        #: the unified cache. Passing ``color_distributed``'s legacy
-        #: ``steps_cache`` dict here makes that dict the backing store.
+        #: the unified cache; a caller-supplied dict becomes the
+        #: backing store (it then outlives the session)
         self.cache: dict = {} if cache is None else cache
         self.max_entries = max_entries
         self.stats = CacheStats()
@@ -326,8 +317,8 @@ class Session:
         the reset-scoped counter groups, so the numbers match
         ``measure_launches`` / the exchange-invariant tests bit-for-bit.
         Cached under the session key space — repeated traced runs of the
-        same configuration pay a dict lookup, which is what keeps traced
-        wall time within the BENCH_obs overhead budget.
+        same configuration pay a dict lookup, which keeps a traced run's
+        wall time close to an untraced one's.
         """
         s = meter.statics
         if s is None:
@@ -450,7 +441,7 @@ class Session:
         does not depend on the dispatch regime)."""
         if isinstance(g, ipgc.IPGCGraph):
             # already prepared by the caller; only the window resolves
-            # (auto needs the host Graph, exactly like the legacy engine)
+            # (auto needs the host Graph's degrees)
             window = spec.window
             if window == "auto":
                 assert not alg.uses_window, \
@@ -481,7 +472,8 @@ class Session:
         n = ig.n_nodes
         pol = policy or make_policy(spec.mode, spec.h)
         caps = bucket_capacities(n, ratio=spec.bucket_ratio)
-        force_hub = ipgc.force_hub_enabled()
+        # the hub side-channel runs only where the graph has hubs
+        force_hub = False
         tile_rows = resolve_tile_rows(spec.tile_rows, ig.layout_kind,
                                       spec.impl)
         dense_fn, sparse_fn = alg.step_fns(fused)
@@ -564,7 +556,7 @@ class Session:
         pol = policy or make_policy(spec.mode, spec.h)
         caps = bucket_capacities(n, ratio=spec.bucket_ratio)
         lows = chunk_lower_bounds(caps)
-        force_hub = ipgc.force_hub_enabled()
+        force_hub = False
         tile_rows = resolve_tile_rows(spec.tile_rows, ig.layout_kind,
                                       spec.impl)
         # None keeps the pre-subsystem IPGC jit specialisation
@@ -647,7 +639,7 @@ class Session:
             raise ValueError(
                 f"algorithm {alg.name!r} is not shard-safe: "
                 f"{alg.shard_unsafe_reason or 'no distributed steps'}")
-        assert isinstance(g, Graph), "color_distributed needs a host Graph"
+        assert isinstance(g, Graph), "the dist regime needs a host Graph"
         plan = resolve_plan(g, spec.layout)
         if plan is not None and plan.kind == "csr-segment":
             raise NotImplementedError(
@@ -668,10 +660,10 @@ class Session:
         # a caller-provided mesh is cached by identity (steps close over
         # it). The algorithm and plan join as frozen instances. Unlike
         # the prep entries, the graph joins by CONTENT (name, sizes and a
-        # checksum of its CSR) — the legacy steps_cache contract: a caller
-        # that rebuilds an equal Graph per request must still reuse the
-        # partitioned graph and jitted shard_map steps, and a relabeled
-        # graph of the same name and sizes must not.
+        # checksum of its CSR): a caller that rebuilds an equal Graph per
+        # request must still reuse the partitioned graph and jitted
+        # shard_map steps, and a relabeled graph of the same name and
+        # sizes must not.
         key = ("dist", g.name, g.n_nodes, g.n_edges, _csr_checksum(g),
                n_shards, node_axes,
                spec.window, spec.priority, fused, spec.balance, alg, plan,
@@ -794,7 +786,7 @@ class Session:
 
 
 # ---------------------------------------------------------------------------
-# the outlined chunk program (moved from core/engine.py, jaxpr-identical)
+# the outlined chunk program
 # ---------------------------------------------------------------------------
 
 def _chunk_impl(ig, colors, aux, wl, thresh, low, max_iter, it0, nd0, ns0,
@@ -859,20 +851,20 @@ _hybrid_chunk = jax.jit(
 
 
 # ---------------------------------------------------------------------------
-# process-default session (the one the thin legacy dispatchers share)
+# process-default session
 # ---------------------------------------------------------------------------
 
 _DEFAULT_SESSION: Session | None = None
 
 
 def default_session() -> Session:
-    """The process-wide session the legacy entry points run through, so
-    plain ``engine.color`` calls amortize preparation across requests."""
+    """The process-wide session, so callers that do not keep their own
+    amortize preparation across requests."""
     global _DEFAULT_SESSION
     if _DEFAULT_SESSION is None:
         # bounded: entries pin graphs, and nothing ever clears the
         # process-default store — an endless stream of distinct graphs
-        # through plain engine.color must not grow memory without limit
+        # must not grow memory without limit
         _DEFAULT_SESSION = Session(max_entries=256)
     return _DEFAULT_SESSION
 

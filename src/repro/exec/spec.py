@@ -1,24 +1,18 @@
 """``ExecutionSpec`` — the frozen description of HOW a coloring runs.
 
-The repo grew three dispatch regimes for the paper's persistent-worklist
-Pipe (DESIGN.md §9): the host loop, the device-resident outlined chunks,
-and the sharded ``shard_map`` driver. Each historically resolved its own
-knobs (algorithm, layout plan, policy mode, fused family, window, bucket
-ratio) from loose keyword arguments, which meant three disjoint compile
-caches and no way to say "this exact configuration" once and reuse it
-across requests.
+The paper's persistent-worklist Pipe runs in three dispatch regimes
+(DESIGN.md §9): the host loop, the device-resident outlined chunks, and
+the sharded ``shard_map`` driver. An ``ExecutionSpec`` freezes the full
+static configuration of a run once, so it can be reused across requests:
 
-An ``ExecutionSpec`` freezes the full static configuration:
-
-  regime x mode x algo x layout x policy knobs x fused/outline knobs
+  regime x mode x algo x layout x policy knobs x fused knob
 
 Every field is hashable (``algo`` may be an ``Algorithm`` instance and
 ``layout`` a ``LayoutPlan`` — both frozen dataclasses), so a spec rides
 jit static arguments and dict keys directly. ``Session`` (session.py)
 keys its unified compile cache on ``spec.static_key() x`` the graph's
-static fields; ``spec_for`` maps the legacy ``engine.color`` keyword
-surface onto a spec so the historical entry points stay bit-identical
-thin dispatchers.
+static fields. ``Session.run(spec, g)`` is the one way to color a graph,
+and ``regime`` is the one place a dispatch regime is chosen.
 
 Runtime-only inputs — a caller-supplied ``Policy`` instance (stateful,
 e.g. ``AutoTuned``), ``collect_tti``, a custom mesh — are deliberately
@@ -38,8 +32,8 @@ class ExecutionSpec:
     #: dispatch regime: "host" (per-iteration host loop), "outlined"
     #: (device-resident lax.while_loop chunks), "dist" (sharded Pipe)
     regime: str = "host"
-    #: policy mode ("hybrid" / "topology" / "data" / "hybrid-auto"; the
-    #: legacy "dist-*" prefix is accepted and stripped by make_policy)
+    #: policy mode ("hybrid" / "topology" / "data" / "hybrid-auto"); the
+    #: sharded Pipe is selected by ``regime="dist"``, not by the mode
     mode: str = "hybrid"
     #: registry name or frozen Algorithm instance
     algo: "str | object" = "ipgc"
@@ -71,6 +65,10 @@ class ExecutionSpec:
         if self.regime not in REGIMES:
             raise ValueError(
                 f"unknown regime {self.regime!r}; valid: {REGIMES}")
+        if self.mode.startswith("dist-"):
+            raise ValueError(
+                f"mode {self.mode!r}: the sharded Pipe is a regime, not a "
+                f"mode — pass regime='dist', mode={self.mode[5:]!r}")
         if self.exchange not in ("dense", "boundary", "auto"):
             raise ValueError(
                 f"unknown exchange {self.exchange!r}; valid: "
@@ -108,7 +106,7 @@ class ExecutionSpec:
             raise ValueError(
                 "lane-batched execution requires impl='jnp' (the Pallas "
                 "kernels are not audited under vmap)")
-        if self.mode.startswith("dist-") or self.mode == "hybrid-auto":
+        if self.mode == "hybrid-auto":
             raise ValueError(
                 f"lane-batched execution cannot replay mode {self.mode!r} "
                 "per lane: the batched Pipe needs a monotone per-lane "
@@ -127,42 +125,3 @@ class ExecutionSpec:
                 self.h, self.window, self.impl, self.bucket_ratio,
                 self.max_iter, self.priority, self.fused, self.n_shards,
                 self.balance, self.tile_rows, self.exchange)
-
-
-def spec_for(
-    *,
-    mode: str = "hybrid",
-    algo: "str | object" = "ipgc",
-    h: float = 0.6,
-    window: "int | str" = "auto",
-    impl: str = "jnp",
-    bucket_ratio: int = 2,
-    max_iter: int = 10_000,
-    priority: str = "hash",
-    fused: "bool | None" = None,
-    outline: "bool | None" = None,
-    n_shards: "int | None" = None,
-    layout: "str | object | None" = None,
-    balance: bool = True,
-    tile_rows: "int | str | None" = "auto",
-    exchange: str = "dense",
-) -> ExecutionSpec:
-    """Map the legacy ``engine.color`` keyword surface onto a spec.
-
-    Regime resolution mirrors the historical dispatch exactly:
-    ``mode="dist-*"`` wins, then ``outline`` (None consults
-    ``engine.outline_default()``), else the host loop.
-    """
-    if mode.startswith("dist-"):
-        regime = "dist"
-    else:
-        if outline is None:
-            from repro.core.engine import outline_default
-            outline = outline_default()
-        regime = "outlined" if outline else "host"
-    return ExecutionSpec(
-        regime=regime, mode=mode, algo=algo, layout=layout, h=h,
-        window=window, impl=impl, bucket_ratio=bucket_ratio,
-        max_iter=max_iter, priority=priority, fused=fused,
-        n_shards=n_shards, balance=balance, tile_rows=tile_rows,
-        exchange=exchange)

@@ -165,6 +165,29 @@ def plan_layout(degrees: np.ndarray, *, layout: str | LayoutPlan = "auto",
                      f"{LAYOUT_KINDS + ('auto',)}")
 
 
+def resolve_plan(g, layout):
+    """Resolve an ``ExecutionSpec.layout`` override to a static
+    ``LayoutPlan``.
+
+    ``None`` -> the plan the graph was assembled under. A kind string
+    re-dispatches *execution* on the same arrays (every assembly keeps
+    CSR complete and ELL+tail complete, so flipping e.g. an ell-tail
+    graph to ``"csr-segment"`` execution — or back — is always sound);
+    an explicit ``LayoutPlan`` is passed through. This is the layout
+    analogue of ``algo=``: the resolved plan rides the prepared graph's
+    static fields, so every step cache keys on it for free.
+    """
+    plan = getattr(g, "layout", None)
+    if layout is None:
+        return plan
+    if isinstance(layout, LayoutPlan):
+        return layout
+    if layout not in LAYOUT_KINDS:
+        raise ValueError(f"unknown layout {layout!r}; valid: "
+                         f"{LAYOUT_KINDS} (or a LayoutPlan)")
+    return dataclasses.replace(plan or LayoutPlan(), kind=layout)
+
+
 def run_pipeline(edges: EdgeList, *, symmetrize: bool = True,
                  reorder: str = "identity", seed: int = 0,
                  layout: "str | LayoutPlan" = "ell-tail",

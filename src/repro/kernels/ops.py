@@ -2,8 +2,8 @@
 
 On a TPU backend the kernels compile natively; on any other backend they
 execute in interpret mode (``interpret()``) so the engine's
-``impl="pallas"`` path stays testable end-to-end. CPU *benchmarks* use the jnp reference path (``impl="jnp"``)
-— interpret mode measures Python, not the kernel.
+``impl="pallas"`` path stays testable end-to-end. Interpret mode
+measures Python, not the kernel: time the kernels only on the chip.
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@
 
 Everything here is host-side Python on the injectable-clock convention;
 no instrument traces into a jaxpr — traced-vs-untraced runs are
-jaxpr-identical (tests/test_obs.py, BENCH_obs.json).
+jaxpr-identical (tests/test_obs.py).
 """
 from repro.obs.metrics import (Counter, CounterGroup, DEPTH_EDGES, Gauge,
                                Histogram, LATENCY_EDGES, MetricsRegistry,

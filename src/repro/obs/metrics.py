@@ -9,9 +9,7 @@ Three instrument kinds plus a registry:
     in pre-declared upper-edge buckets, so memory is O(#edges) no matter
     how many samples stream through. A reported percentile is the upper
     edge of the bucket containing that quantile rank — a deterministic
-    upper bound whose resolution is the bucket ladder, which replaces
-    the bench scripts' hand-rolled ``np.percentile`` over stored-sample
-    lists (benchmarks/bench_engine_modes.py --stream).
+    upper bound whose resolution is the bucket ladder.
   * ``CounterGroup`` — a named family of related counters with the
     dict-compatible surface the engine's trace-time accounting has
     always used (``group[k] += 1``, ``dict(group)``) PLUS a reset-scoped

@@ -20,15 +20,15 @@ counter groups (``ipgc.LAUNCH_COUNTS``, ``ipgc.GATHER_COUNTS``,
   * for the distributed regime: exchanges per iteration AND **bytes
     exchanged per iteration** — each ``color_psum`` moves one
     ``int32[N+1]`` delta per device, so ``bytes/iter = exchanges/iter
-    x 4(N+1)`` (the ROADMAP's BENCH_dist accounting gap);
+    x 4(N+1)``;
   * a time split: ``dispatch_seconds`` sums the per-dispatch timers,
     against the run's ``total_seconds``;
   * a cache snapshot (``CacheStats.as_dict()`` of the owning session at
     report time, plus this run's delta).
 
-``to_json()`` emits the JSON-safe schema ``benchmarks/regress.py`` and
-``examples/color_suite.py --json`` consume (colors array and live trace
-excluded; pass ``include_chrome=True`` to embed ``trace.to_chrome()``).
+``to_json()`` emits the JSON-safe schema ``examples/color_suite.py
+--json`` prints (colors array and live trace excluded; pass
+``include_chrome=True`` to embed ``trace.to_chrome()``).
 
 This module is pure data assembly — it imports nothing from the engine
 at module scope, so the counter-owning modules can import ``repro.obs``

@@ -78,10 +78,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ipgc
-from repro.core.engine import ColoringResult, resolve_plan
 from repro.core.policy import (Timer, device_threshold,
                                make_admission_policy, make_chunk_policy,
                                make_policy)
+from repro.core.result import ColoringResult
 from repro.core.worklist import Worklist, bucket_capacities, pick_bucket
 from repro.exec.batch import (_batched_chunk, _pow2, empty_lane,
                               fresh_lane_state, grow_shape_class,
@@ -89,6 +89,7 @@ from repro.exec.batch import (_batched_chunk, _pow2, empty_lane,
                               widen_lanes)
 from repro.exec.spec import ExecutionSpec
 from repro.graphs.csr import Graph
+from repro.graphs.layout import resolve_plan
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import (DEPTH_EDGES, LATENCY_EDGES, SLACK_EDGES,
                                MetricsRegistry)
@@ -506,7 +507,7 @@ class StreamSession:
         self.config = config or StreamConfig()
         self._alg = spec.validate_batchable()
         self._fused = self._alg.resolve_fused(spec.fused, default=False)
-        self._force_hub = ipgc.force_hub_enabled()
+        self._force_hub = False   # as Session.run's host regime
         self._tile_rows = (spec.tile_rows
                            if isinstance(spec.tile_rows, int) else None)
         self._algo_static = None if self._alg == IPGC() else self._alg
@@ -814,7 +815,7 @@ class StreamSession:
         up to a power of two) and per-group adaptive widths — plus the
         queue-depth/latency/occupancy instruments the pump/harvest loop
         has accumulated so far. ``to_json()`` is the machine-readable
-        service snapshot ``bench_engine_modes --stream`` records."""
+        service snapshot."""
         return RunReport(
             regime="stream", algo=str(self.spec.algo),
             graph=f"<stream:{self.counters['submitted']} submitted>",

@@ -13,8 +13,9 @@ import pytest
 
 from repro.algos import Algorithm, algorithm_names, get_algorithm
 from repro.algos.jpl import JPL, jpl_dense_step_impl, jpl_sparse_step_impl
-from repro.core import color, color_outlined_hybrid, ipgc, verify_coloring
+from repro.core import ipgc, verify_coloring
 from repro.core.worklist import full_worklist
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import build_graph, make_graph
 
 # power-law (kron), regular mesh (europe_osm), hub-heavy (hollywood)
@@ -64,9 +65,9 @@ def test_abstract_algorithm_rejected():
 # ---------------------------------------------------------------------------
 
 def _exec_modes(alg):
-    modes = [dict(outline=False), dict(outline=True)]
+    modes = [dict(regime="host"), dict(regime="outlined")]
     if alg.shard_safe:
-        modes.append(dict(mode="dist-hybrid", n_shards=1))
+        modes.append(dict(regime="dist", n_shards=1))
     return modes
 
 
@@ -76,7 +77,7 @@ def test_valid_coloring_all_declared_modes(graphs, name, algo):
     g = graphs[name]
     alg = get_algorithm(algo)
     for kw in _exec_modes(alg):
-        r = color(g, algo=algo, **({"mode": "hybrid"} | kw))
+        r = default_session().run(ExecutionSpec(algo=algo, **kw), g)
         verify_coloring(g, r.colors, context=f"{algo} {kw}")
         alg.check_invariants(r, g)
         assert r.n_colors >= 1
@@ -86,17 +87,18 @@ def test_valid_coloring_all_declared_modes(graphs, name, algo):
 def test_policy_degenerate_modes(graphs, algo):
     g = graphs["kron_g500-logn21_s"]
     for mode in ("topology", "data"):
-        r = color(g, algo=algo, mode=mode, outline=False)
+        r = default_session().run(ExecutionSpec(algo=algo, mode=mode), g)
         verify_coloring(g, r.colors, context=f"{algo} {mode}")
 
 
 def test_jpl_edge_cases():
+    jpl = ExecutionSpec(algo="jpl")
     one = build_graph(np.array([0]), np.array([0]), 1, name="one")
-    r = color(one, algo="jpl")
+    r = default_session().run(jpl, one)
     assert r.n_colors == 1
     tri = build_graph(np.array([0, 1, 2]), np.array([1, 2, 0]), 3,
                       name="tri")
-    r = color(tri, algo="jpl")
+    r = default_session().run(jpl, tri)
     verify_coloring(tri, r.colors)
     assert r.n_colors == 3                      # triangle floor holds
 
@@ -108,8 +110,8 @@ def test_jpl_edge_cases():
 @pytest.mark.parametrize("name", GRAPHS)
 def test_ipgc_algo_bit_identical_host_loop(graphs, name):
     g = graphs[name]
-    r0 = color(g, mode="hybrid", outline=False)          # default path
-    r1 = color(g, mode="hybrid", algo="ipgc", outline=False)
+    r0 = default_session().run(ExecutionSpec(), g)       # default path
+    r1 = default_session().run(ExecutionSpec(algo="ipgc"), g)
     np.testing.assert_array_equal(r0.colors, r1.colors)
     assert r0.iterations == r1.iterations
     assert r0.mode_trace == r1.mode_trace
@@ -118,13 +120,15 @@ def test_ipgc_algo_bit_identical_host_loop(graphs, name):
 
 def test_ipgc_algo_bit_identical_outlined_and_dist(graphs):
     g = graphs["kron_g500-logn21_s"]
-    ro0 = color_outlined_hybrid(g)
-    ro1 = color_outlined_hybrid(g, algo="ipgc")
+    ro0 = default_session().run(ExecutionSpec(regime="outlined"), g)
+    ro1 = default_session().run(
+        ExecutionSpec(regime="outlined", algo="ipgc"), g)
     np.testing.assert_array_equal(ro0.colors, ro1.colors)
     assert (ro0.iterations, ro0.mode_trace) == (ro1.iterations,
                                                 ro1.mode_trace)
-    rd0 = color(g, mode="dist-hybrid", n_shards=1)
-    rd1 = color(g, mode="dist-hybrid", algo="ipgc", n_shards=1)
+    rd0 = default_session().run(ExecutionSpec(regime="dist", n_shards=1), g)
+    rd1 = default_session().run(
+        ExecutionSpec(regime="dist", algo="ipgc", n_shards=1), g)
     np.testing.assert_array_equal(rd0.colors, rd1.colors)
     assert (rd0.iterations, rd0.mode_trace) == (rd1.iterations,
                                                 rd1.mode_trace)
@@ -139,10 +143,11 @@ def test_jpl_colors_invariant_across_modes(graphs):
     priority draw each round, so host/outlined/policy-mode colorings are
     IDENTICAL (stronger than IPGC's cross-mode equality)."""
     g = graphs["europe_osm_s"]
-    r_h = color(g, algo="jpl", mode="hybrid", outline=False)
-    r_t = color(g, algo="jpl", mode="topology", outline=False)
-    r_d = color(g, algo="jpl", mode="data", outline=False)
-    r_o = color(g, algo="jpl", mode="hybrid", outline=True)
+    r_h = default_session().run(ExecutionSpec(algo="jpl"), g)
+    r_t = default_session().run(ExecutionSpec(algo="jpl", mode="topology"), g)
+    r_d = default_session().run(ExecutionSpec(algo="jpl", mode="data"), g)
+    r_o = default_session().run(
+        ExecutionSpec(regime="outlined", algo="jpl"), g)
     for r in (r_t, r_d, r_o):
         np.testing.assert_array_equal(r_h.colors, r.colors)
         assert r.iterations == r_h.iterations
@@ -150,8 +155,8 @@ def test_jpl_colors_invariant_across_modes(graphs):
 
 def test_jpl_impl_parity(graphs):
     g = graphs["hollywood-2009_s"]       # hub-heavy: exercises tail extrema
-    r_j = color(g, algo="jpl", impl="jnp", outline=False)
-    r_p = color(g, algo="jpl", impl="pallas", outline=False)
+    r_j = default_session().run(ExecutionSpec(algo="jpl", impl="jnp"), g)
+    r_p = default_session().run(ExecutionSpec(algo="jpl", impl="pallas"), g)
     np.testing.assert_array_equal(r_j.colors, r_p.colors)
 
 
@@ -177,13 +182,17 @@ def test_jpl_quality_gap_vs_ipgc(graphs):
     independent-set colorer trades color quality for round speed."""
     worse = 0
     for name, g in graphs.items():
-        if color(g, algo="jpl").n_colors < color(g, algo="ipgc").n_colors:
+        n_jpl = default_session().run(ExecutionSpec(algo="jpl"), g).n_colors
+        n_ipgc = default_session().run(ExecutionSpec(algo="ipgc"),
+                                       g).n_colors
+        if n_jpl < n_ipgc:
             worse += 1
     assert worse == 0
 
 
 def test_jpl_palette_is_compact(graphs):
-    r = color(graphs["kron_g500-logn21_s"], algo="jpl")
+    r = default_session().run(ExecutionSpec(algo="jpl"),
+                              graphs["kron_g500-logn21_s"])
     used = np.unique(r.colors[r.colors >= 0])
     np.testing.assert_array_equal(used, np.arange(len(used)))
     assert r.n_colors == len(used)
@@ -193,21 +202,23 @@ def test_spec_greedy_pins_fused_family(graphs):
     """spec-greedy IS deferred detect-and-repair: the caller's ``fused``
     request cannot reintroduce a same-iteration resolve phase."""
     g = graphs["europe_osm_s"]
-    r_def = color(g, algo="spec-greedy", outline=False)
-    r_f0 = color(g, algo="spec-greedy", outline=False, fused=False)
+    r_def = default_session().run(ExecutionSpec(algo="spec-greedy"), g)
+    r_f0 = default_session().run(
+        ExecutionSpec(algo="spec-greedy", fused=False), g)
     np.testing.assert_array_equal(r_def.colors, r_f0.colors)
     assert r_def.iterations == r_f0.iterations
     # same trajectory as the fused IPGC steps it reuses (palette aside)
-    r_ipgc = color(g, algo="ipgc", outline=False, fused=True)
+    r_ipgc = default_session().run(ExecutionSpec(algo="ipgc", fused=True), g)
     assert r_def.iterations == r_ipgc.iterations
     assert r_def.mode_trace == r_ipgc.mode_trace
 
 
 def test_spec_greedy_dist_matches_quality(graphs):
     g = graphs["kron_g500-logn21_s"]
-    r = color(g, algo="spec-greedy", mode="dist-hybrid", n_shards=1)
+    r = default_session().run(
+        ExecutionSpec(regime="dist", algo="spec-greedy", n_shards=1), g)
     verify_coloring(g, r.colors, context="spec-greedy dist")
-    r_host = color(g, algo="spec-greedy", outline=False)
+    r_host = default_session().run(ExecutionSpec(algo="spec-greedy"), g)
     # dist repartitions (relabels) the graph, so exact colors differ; the
     # class count must stay in the same band
     assert abs(r.n_colors - r_host.n_colors) <= max(4, r_host.n_colors // 2)
@@ -221,14 +232,16 @@ def test_dist_rejects_non_shard_safe():
         shard_unsafe_reason="stub: declaration-contract test")
     g = make_graph("europe_osm_s", scale=0.01)
     with pytest.raises(ValueError, match="not shard-safe"):
-        color(g, algo=stub, mode="dist-hybrid", n_shards=1)
+        default_session().run(
+            ExecutionSpec(regime="dist", algo=stub, n_shards=1), g)
 
 
 def test_custom_algorithm_instance_accepted(graphs):
     """The registry is open: an unregistered instance rides through
     ``algo=`` directly (tuned variants need no global name)."""
     tuned = JPL(name="jpl-tuned")
-    r = color(graphs["europe_osm_s"], algo=tuned, outline=False)
+    r = default_session().run(ExecutionSpec(algo=tuned),
+                              graphs["europe_osm_s"])
     verify_coloring(graphs["europe_osm_s"], r.colors)
 
 
@@ -238,8 +251,10 @@ def test_outlined_specialisation_not_keyed_on_name(graphs):
     a different algorithm carrying the name "ipgc" keeps its own steps."""
     g = graphs["europe_osm_s"]
     rogue = JPL(name="ipgc")
-    r = color(g, algo=rogue, outline=True)
-    r_jpl = color(g, algo="jpl", outline=True)
+    r = default_session().run(ExecutionSpec(regime="outlined", algo=rogue),
+                              g)
+    r_jpl = default_session().run(
+        ExecutionSpec(regime="outlined", algo="jpl"), g)
     np.testing.assert_array_equal(r.colors, r_jpl.colors)
     assert r.iterations == r_jpl.iterations
 
@@ -261,8 +276,9 @@ def test_jpl_round_counter_rides_outlining(graphs):
     color classes 2r/2r+1 only line up if every on-device trip advanced
     the same counter the host loop would have."""
     g = graphs["kron_g500-logn21_s"]
-    r_host = color(g, algo="jpl", outline=False)
-    r_out = color(g, algo="jpl", outline=True)
+    r_host = default_session().run(ExecutionSpec(algo="jpl"), g)
+    r_out = default_session().run(
+        ExecutionSpec(regime="outlined", algo="jpl"), g)
     np.testing.assert_array_equal(r_host.colors, r_out.colors)
     assert r_host.iterations == r_out.iterations
     assert r_out.host_dispatches <= r_host.host_dispatches
@@ -288,9 +304,20 @@ def test_jpl_extrema_kernel_matches_ref():
 
 def test_jpl_hub_side_channel(graphs):
     """Hub COO-tail priorities must reach the extrema fold: force the hub
-    side-channel on a hubless mesh graph and require identical output."""
+    side-channel on a hubless mesh graph and require identical output,
+    step for step, from the dense and the sparse round."""
     g = graphs["europe_osm_s"]
-    with ipgc.forced_hub(True):
-        r_forced = color(g, algo="jpl", outline=False)
-    r_plain = color(g, algo="jpl", outline=False)
-    np.testing.assert_array_equal(r_forced.colors, r_plain.colors)
+    alg = get_algorithm("jpl")
+    ig = alg.prepare(g)
+    assert ig.n_hub == 0          # only force_hub turns the fold on
+    dense, sparse = alg.step_fns(False)
+    state = {fh: alg.init_state(ig) for fh in (False, True)}
+    for it in range(4 * ig.n_nodes):
+        step = dense if it % 2 == 0 else sparse
+        for fh in (False, True):
+            state[fh] = step(ig, *state[fh], window=128, impl="jnp",
+                             force_hub=fh, tile_rows=None)[:3]
+        np.testing.assert_array_equal(state[True][0], state[False][0])
+        if int(state[False][2].count) == 0:
+            break
+    assert int(state[True][2].count) == 0

@@ -43,13 +43,15 @@ def test_bfs_property_random_graphs(n, density, data):
 
 
 def test_outlined_engine_matches_hybrid():
-    from repro.core import color
-    from repro.core.engine import color_outlined
+    from repro.exec import ExecutionSpec, default_session
     g = make_graph("kron_g500-logn21_s", scale=0.02)
-    r_o = color_outlined(g, window=64)
-    r_h = color(g, mode="topology", window=64)
+    r_o = default_session().run(
+        ExecutionSpec(regime="outlined", mode="topology", window=64), g)
+    r_h = default_session().run(
+        ExecutionSpec(mode="topology", window=64), g)
     np.testing.assert_array_equal(r_o.colors, r_h.colors)
     assert r_o.iterations == r_h.iterations
+    assert r_o.mode_trace == r_h.mode_trace == "D" * r_h.iterations
 
 
 def test_bfs_pallas_impl_parity():

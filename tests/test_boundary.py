@@ -9,7 +9,8 @@ from tests._hyp import HAVE_HYPOTHESIS, HYPOTHESIS_SKIP_REASON, given, \
     settings, st
 from tests.test_distributed import _run_forced_devices
 
-from repro.core import color, verify_coloring
+from repro.core import verify_coloring
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import build_graph, make_graph
 from repro.graphs.partition import (boundary_capacities, boundary_info,
                                     exchange_break_even, ghost_ids,
@@ -129,12 +130,13 @@ def test_exchange_modes_bit_identical_single_shard():
     for algo in ("ipgc", "spec-greedy", "jpl"):
         g2, relabel = prepare_partition(g, 1)
         fused = None if algo == "jpl" else True
-        r_h = color(g2, mode="hybrid", algo=algo, fused=fused,
-                    outline=False)
+        r_h = default_session().run(ExecutionSpec(algo=algo, fused=fused),
+                                    g2)
         ref = r_h.colors[relabel[:g.n_nodes]]
         for ex in ("dense", "boundary", "auto"):
-            r = color(g, mode="dist-hybrid", algo=algo, n_shards=1,
-                      exchange=ex)
+            r = default_session().run(
+                ExecutionSpec(regime="dist", algo=algo, n_shards=1,
+                              exchange=ex), g)
             verify_coloring(g, r.colors, context=f"{algo}/{ex}")
             np.testing.assert_array_equal(r.colors, ref)
             assert r.iterations == r_h.iterations, (algo, ex)
@@ -148,7 +150,8 @@ def test_exchange_modes_bit_identical_multishard_subprocess():
     bit-identical to the host engine AND to the dense-exchange path."""
     code = """
 import numpy as np
-from repro.core import color, verify_coloring
+from repro.core import verify_coloring
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import make_graph
 from repro.graphs.partition import prepare_partition
 g = make_graph("europe_osm_s", scale=0.01)
@@ -156,12 +159,13 @@ for algo in ("ipgc", "spec-greedy", "jpl"):
     for s in (1, 2, 8):
         g2, relabel = prepare_partition(g, s)
         fused = None if algo == "jpl" else True
-        r_h = color(g2, mode="hybrid", algo=algo, fused=fused,
-                    outline=False)
+        r_h = default_session().run(ExecutionSpec(algo=algo, fused=fused),
+                                    g2)
         ref = r_h.colors[relabel[:g.n_nodes]]
         for ex in ("dense", "boundary", "auto"):
-            r = color(g, mode="dist-hybrid", algo=algo, n_shards=s,
-                      exchange=ex)
+            r = default_session().run(
+                ExecutionSpec(regime="dist", algo=algo, n_shards=s,
+                              exchange=ex), g)
             verify_coloring(g, r.colors, context=f"{algo}/{ex}/{s}")
             np.testing.assert_array_equal(r.colors, ref)
             assert r.iterations == r_h.iterations, (algo, ex, s)
@@ -262,10 +266,12 @@ def test_report_traffic_win_visible():
     partition-friendly graph the auto path must move fewer bytes than
     the dense path once the worklist thins (the PR's point)."""
     g = make_graph("europe_osm_s", scale=0.02)
-    r_dense = color(g, mode="dist-hybrid", n_shards=1, exchange="dense",
-                    trace=True)
-    r_auto = color(g, mode="dist-hybrid", n_shards=1, exchange="auto",
-                   trace=True)
+    r_dense = default_session().run(
+        ExecutionSpec(regime="dist", n_shards=1, exchange="dense"), g,
+        trace=True)
+    r_auto = default_session().run(
+        ExecutionSpec(regime="dist", n_shards=1, exchange="auto"), g,
+        trace=True)
     np.testing.assert_array_equal(r_dense.colors, r_auto.colors)
     xd = r_dense.exchanges
     xa = r_auto.exchanges

@@ -2,10 +2,10 @@
 import numpy as np
 import pytest
 
-from repro.core import (color, jpl_color, vb_color, bucket_capacities,
-                        verify_coloring)
+from repro.core import jpl_color, bucket_capacities, verify_coloring
 from repro.core.policy import make_policy, AutoTuned
 from repro.core.worklist import pick_bucket
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import make_graph, validate_coloring, build_graph
 
 GRAPHS = ["europe_osm_s", "kron_g500-logn21_s", "Audikw_1_s", "circuit5M_s"]
@@ -19,21 +19,24 @@ def graphs():
 @pytest.mark.parametrize("mode", ["topology", "data", "hybrid", "hybrid-auto"])
 @pytest.mark.parametrize("name", GRAPHS)
 def test_engine_valid_coloring(graphs, name, mode):
-    r = color(graphs[name], mode=mode)
+    r = default_session().run(ExecutionSpec(mode=mode), graphs[name])
     verify_coloring(graphs[name], r.colors, context=f"{name}/{mode}")
     assert r.n_colors >= 1
 
 
 @pytest.mark.parametrize("name", GRAPHS)
 def test_baselines_valid(graphs, name):
-    for fn in (jpl_color, vb_color):
-        r = fn(graphs[name])
+    # JPL, and the Kokkos vertex-based baseline: data-driven, 32-wide
+    # forbidden window, hash tie-break
+    vb = ExecutionSpec(mode="data", window=32, priority="hash")
+    for r in (jpl_color(graphs[name]),
+              default_session().run(vb, graphs[name])):
         verify_coloring(graphs[name], r.colors, context=name)
 
 
 def test_hybrid_switches_at_h(graphs):
     g = graphs["kron_g500-logn21_s"]
-    r = color(g, mode="hybrid", h=0.6)
+    r = default_session().run(ExecutionSpec(mode="hybrid", h=0.6), g)
     # trace must be a (possibly empty) run of D followed by only S —
     # the active set shrinks monotonically so the policy flips once
     t = r.mode_trace
@@ -43,7 +46,7 @@ def test_hybrid_switches_at_h(graphs):
 
 def test_worklist_monotone_shrink(graphs):
     g = graphs["kron_g500-logn21_s"]
-    r = color(g, mode="hybrid")
+    r = default_session().run(ExecutionSpec(mode="hybrid"), g)
     assert all(b <= a for a, b in zip(r.counts, r.counts[1:])), r.counts
 
 
@@ -52,7 +55,7 @@ def test_ipgc_fewer_colors_than_jpl(graphs):
     colors than independent-set (cuSPARSE-style) coloring."""
     worse = 0
     for name, g in graphs.items():
-        c_h = color(g, mode="hybrid").n_colors
+        c_h = default_session().run(ExecutionSpec(mode="hybrid"), g).n_colors
         c_j = jpl_color(g).n_colors
         if c_j < c_h:
             worse += 1
@@ -64,35 +67,35 @@ def test_same_colors_across_modes(graphs):
     'they all implement exactly the same algorithm for assigning colors,
     just with different optimizations') — identical colorings."""
     g = graphs["Audikw_1_s"]
-    r_t = color(g, mode="topology")
-    r_d = color(g, mode="data")
-    r_h = color(g, mode="hybrid")
+    r_t = default_session().run(ExecutionSpec(mode="topology"), g)
+    r_d = default_session().run(ExecutionSpec(mode="data"), g)
+    r_h = default_session().run(ExecutionSpec(mode="hybrid"), g)
     np.testing.assert_array_equal(r_t.colors, r_d.colors)
     np.testing.assert_array_equal(r_t.colors, r_h.colors)
 
 
 def test_impl_parity_jnp_pallas(graphs):
     g = graphs["circuit5M_s"]
-    r_j = color(g, mode="hybrid", impl="jnp")
-    r_p = color(g, mode="hybrid", impl="pallas")
+    r_j = default_session().run(ExecutionSpec(mode="hybrid", impl="jnp"), g)
+    r_p = default_session().run(ExecutionSpec(mode="hybrid", impl="pallas"), g)
     np.testing.assert_array_equal(r_j.colors, r_p.colors)
 
 
 def test_triangle_and_star():
     # triangle needs exactly 3 colors, star needs 2
     tri = build_graph(np.array([0, 1, 2]), np.array([1, 2, 0]), 3, name="tri")
-    r = color(tri, mode="hybrid")
+    r = default_session().run(ExecutionSpec(mode="hybrid"), tri)
     assert r.n_colors == 3
     assert validate_coloring(tri, r.colors)["conflicts"] == 0
     star = build_graph(np.zeros(10, int), np.arange(1, 11), 11, name="star")
-    r = color(star, mode="hybrid")
+    r = default_session().run(ExecutionSpec(mode="hybrid"), star)
     assert r.n_colors == 2
 
 
 def test_mex_optimality_on_isolated_nodes():
     # nodes with no neighbours all take color 0
     g = build_graph(np.array([0]), np.array([1]), 8, name="pair")
-    r = color(g, mode="data")
+    r = default_session().run(ExecutionSpec(mode="data"), g)
     assert set(np.asarray(r.colors)[2:].tolist()) == {0}
 
 
@@ -125,6 +128,6 @@ def test_window_exhaustion_hub():
     n = 200
     s, d = np.meshgrid(np.arange(n), np.arange(n))
     g = build_graph(s.ravel(), d.ravel(), n, name="K200", ell_cap=64)
-    r = color(g, mode="hybrid", window=128)
+    r = default_session().run(ExecutionSpec(mode="hybrid", window=128), g)
     verify_coloring(g, r.colors)
     assert r.n_colors == n

@@ -10,10 +10,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import color, color_distributed, ipgc, verify_coloring
+from repro.core import ipgc, verify_coloring
 from repro.core.distributed import (EXCHANGE_COUNTS, make_dist_dense_step,
                                     make_dist_sparse_step)
 from repro.core.worklist import full_worklist
+from repro.exec import ExecutionSpec, Session, default_session
 from repro.graphs import build_graph, make_graph, validate_coloring
 from repro.graphs.partition import prepare_partition
 
@@ -102,15 +103,17 @@ def test_color_distributed_multishard_subprocess():
     labeling), iteration count and mode trace — on >= 3 suite graphs."""
     code = """
 import jax, numpy as np
-from repro.core import color, color_distributed, verify_coloring
+from repro.core import verify_coloring
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import make_graph
 from repro.graphs.partition import prepare_partition
 for name in ["europe_osm_s", "kron_g500-logn21_s", "hollywood-2009_s"]:
     g = make_graph(name, scale=0.01)
     for s in (1, 2, 8):
-        r_d = color_distributed(g, n_shards=s)
+        r_d = default_session().run(ExecutionSpec(regime="dist", n_shards=s),
+                                    g)
         g2, relabel = prepare_partition(g, s)
-        r_h = color(g2, mode="hybrid", fused=True, outline=False)
+        r_h = default_session().run(ExecutionSpec(fused=True), g2)
         verify_coloring(g, r_d.colors, context=f"{name}/shards_{s}")
         np.testing.assert_array_equal(r_d.colors,
                                       r_h.colors[relabel[:g.n_nodes]])
@@ -129,16 +132,18 @@ def test_dist_two_phase_ell_kinds_multishard_subprocess():
     two-phase engine (colors, iterations, mode trace)."""
     code = """
 import numpy as np
-from repro.core import color, color_distributed, verify_coloring
+from repro.core import verify_coloring
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import get_dataset
 from repro.graphs.partition import prepare_partition
 for kind in ("pure-ell", "ell-tail", "hub-split"):
     g = get_dataset("kron_g500-logn21_s", scale=0.01, layout=kind,
                     **({} if kind == "pure-ell" else {"ell_cap": 16}))
     g2, relabel = prepare_partition(g, 4)
-    r_h = color(g2, mode="hybrid", fused=False, outline=False)
+    r_h = default_session().run(ExecutionSpec(fused=False), g2)
     for ex in ("dense", "auto"):
-        r = color_distributed(g, n_shards=4, fused=False, exchange=ex)
+        r = default_session().run(ExecutionSpec(
+            regime="dist", n_shards=4, fused=False, exchange=ex), g)
         verify_coloring(g, r.colors, context=f"{kind}/{ex}")
         np.testing.assert_array_equal(r.colors, r_h.colors[relabel[:g.n_nodes]])
         assert (r.iterations, r.mode_trace) == (r_h.iterations,
@@ -205,9 +210,9 @@ def test_color_distributed_matches_host_engine(name):
     count and mode trace as the host-loop Pipe on the repartitioned graph,
     with colors returned in the ORIGINAL labeling."""
     g = make_graph(name, scale=0.01)
-    r_d = color_distributed(g, n_shards=1)
+    r_d = default_session().run(ExecutionSpec(regime="dist", n_shards=1), g)
     g2, relabel = prepare_partition(g, 1)
-    r_h = color(g2, mode="hybrid", fused=True, outline=False)
+    r_h = default_session().run(ExecutionSpec(fused=True), g2)
     verify_coloring(g, r_d.colors)
     np.testing.assert_array_equal(r_d.colors, r_h.colors[relabel[:g.n_nodes]])
     assert r_d.iterations == r_h.iterations
@@ -216,21 +221,22 @@ def test_color_distributed_matches_host_engine(name):
 
 
 def test_color_dist_mode_dispatch():
-    """engine.color(mode="dist-hybrid") routes through the sharded Pipe,
-    forwards ``fused``, and a shared steps_cache reproduces the uncached
-    run without rebuilding the jitted steps."""
+    """``regime="dist"`` routes through the sharded Pipe, forwards
+    ``fused``, and a shared cache store reproduces the uncached run
+    without rebuilding the jitted steps."""
     g = make_graph("kron_g500-logn21_s", scale=0.01)
-    r = color(g, mode="dist-hybrid", n_shards=1)
+    dist = ExecutionSpec(regime="dist", n_shards=1)
+    r = default_session().run(dist, g)
     verify_coloring(g, r.colors)
-    np.testing.assert_array_equal(r.colors,
-                                  color_distributed(g, n_shards=1).colors)
-    r2p = color(g, mode="dist-hybrid", n_shards=1, fused=False)
-    np.testing.assert_array_equal(
-        r2p.colors, color_distributed(g, n_shards=1, fused=False).colors)
+    np.testing.assert_array_equal(r.colors, Session().run(dist, g).colors)
+    two_phase = ExecutionSpec(regime="dist", n_shards=1, fused=False)
+    r2p = default_session().run(two_phase, g)
+    np.testing.assert_array_equal(r2p.colors,
+                                  Session().run(two_phase, g).colors)
     cache: dict = {}
-    a = color_distributed(g, n_shards=1, steps_cache=cache)
+    a = Session(cache=cache).run(dist, g)
     assert len(cache) == 1
-    b = color_distributed(g, n_shards=1, steps_cache=cache)
+    b = Session(cache=cache).run(dist, g)
     assert len(cache) == 1                     # reused, not rebuilt
     np.testing.assert_array_equal(a.colors, b.colors)
     np.testing.assert_array_equal(a.colors, r.colors)
@@ -241,25 +247,26 @@ def test_color_distributed_degenerate_policies():
     """The sharded Pipe supports the paper's degenerate baselines too —
     the persistent worklist keeps both modes correct on their own."""
     g = make_graph("europe_osm_s", scale=0.01)
+    traces = {}
     for mode in ("topology", "data"):
-        r = color_distributed(g, n_shards=1, mode=mode)
+        r = default_session().run(
+            ExecutionSpec(regime="dist", n_shards=1, mode=mode), g)
         verify_coloring(g, r.colors, context=mode)
-    assert set(color_distributed(g, n_shards=1, mode="topology").mode_trace) \
-        == {"D"}
-    assert set(color_distributed(g, n_shards=1, mode="data").mode_trace) \
-        == {"S"}
+        traces[mode] = set(r.mode_trace)
+    assert traces == {"topology": {"D"}, "data": {"S"}}
 
 
 def test_color_distributed_edge_cases():
     # 1-node graph (the only edge is a removed self loop) — padding to the
     # 8-aligned block makes the real node a minority of its own shard
+    dist = ExecutionSpec(regime="dist", n_shards=1)
     one = build_graph(np.array([0]), np.array([0]), 1, name="one")
-    r = color_distributed(one, n_shards=1)
+    r = default_session().run(dist, one)
     assert validate_coloring(one, r.colors) == {
         "conflicts": 0, "uncolored": 0, "n_colors": 1}
     # empty-after-preprocessing graph
     empty = build_graph(np.array([3]), np.array([3]), 8, name="empty")
-    r = color_distributed(empty, n_shards=1)
+    r = default_session().run(dist, empty)
     v = validate_coloring(empty, r.colors)
     assert v["conflicts"] == 0 and v["uncolored"] == 0 and v["n_colors"] == 1
 
@@ -332,7 +339,8 @@ def test_dist_packed_hub_tails_multishard_subprocess():
     code = """
 import numpy as np
 import jax.numpy as jnp
-from repro.core import color, ipgc, verify_coloring
+from repro.core import ipgc, verify_coloring
+from repro.exec import ExecutionSpec, default_session
 import jax
 from repro.core.distributed import _local_graph_view, shard_graph
 from repro.graphs import generators, ingest
@@ -351,11 +359,11 @@ view = _local_graph_view(ig, sg, ig.n_nodes, jax.tree.map(jnp.asarray, (
     tuple(a[:a.shape[0] // 4] for a in tails))))
 assert ipgc._hub_packed(view, jnp.zeros((1024,), jnp.int32)) is not None
 for fused in (True, False):
-    r_h = color(g2, mode="hybrid", fused=fused, outline=False)
+    r_h = default_session().run(ExecutionSpec(fused=fused), g2)
     ref = r_h.colors[relabel[:g.n_nodes]]
     for ex in ("dense", "boundary", "auto"):
-        r = color(g, mode="dist-hybrid", n_shards=4, exchange=ex,
-                  fused=fused)
+        r = default_session().run(ExecutionSpec(
+            regime="dist", n_shards=4, exchange=ex, fused=fused), g)
         verify_coloring(g, r.colors, context=f"{fused}/{ex}")
         np.testing.assert_array_equal(r.colors, ref)
         assert (r.iterations, r.mode_trace) == (r_h.iterations,

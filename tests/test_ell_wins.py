@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 from jax.extend.core import Jaxpr, Literal
 
-from repro.core import color, color_distributed, ipgc, verify_coloring
+from repro.core import ipgc, verify_coloring
 from repro.core.worklist import full_worklist
 from repro.exec import ExecutionSpec, Session
 from repro.graphs import build_graph, get_dataset
@@ -193,7 +193,7 @@ def test_ell_kinds_color_as_csr_segment(kinds, kind, priority, regime):
     if regime == "dist":
         g2, relabel = prepare_partition(g, 1)
         ref = s.run(dataclasses.replace(host, layout="csr-segment"), g2)
-        r = color_distributed(g, n_shards=1, fused=False, priority=priority)
+        r = s.run(dataclasses.replace(host, regime="dist", n_shards=1), g)
         np.testing.assert_array_equal(r.colors, ref.colors[relabel[:g.n_nodes]])
         assert (r.iterations, r.mode_trace) == (ref.iterations,
                                                 ref.mode_trace)

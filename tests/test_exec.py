@@ -1,16 +1,15 @@
 """Unified execution sessions (DESIGN.md §9): spec/session contracts,
-legacy-entry-point bit-identity through the session layer, the unified
-compile cache, and batched multi-graph bit-identity."""
+warm-vs-cold bit-identity per regime, the unified compile cache, and
+batched multi-graph bit-identity."""
 import dataclasses
 
 import jax
 import numpy as np
 import pytest
 
-from repro.core import (color, color_distributed, color_outlined_hybrid,
-                        ipgc, verify_coloring)
+from repro.core import ipgc, verify_coloring
 from repro.core.worklist import stacked_worklist
-from repro.exec import ExecutionSpec, Session, default_session, spec_for
+from repro.exec import ExecutionSpec, Session, default_session
 from repro.graphs import get_dataset, get_dataset_batch, make_graph
 
 GRAPHS = ["europe_osm_s", "kron_g500-logn21_s", "hollywood-2009_s"]
@@ -38,64 +37,62 @@ def _same_result(a, b, *, dispatches=True):
 def test_spec_validates_regime_and_is_hashable():
     with pytest.raises(ValueError, match="regime"):
         ExecutionSpec(regime="warp")
+    # the sharded Pipe is chosen by the regime alone, never by the mode
+    for mode in ("dist-hybrid", "dist-topology"):
+        with pytest.raises(ValueError, match="regime='dist'"):
+            ExecutionSpec(mode=mode)
     s = ExecutionSpec(regime="host", window=64)
     assert hash(s.static_key())          # usable as a cache key
     assert s.static_key() != ExecutionSpec(regime="outlined",
                                            window=64).static_key()
 
 
-def test_spec_for_maps_the_legacy_keyword_surface():
-    assert spec_for(mode="dist-hybrid", n_shards=2).regime == "dist"
-    assert spec_for(outline=True).regime == "outlined"
-    assert spec_for(outline=False).regime == "host"
-    from repro.core.engine import outlined
-    with outlined(True):
-        assert spec_for().regime == "outlined"
-    with outlined(False):
-        assert spec_for().regime == "host"
-
-
 # ---------------------------------------------------------------------------
-# Session.run — one executor behind the three Pipes, bit-identically
+# Session.run — a warm run on the same session is bit-identical to its
+# cold first run (the benchmark warms up, then times a window of runs)
 # ---------------------------------------------------------------------------
+
+def _cold_then_warm(spec, g):
+    s = Session()
+    cold = s.run(spec, g)
+    warm = s.run(spec, g)
+    _same_result(cold, warm)
+    return warm
+
 
 @pytest.mark.parametrize("fused", [False, True])
 @pytest.mark.parametrize("name", GRAPHS)
 def test_session_run_matches_host_entry_point(graphs, name, fused):
     g = graphs[name]
-    s = Session()
-    r_sess = s.run(ExecutionSpec(regime="host", fused=fused), g)
-    r_legacy = color(g, mode="hybrid", fused=fused, outline=False)
-    _same_result(r_sess, r_legacy)
-    verify_coloring(g, r_sess.colors, context=name)
-    assert r_sess.host_dispatches == r_sess.iterations   # host-loop contract
+    r = _cold_then_warm(ExecutionSpec(regime="host", fused=fused), g)
+    verify_coloring(g, r.colors, context=name)
+    assert r.host_dispatches == r.iterations   # host-loop contract
 
 
 def test_session_run_matches_outlined_entry_point(graphs):
     g = graphs["kron_g500-logn21_s"]
-    s = Session()
-    r_sess = s.run(ExecutionSpec(regime="outlined", fused=False), g)
-    r_legacy = color_outlined_hybrid(g, fused=False)
-    _same_result(r_sess, r_legacy)
-    assert r_sess.host_dispatches < r_sess.iterations    # chunked contract
+    r = _cold_then_warm(ExecutionSpec(regime="outlined", fused=False), g)
+    verify_coloring(g, r.colors)
+    assert r.host_dispatches < r.iterations    # chunked contract
 
 
 def test_session_run_matches_dist_entry_point(graphs):
     g = graphs["europe_osm_s"]
-    s = Session()
-    r_sess = s.run(ExecutionSpec(regime="dist", n_shards=1), g)
-    r_legacy = color_distributed(g, n_shards=1, steps_cache={})
-    _same_result(r_sess, r_legacy)
-    verify_coloring(g, r_sess.colors)
+    r = _cold_then_warm(ExecutionSpec(regime="dist", n_shards=1), g)
+    verify_coloring(g, r.colors)
+    assert r.host_dispatches == r.iterations   # one dispatch an iteration
 
 
 def test_legacy_steps_cache_still_accepted_and_reused(graphs):
+    # a caller-supplied dict is the session's store: a dist run fills it,
+    # and a warm run on a new session over the same dict adds nothing
     g = graphs["europe_osm_s"]
     cache: dict = {}
-    a = color_distributed(g, n_shards=1, steps_cache=cache)
-    assert len(cache) > 0                 # the dict IS the session store
+    spec = ExecutionSpec(regime="dist", n_shards=1)
+    a = Session(cache=cache).run(spec, g)
+    assert len(cache) > 0
     n_entries = len(cache)
-    b = color_distributed(g, n_shards=1, steps_cache=cache)
+    b = Session(cache=cache).run(spec, g)
     assert len(cache) == n_entries        # warm: no new artifacts
     _same_result(a, b)
 
@@ -128,10 +125,11 @@ def test_default_session_backs_the_legacy_entry_points(graphs):
     reset_default_session()
     try:
         g = graphs["hollywood-2009_s"]
-        color(g, mode="hybrid", outline=False)
+        spec = ExecutionSpec(mode="hybrid")
+        default_session().run(spec, g)
         stats = default_session().stats
-        assert stats.misses >= 1
-        color(g, mode="hybrid", outline=False)
+        assert stats.misses >= 1 and stats.hits == 0
+        default_session().run(spec, g)
         assert stats.hits >= 1
     finally:
         reset_default_session()
@@ -182,14 +180,15 @@ def test_session_pin_marks_hits_and_nests():
 
 
 def test_dist_cache_keys_by_content_like_legacy_steps_cache():
-    # legacy contract: a caller that REBUILDS an equal graph per request
-    # still reuses the partitioned graph + jitted shard_map steps
+    # a caller that REBUILDS an equal graph per request still reuses the
+    # partitioned graph + jitted shard_map steps
     a = make_graph("europe_osm_s", scale=0.01)
     b = dataclasses.replace(a)            # equal content, distinct object
     cache: dict = {}
-    r_a = color_distributed(a, n_shards=1, steps_cache=cache)
+    spec = ExecutionSpec(regime="dist", n_shards=1)
+    r_a = Session(cache=cache).run(spec, a)
     n_entries = len(cache)
-    r_b = color_distributed(b, n_shards=1, steps_cache=cache)
+    r_b = Session(cache=cache).run(spec, b)
     assert len(cache) == n_entries        # content key -> warm hit
     _same_result(r_a, r_b)
 
@@ -404,10 +403,11 @@ def test_tile_rows_pallas_bit_identical_to_jnp(graphs, regime):
     """The tile height is a pure performance knob: every (impl, tile_rows)
     combination inside the fused family produces the SAME coloring."""
     g = graphs["hollywood-2009_s"]        # hub-heavy: hub variant on
-    kw = dict(fused=True, outline=(regime == "outlined"))
-    base = color(g, impl="jnp", **kw)
+    kw = dict(regime=regime, fused=True)
+    base = default_session().run(ExecutionSpec(impl="jnp", **kw), g)
     for tr in (8, 128, "auto"):
-        got = color(g, impl="pallas", tile_rows=tr, **kw)
+        got = default_session().run(
+            ExecutionSpec(impl="pallas", tile_rows=tr, **kw), g)
         np.testing.assert_array_equal(base.colors, got.colors)
         assert got.iterations == base.iterations
         assert got.mode_trace == base.mode_trace

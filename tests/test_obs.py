@@ -27,10 +27,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import color, ipgc
+from repro.core import ipgc
 from repro.core.policy import measure_launches
 from repro.core.worklist import bucket_capacities, full_worklist, pick_bucket
-from repro.exec import ExecutionSpec, Session
+from repro.exec import ExecutionSpec, Session, default_session
 from repro.graphs import make_graph
 from repro.obs import (CounterGroup, Histogram, MetricsRegistry, RunReport,
                        Trace, current_trace, maybe_span, tracing)
@@ -253,7 +253,7 @@ def test_dist_report_exchange_accounting(g):
     from repro.core.distributed import make_dist_dense_step
     from repro.graphs.partition import prepare_partition
     s = Session()
-    spec = ExecutionSpec(regime="dist", mode="dist-hybrid", window=32,
+    spec = ExecutionSpec(regime="dist", mode="hybrid", window=32,
                          n_shards=1)
     rep = s.run(spec, g, trace=True)
     # fused dist steps (the driver default): ONE exchange per iteration
@@ -287,7 +287,7 @@ def test_dist_report_boundary_exchange_accounting(g):
     owned-block swap when 'd' (obs/report.py formulas)."""
     from repro.obs.report import dense_swap_bytes, packed_exchange_bytes
     s = Session()
-    spec = ExecutionSpec(regime="dist", mode="dist-hybrid", window=32,
+    spec = ExecutionSpec(regime="dist", mode="hybrid", window=32,
                          n_shards=1, exchange="auto")
     rep = s.run(spec, g, trace=True)
     n = rep.exchanges["payload_bytes"]["dense_swap"] // 4
@@ -307,17 +307,18 @@ def test_dist_report_boundary_exchange_accounting(g):
     assert rep.exchanges["total_bytes"] == \
         sum(rep.exchanges["bytes_per_iter"])
     # same run, same colors as the dense-exchange report
-    rep0 = s.run(ExecutionSpec(regime="dist", mode="dist-hybrid",
+    rep0 = s.run(ExecutionSpec(regime="dist", mode="hybrid",
                                window=32, n_shards=1), g)
     np.testing.assert_array_equal(rep.colors, rep0.colors)
 
 
 def test_outlined_report_and_engine_entry_point(g):
-    rep = color(g, window=64, outline=True, trace=True)
+    outlined = ExecutionSpec(regime="outlined", window=64)
+    rep = default_session().run(outlined, g, trace=True)
     assert rep.regime == "outlined"
     assert rep.host_dispatches == rep.timing["dispatches"]
     assert len(rep.trace.find("session.chunk")) == rep.host_dispatches
-    plain = color(g, window=64, outline=True)
+    plain = default_session().run(outlined, g)
     np.testing.assert_array_equal(rep.colors, plain.colors)
     assert rep.mode_trace == plain.mode_trace
 

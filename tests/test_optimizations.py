@@ -88,9 +88,10 @@ def test_flash_remat_reduces_residual_memory():
 
 
 def test_adaptive_window_preserves_validity():
-    from repro.core import color, verify_coloring
+    from repro.core import verify_coloring
+    from repro.exec import ExecutionSpec, default_session
     from repro.graphs import make_graph
     for name in ("europe_osm_s", "kron_g500-logn21_s"):
         g = make_graph(name, scale=0.02)
-        r = color(g, mode="hybrid", window="auto")
+        r = default_session().run(ExecutionSpec(window="auto"), g)
         verify_coloring(g, r.colors, context=name)

@@ -7,8 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import color, color_outlined_hybrid, ipgc, verify_coloring
+from repro.core import ipgc, verify_coloring
 from repro.core.worklist import bucket_capacities, full_worklist
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import build_graph, make_graph, validate_coloring
 
 # power-law (kron), regular mesh (europe_osm), hub-heavy (hollywood)
@@ -32,75 +33,49 @@ def _assert_equivalent(g, r_host, r_out):
 @pytest.mark.parametrize("name", GRAPHS)
 def test_outlined_matches_host_loop_jnp(graphs, name, fused):
     g = graphs[name]
-    r_host = color(g, mode="hybrid", fused=fused, outline=False)
-    r_out = color_outlined_hybrid(g, fused=fused)
+    r_host = default_session().run(ExecutionSpec(fused=fused), g)
+    r_out = default_session().run(
+        ExecutionSpec(regime="outlined", fused=fused), g)
     _assert_equivalent(g, r_host, r_out)
 
 
 @pytest.mark.parametrize("fused", [False, True])
 def test_outlined_matches_host_loop_pallas(graphs, fused):
     g = graphs["kron_g500-logn21_s"]
-    r_host = color(g, mode="hybrid", impl="pallas", fused=fused,
-                   outline=False)
-    r_out = color_outlined_hybrid(g, impl="pallas", fused=fused)
+    r_host = default_session().run(
+        ExecutionSpec(impl="pallas", fused=fused), g)
+    r_out = default_session().run(
+        ExecutionSpec(regime="outlined", impl="pallas", fused=fused), g)
     _assert_equivalent(g, r_host, r_out)
 
 
 def test_outlined_pallas_matches_jnp(graphs):
     g = graphs["europe_osm_s"]
-    r_j = color_outlined_hybrid(g, impl="jnp")
-    r_p = color_outlined_hybrid(g, impl="pallas")
+    r_j = default_session().run(
+        ExecutionSpec(regime="outlined", impl="jnp"), g)
+    r_p = default_session().run(
+        ExecutionSpec(regime="outlined", impl="pallas"), g)
     np.testing.assert_array_equal(r_j.colors, r_p.colors)
     assert r_j.iterations == r_p.iterations
 
 
 def test_outlined_edge_cases():
     # 1-node graph (the only edge is a removed self loop)
+    outlined = ExecutionSpec(regime="outlined")
     one = build_graph(np.array([0]), np.array([0]), 1, name="one")
-    r = color_outlined_hybrid(one)
+    r = default_session().run(outlined, one)
     assert validate_coloring(one, r.colors) == {
         "conflicts": 0, "uncolored": 0, "n_colors": 1}
     # graph whose edge list is empty after preprocessing
     empty = build_graph(np.array([3]), np.array([3]), 8, name="empty")
-    r = color_outlined_hybrid(empty)
+    r = default_session().run(outlined, empty)
     v = validate_coloring(empty, r.colors)
     assert v["conflicts"] == 0 and v["uncolored"] == 0 and v["n_colors"] == 1
     # the host loop agrees on the degenerate graphs too
     for g in (one, empty):
         np.testing.assert_array_equal(
-            color_outlined_hybrid(g).colors,
-            color(g, mode="hybrid", fused=True, outline=False).colors)
-
-
-def test_set_outline_default_toggles_after_import(graphs):
-    """The env flag is read once at import; programmatic toggling goes
-    through the ``outlined`` context manager (scoped form of the cached
-    setter, mirrors ipgc.forced_hub) and takes effect immediately on
-    ``color(outline=None)`` — with no leak past the block."""
-    from repro.core import outlined
-    from repro.core.engine import outline_default
-    g = graphs["europe_osm_s"]
-    baseline = outline_default()
-    with outlined(True):
-        assert outline_default() is True
-        r_on = color(g, mode="hybrid")          # outline=None -> outlined
-        assert r_on.host_dispatches < r_on.iterations
-        with outlined(False):                   # nests and restores
-            assert outline_default() is False
-            r_off = color(g, mode="hybrid")     # outline=None -> host loop
-            assert r_off.host_dispatches == r_off.iterations
-        assert outline_default() is True
-    np.testing.assert_array_equal(r_on.colors, r_off.colors)
-    assert outline_default() is baseline        # nothing leaked
-
-
-def test_outline_flag_on_color(graphs):
-    g = graphs["kron_g500-logn21_s"]
-    r_flag = color(g, mode="hybrid", outline=True)
-    r_direct = color_outlined_hybrid(g, fused=False)
-    # color(outline=True) forwards its fused default (False)
-    np.testing.assert_array_equal(r_flag.colors, r_direct.colors)
-    assert r_flag.host_dispatches == r_direct.host_dispatches
+            default_session().run(outlined, g).colors,
+            default_session().run(ExecutionSpec(fused=True), g).colors)
 
 
 @pytest.mark.parametrize("ratio", [2, 4])
@@ -108,17 +83,19 @@ def test_outlined_dispatch_bound(graphs, ratio):
     """Acceptance: at most len(bucket_capacities(n)) + O(1) host dispatches
     per coloring, vs one dispatch per iteration for the host loop."""
     g = graphs["kron_g500-logn21_s"]
-    r = color_outlined_hybrid(g, bucket_ratio=ratio)
+    r = default_session().run(
+        ExecutionSpec(regime="outlined", bucket_ratio=ratio), g)
     caps = bucket_capacities(g.n_nodes, ratio=ratio)
     assert r.host_dispatches <= len(caps) + 1
-    r_host = color(g, mode="hybrid", fused=True, outline=False)
+    r_host = default_session().run(ExecutionSpec(fused=True), g)
     assert r_host.host_dispatches == r_host.iterations
     assert r.host_dispatches < r_host.host_dispatches
 
 
 def test_outlined_hybrid_auto_policy(graphs):
     g = graphs["europe_osm_s"]
-    r = color_outlined_hybrid(g, mode="hybrid-auto")
+    r = default_session().run(
+        ExecutionSpec(regime="outlined", mode="hybrid-auto"), g)
     verify_coloring(g, r.colors)
 
 
@@ -152,8 +129,8 @@ def test_fused_host_loop_valid_and_comparable_quality(graphs):
     """Fused (deferred-resolve) semantics stay valid and do not blow up the
     chromatic quality vs the two-phase steps."""
     for name, g in graphs.items():
-        r2 = color(g, mode="hybrid", fused=False, outline=False)
-        rf = color(g, mode="hybrid", fused=True, outline=False)
+        r2 = default_session().run(ExecutionSpec(fused=False), g)
+        rf = default_session().run(ExecutionSpec(fused=True), g)
         verify_coloring(g, rf.colors, context=name)
         assert rf.n_colors <= 2 * r2.n_colors + 2, (name, rf.n_colors,
                                                     r2.n_colors)

@@ -14,8 +14,9 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import color, color_outlined_hybrid, verify_coloring
+from repro.core import verify_coloring
 from repro.core.verify import coloring_stats
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import (LAYOUT_KINDS, LayoutPlan, REORDERINGS, build_graph,
                           get_dataset, make_graph, plan_layout)
 from repro.graphs import ingest, transform
@@ -151,7 +152,7 @@ def test_reordered_colors_map_back_to_original_ids(how):
     g_re = get_dataset("kron_g500-logn21_s", scale=0.02, reorder=how,
                        layout="ell-tail", ell_cap=128)
     assert not g_re.perm.is_identity
-    r = color(g_re, mode="hybrid", outline=False)
+    r = default_session().run(ExecutionSpec(), g_re)
     verify_coloring(g_re, r.colors, context=f"{how}/internal")
     back = g_re.perm.colors_to_original(r.colors)
     verify_coloring(g_orig, back, context=f"{how}/original-ids")
@@ -161,12 +162,12 @@ def test_reordered_colors_map_back_outlined_and_dist():
     g_orig = make_graph("europe_osm_s", scale=0.02)
     g_re = get_dataset("europe_osm_s", scale=0.02, reorder="shuffle",
                        layout="ell-tail", ell_cap=128)
-    r_out = color_outlined_hybrid(g_re)
+    r_out = default_session().run(ExecutionSpec(regime="outlined"), g_re)
     verify_coloring(g_orig, g_re.perm.colors_to_original(r_out.colors),
                     context="shuffle/outlined")
-    from repro.core.distributed import color_distributed
-    r_dist = color_distributed(g_re,
-                               n_shards=min(2, jax.device_count()))
+    r_dist = default_session().run(
+        ExecutionSpec(regime="dist", n_shards=min(2, jax.device_count())),
+        g_re)
     verify_coloring(g_orig, g_re.perm.colors_to_original(r_dist.colors),
                     context="shuffle/dist")
 
@@ -269,7 +270,7 @@ GRAPH = "kron_g500-logn21_s"
 @pytest.fixture(scope="module")
 def kron_ref():
     g = make_graph(GRAPH, scale=0.02)
-    return g, color(g, mode="hybrid", outline=False)
+    return g, default_session().run(ExecutionSpec(), g)
 
 
 @pytest.mark.parametrize("kind", LAYOUT_KINDS)
@@ -283,7 +284,7 @@ def test_layout_execution_variants_agree_bit_exactly(kron_ref, kind):
         g = get_dataset(GRAPH, scale=0.02, layout=kind)
     else:
         g = get_dataset(GRAPH, scale=0.02, layout=kind, ell_cap=32)
-    r = color(g, mode="hybrid", outline=False)
+    r = default_session().run(ExecutionSpec(), g)
     np.testing.assert_array_equal(r.colors, r_ref.colors)
     assert r.iterations == r_ref.iterations
     assert r.mode_trace == r_ref.mode_trace
@@ -292,28 +293,29 @@ def test_layout_execution_variants_agree_bit_exactly(kron_ref, kind):
 @pytest.mark.parametrize("fused", [False, True])
 def test_csr_segment_outlined_matches_host(fused):
     g = get_dataset(GRAPH, scale=0.02, layout="csr-segment")
-    r_host = color(g, mode="hybrid", fused=fused, outline=False)
-    r_out = color_outlined_hybrid(g, fused=fused)
+    r_host = default_session().run(ExecutionSpec(fused=fused), g)
+    r_out = default_session().run(
+        ExecutionSpec(regime="outlined", fused=fused), g)
     np.testing.assert_array_equal(r_out.colors, r_host.colors)
     assert r_out.mode_trace == r_host.mode_trace
     assert r_out.host_dispatches < r_host.host_dispatches
 
 
 def test_engine_layout_override_redispatches_execution(kron_ref):
-    """``color(layout=...)`` flips the execution variant on the same
-    arrays (the plan rides the prepared graph's static fields)."""
+    """``ExecutionSpec(layout=...)`` flips the execution variant on the
+    same arrays (the plan rides the prepared graph's static fields)."""
     g, r_ref = kron_ref
     from repro.core import ipgc
-    from repro.core.engine import resolve_plan
+    from repro.graphs.layout import resolve_plan
     plan = resolve_plan(g, "csr-segment")
     assert plan.kind == "csr-segment"
     assert plan.ell_width == g.layout.ell_width
     ig = ipgc.prepare(g, plan=plan)
     assert ig.layout_kind == "csr-segment" and ig.edge_src is not None
-    r = color(g, mode="hybrid", outline=False, layout="csr-segment")
+    r = default_session().run(ExecutionSpec(layout="csr-segment"), g)
     np.testing.assert_array_equal(r.colors, r_ref.colors)
     with pytest.raises(ValueError, match="unknown layout"):
-        color(g, mode="hybrid", outline=False, layout="typo")
+        default_session().run(ExecutionSpec(layout="typo"), g)
 
 
 def test_csr_segment_gather_contract():
@@ -340,23 +342,23 @@ def test_csr_segment_gather_contract():
 
 
 def test_dist_rejects_csr_segment_with_clear_message():
-    from repro.core.distributed import color_distributed
     g = get_dataset("europe_osm_s", scale=0.01, layout="csr-segment")
     with pytest.raises(NotImplementedError, match="ell-tail"):
-        color_distributed(g, n_shards=1)
+        default_session().run(ExecutionSpec(regime="dist", n_shards=1), g)
     # the documented escape hatch: ELL-family execution of the same graph
-    r = color_distributed(g, n_shards=1, layout="ell-tail")
+    r = default_session().run(
+        ExecutionSpec(regime="dist", n_shards=1, layout="ell-tail"), g)
     verify_coloring(g, r.colors, context="dist/ell-override")
 
 
 @pytest.mark.parametrize("kind", ["pure-ell", "hub-split"])
 def test_dist_matches_host_on_ell_family_layouts(kind):
-    from repro.core.distributed import color_distributed
     g = get_dataset("hollywood-2009_s", scale=0.02, layout=kind)
     shards = min(2, jax.device_count())
-    r_dist = color_distributed(g, n_shards=shards)
+    r_dist = default_session().run(
+        ExecutionSpec(regime="dist", n_shards=shards), g)
     verify_coloring(g, r_dist.colors, context=f"dist/{kind}")
-    r_host = color(g, mode="hybrid", fused=True, outline=False)
+    r_host = default_session().run(ExecutionSpec(fused=True), g)
     assert r_dist.n_colors == r_host.n_colors
 
 
@@ -367,7 +369,7 @@ def test_jpl_runs_under_every_layout():
     ref = None
     for kind in LAYOUT_KINDS:
         g = get_dataset("europe_osm_s", scale=0.02, layout=kind)
-        r = color(g, algo="jpl", mode="hybrid", outline=False)
+        r = default_session().run(ExecutionSpec(algo="jpl"), g)
         verify_coloring(g, r.colors, context=f"jpl/{kind}")
         if ref is None:
             ref = r.colors
@@ -408,7 +410,7 @@ def test_register_ad_hoc_dataset():
     register_dataset("two-cliques", two_cliques)
     g = get_dataset("two-cliques", scale=1.0)
     assert g.n_nodes == 16
-    r = color(g, mode="hybrid", outline=False)
+    r = default_session().run(ExecutionSpec(), g)
     assert r.n_colors == 8             # each K8 clique needs 8 colors
 
 

@@ -1,10 +1,10 @@
 """Policy tuning paths: ``AutoTuned.observe_chunk`` mixed-mode updates and
-``engine.adaptive_window`` clamping at degenerate degree histograms."""
+``policy.adaptive_window`` clamping at degenerate degree histograms."""
 import numpy as np
 import pytest
 
-from repro.core.engine import adaptive_window
-from repro.core.policy import AutoTuned, device_threshold, make_policy
+from repro.core.policy import (AutoTuned, adaptive_window, device_threshold,
+                               make_policy)
 from repro.graphs import build_graph
 
 
@@ -120,7 +120,10 @@ def test_adaptive_window_tracks_typical_degree_between_clamps():
 def test_make_policy_modes_still_resolve():
     # guard: the tuning tests above rely on these spellings
     assert isinstance(make_policy("hybrid-auto"), AutoTuned)
-    assert make_policy("dist-hybrid")(900, 1000) is True
+    assert make_policy("hybrid")(900, 1000) is True
+    # the sharded Pipe is ExecutionSpec.regime's business, not a mode
+    with pytest.raises(ValueError, match="unknown mode"):
+        make_policy("dist-hybrid")
 
 
 # ---------------------------------------------------------------------------

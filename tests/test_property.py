@@ -8,8 +8,9 @@ import pytest
 
 from _hyp import given, settings, st
 
-from repro.core import color, jpl_color
+from repro.core import jpl_color
 from repro.core.worklist import bucket_capacities, pick_bucket
+from repro.exec import ExecutionSpec, default_session
 from repro.graphs import build_graph, validate_coloring
 from repro.graphs.partition import (balance_permutation, prepare_partition,
                                     repartition, shard_bounds)
@@ -27,7 +28,8 @@ def test_coloring_always_valid_on_random_graphs(n, e, data):
     dst = rng.integers(0, n, size=max(e, 1))
     g = build_graph(src, dst, n, name="h", ell_cap=32)
     mode = data.draw(st.sampled_from(["hybrid", "data", "topology"]))
-    r = color(g, mode=mode, window=data.draw(st.sampled_from([32, "auto"])))
+    window = data.draw(st.sampled_from([32, "auto"]))
+    r = default_session().run(ExecutionSpec(mode=mode, window=window), g)
     v = validate_coloring(g, r.colors)
     assert v["conflicts"] == 0
     assert v["uncolored"] == 0
@@ -95,7 +97,7 @@ def test_repartition_relabel_preserves_coloring_validity(n, n_shards, data):
     src = rng.integers(0, n, size=3 * n)
     dst = rng.integers(0, n, size=3 * n)
     g = build_graph(src, dst, n, name="h", ell_cap=16)
-    r = color(g, mode="hybrid", window=32)
+    r = default_session().run(ExecutionSpec(mode="hybrid", window=32), g)
     assert validate_coloring(g, r.colors)["conflicts"] == 0
     g2, new_of_old = repartition(g, n_shards,
                                  balance=data.draw(st.booleans()))
@@ -105,7 +107,7 @@ def test_repartition_relabel_preserves_coloring_validity(n, n_shards, data):
     assert v2["conflicts"] == 0 and v2["uncolored"] == 0
     assert v2["n_colors"] == r.n_colors
     # and back: coloring the relabeled graph maps to a valid original one
-    r2 = color(g2, mode="hybrid", window=32)
+    r2 = default_session().run(ExecutionSpec(mode="hybrid", window=32), g2)
     v_back = validate_coloring(g, r2.colors[new_of_old])
     assert v_back["conflicts"] == 0 and v_back["uncolored"] == 0
 

@@ -11,10 +11,10 @@ from bench import reference
 from bench.gen import rgg
 from repro.algos import get_algorithm
 from repro.core import ipgc
-from repro.core.engine import resolve_plan
 from repro.core.worklist import bucket_capacities, pick_bucket, resize_items
 from repro.exec import ExecutionSpec, Session
 from repro.graphs import build_graph
+from repro.graphs.layout import resolve_plan
 
 N, FACTOR, SEED = 2048, 1.0, 1       # max degree 33-40: pure-ell width 40
 W = 32

@@ -557,6 +557,46 @@ def test_stream_edf_never_sheds_without_observations(pool):
     assert tk.status == "done" and tk.deadline_met is False
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_stream_edf_meets_every_deadline_fifo_no_more(seed):
+    """One lane on a manual clock (one tick a pump round, one iteration a
+    round). Each deadline is the request's completion round in
+    shortest-first order plus the largest single request's iterations,
+    so earliest-deadline-first (which then runs shortest first) meets
+    every one of them, while FIFO's arrival order can only meet fewer.
+    Every finished ticket still colors exactly like its solo run."""
+    requests = get_dataset_batch(
+        heavy_tail={"count": 8, "names": ("europe_osm_s", "circuit5M_s"),
+                    "min_nodes": 600, "max_nodes": 3000, "alpha": 1.5},
+        seed=seed, layout="ell-tail")
+    spec = ExecutionSpec(regime="host", window=128)
+    solo = [_solo(spec, g) for g in requests]
+    iters = [r.iterations for r in solo]
+    deadlines, acc = {}, 0
+    for i in sorted(range(len(requests)), key=lambda i: (iters[i], i)):
+        acc += iters[i]
+        deadlines[i] = float(acc + max(iters))
+    sess = Session()
+    met = {}
+    for admission in ("fifo", "edf"):
+        clk = ManualClock(start=0.0, tick=0.0)
+        stream = sess.stream(spec, StreamConfig(
+            lanes=1, chunk=1, admission=admission, clock=clk,
+            max_queue=len(requests), max_nodes=4096))
+        tks = [stream.submit(g, deadline_s=deadlines[i])
+               for i, g in enumerate(requests)]
+        while not stream.idle:
+            stream.pump()
+            clk.advance(1.0)
+            assert stream.round < 100 * sum(iters) + 1000, "stream hung"
+        met[admission] = sum(1 for tk in tks if tk.deadline_met)
+        for tk, ref in zip(tks, solo):
+            if tk.status == "done":
+                np.testing.assert_array_equal(tk.result.colors, ref.colors)
+    assert met["edf"] == len(requests), (met, iters)
+    assert met["fifo"] <= met["edf"], (met, iters)
+
+
 def test_admission_policy_order_must_be_permutation(pool):
     class Bad:
         def order(self, queued, clock):

@@ -123,7 +123,7 @@ def test_dist_steps_compile_for_v5e_2x2(topo, monkeypatch, exchange):
 
     from repro.algos import get_algorithm
     from repro.core import distributed
-    from repro.core.engine import resolve_plan
+    from repro.graphs.layout import resolve_plan
     from repro.core.policy import exchange_threshold
     from repro.core.worklist import Worklist, bucket_capacities
     from repro.graphs import make_graph
