@@ -238,7 +238,9 @@ def split_tail(ig: ipgc.IPGCGraph, n_shards: int):
     valid = np.asarray(ig.tail_valid)
     src = np.asarray(ig.tail_src)[valid]          # ascending source rows
     dst = np.asarray(ig.tail_dst)[valid]
-    hubs = np.asarray(ig.hub_ids)[:ig.n_hub]      # ascending hub rows
+    # ascending hub rows (the prepared graph's hub slots past its hubs
+    # are its shape class's padding, held by no row)
+    hubs = np.nonzero(np.asarray(ig.hub_slot) < ig.n_hub)[0].astype(np.int32)
     hub_shard = hubs // blk
     per_shard = np.bincount(hub_shard, minlength=n_shards)
     nh = int(per_shard.max()) if hubs.size else 0

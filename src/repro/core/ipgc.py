@@ -100,7 +100,18 @@ def prepare(g: Graph, *, priority: str = "hash", plan=None) -> IPGCGraph:
     the graph was assembled with; graphs from the legacy builder default
     to ell-tail). Only ``plan.kind`` matters here — the arrays were laid
     out at assembly; prepare picks the execution variant.
+
+    The prepared graph is put in its *shape class* (``_to_class``): every
+    size the graph's draw moves is rounded up a fixed ladder, so graphs
+    of one node count and ELL width share one signature, and with it
+    every compiled step program (DESIGN.md §8). The padding is inert.
     """
+    return jax.tree.map(jnp.asarray,
+                        _to_class(_prepare_exact(g, priority, plan)))
+
+
+def _prepare_exact(g: Graph, priority: str, plan) -> IPGCGraph:
+    """``prepare`` before the shape class, on host (numpy) arrays."""
     a = g.arrays
     n = g.n_nodes
     if plan is None:
@@ -124,37 +135,114 @@ def prepare(g: Graph, *, priority: str = "hash", plan=None) -> IPGCGraph:
                                  np.arange(n_hub + 1)).astype(np.int32)
     if kind == "csr-segment":
         e = int(np.asarray(a.row_ptr)[-1])
-        ep = max(-(-max(e, 1) // 8) * 8, 8)
+        ep = _edge_slots(e)
         es = np.full(ep, max(n - 1, 0), dtype=np.int32)  # pad lanes inert
         ed = np.full(ep, n, dtype=np.int32)              # (ec < 0)
         es[:e] = np.repeat(np.arange(n, dtype=np.int32), deg)
         ed[:e] = np.asarray(a.col_idx)
         ed[kcsr.wins(prio[es], prio[ed], es, ed)] |= np.int32(kcsr.WINS_BIT)
-        edge_src, edge_dst = jnp.asarray(es), jnp.asarray(ed)
+        edge_src, edge_dst = es, ed
         owned = deg
     else:
-        ell_wins = jnp.asarray(_ell_wins(np.asarray(a.ell_idx), prio))
+        ell_wins = _ell_wins(np.asarray(a.ell_idx), prio)
         owned = np.bincount(real, minlength=n)
     return IPGCGraph(
         n_nodes=n,
         ell_width=a.ell_width,
         n_hub=n_hub,
-        ell_idx=jnp.asarray(a.ell_idx),
-        degrees=jnp.asarray(deg),
-        priority=jnp.asarray(prio),
-        tail_src=jnp.asarray(tail_src_safe),
-        tail_dst=jnp.asarray(a.tail_dst),
-        tail_valid=jnp.asarray(tail_valid),
-        tail_slot=jnp.asarray(hub_slot[tail_src_safe]),
-        hub_slot=jnp.asarray(hub_slot),
-        hub_ids=jnp.asarray(hub_ids if n_hub else np.zeros(1, np.int32)),
-        tail_start=jnp.asarray(tail_start),
+        ell_idx=np.asarray(a.ell_idx),
+        degrees=deg,
+        priority=prio,
+        tail_src=tail_src_safe,
+        tail_dst=np.asarray(a.tail_dst),
+        tail_valid=tail_valid,
+        tail_slot=hub_slot[tail_src_safe],
+        hub_slot=hub_slot,
+        hub_ids=hub_ids if n_hub else np.zeros(1, np.int32),
+        tail_start=tail_start,
         layout_kind=kind,
         edge_src=edge_src,
         edge_dst=edge_dst,
         ell_wins=ell_wins,
         seg_bound=_top_sums(owned),
     )
+
+
+def _edge_slots(e: int) -> int:
+    """Length of the csr-segment edge arrays of ``e`` edges before the
+    shape class: a multiple of 8, at least 8."""
+    return max(-(-max(e, 1) // 8) * 8, 8)
+
+
+def class_size(x: int) -> int:
+    """The shape class of a seed-dependent size: the smallest
+    ``{32, ..., 63} * 2^j`` >= ``x`` (``x`` itself up to 64), at most
+    1/32 above it; a multiple of 8 stays one."""
+    return _ladder(x, bits=6)
+
+
+def _to_class(ig: IPGCGraph) -> IPGCGraph:
+    """A host-side prepared graph in its shape class: the tail and the hub
+    slots padded to ``class_size`` by ``_embed``'s inert rules, the
+    csr-segment edge arrays likewise with inert lanes (source ``N-1``,
+    destination the sentinel ``N``), and ``seg_bound`` keyed on what the
+    steps read of it (``_bound_read``). The node count and the ELL width
+    stay as they are."""
+    n, m = ig.n_nodes, ig.tail_src.shape[0]
+    ig = _embed(ig, n, ig.ell_width, class_size(m), class_size(ig.n_hub))
+    csr = ig.layout_kind == "csr-segment"
+    ig = dataclasses.replace(ig, seg_bound=tuple(
+        _bound_read(b, k, csr) for k, b in enumerate(ig.seg_bound)))
+    if not csr:
+        return ig
+    pad = class_size(ig.edge_dst.shape[0]) - ig.edge_dst.shape[0]
+    return dataclasses.replace(
+        ig, edge_src=np.pad(ig.edge_src, (0, pad),
+                            constant_values=max(n - 1, 0)),
+        edge_dst=np.pad(ig.edge_dst, (0, pad), constant_values=n))
+
+
+def _bound_read(bound: int, k: int, chunked: bool) -> int:
+    """``seg_bound[k]`` rounded up as far as the steps' use of it allows,
+    so every step reads what it read before. Rung 0 (``_max_run``) only
+    sets the doubling passes of ``kcsr.segment_or``: its power-of-two
+    ceiling. The other rungs are read through ``packed_cap``, whose
+    1024-slot rounding they take; a csr-segment pass (``chunked``) reads
+    whole chunks above ``PACKED_CHUNK`` (``packed_row_words``'s
+    ``keep``), so there the rung takes whole chunks."""
+    if k == 0:
+        return 1 << max(bound - 1, 0).bit_length() if bound else 0
+    cap = max(-(-bound // 1024) * 1024, 1024)
+    if chunked and cap > PACKED_CHUNK:
+        return -(-cap // PACKED_CHUNK) * PACKED_CHUNK
+    return cap
+
+
+def class_sizes(ig: IPGCGraph) -> dict:
+    """The seed-dependent sizes of a prepared graph (on its shape class
+    once ``prepare`` made it): csr-segment edge slots (0 on the ELL
+    kinds), tail slots and hub slots."""
+    return {"edges": 0 if ig.edge_dst is None else ig.edge_dst.shape[0],
+            "tail": ig.tail_dst.shape[0], "hubs": ig.n_hub}
+
+
+def exact_sizes(g: Graph, kind: str) -> dict:
+    """``class_sizes`` of ``g`` prepared under layout ``kind`` before the
+    shape class."""
+    a = g.arrays
+    return {"edges": (_edge_slots(int(np.asarray(a.row_ptr)[-1]))
+                      if kind == "csr-segment" else 0),
+            "tail": np.asarray(a.tail_src).shape[0],
+            "hubs": int(np.count_nonzero(np.asarray(a.degrees)
+                                         > a.ell_width))}
+
+
+def signature(ig: IPGCGraph) -> tuple:
+    """What keys every step program on a prepared graph: its static
+    fields and the shapes and dtypes of its arrays. Graphs with one
+    signature share their compiled steps."""
+    leaves, tree = jax.tree_util.tree_flatten(ig)
+    return tree, tuple((x.shape, x.dtype) for x in leaves)
 
 
 def wins_words(k: int) -> int:
@@ -198,12 +286,13 @@ def _top_sums(owned: np.ndarray) -> tuple:
     return tuple(_ladder(int(top[min(2 ** k, top.size) - 1])) for k in ks)
 
 
-def _ladder(x: int) -> int:
-    """Smallest ``{8, ..., 15} * 2^j`` (x itself up to 16) >= ``x``: the
-    same power-of-two ceiling, so the same number of doubling passes."""
-    if x <= 16:
+def _ladder(x: int, bits: int = 4) -> int:
+    """Smallest ``{2^(bits-1), ..., 2^bits - 1} * 2^j`` (x itself up to
+    ``2^bits``) >= ``x``: the same power-of-two ceiling, so the same
+    number of doubling passes. ``bits=4`` gives ``{8, ..., 15} * 2^j``."""
+    if x <= 2 ** bits:
         return x
-    j = x.bit_length() - 4
+    j = x.bit_length() - bits
     return -(-x // 2 ** j) * 2 ** j
 
 
@@ -237,37 +326,54 @@ def pad_prepared(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
     coloring the original — the invariant ``Session.run_batch`` is built
     on (tests/test_exec.py).
     """
-    n, k, nh = ig.n_nodes, ig.ell_width, ig.n_hub
-    t = ig.tail_src.shape[0]
     assert ig.layout_kind != "csr-segment", \
         "csr-segment graphs have no batch padding (edge arrays)"
+    # lanes stack only where their static fields agree: no packed bounds
+    return dataclasses.replace(_embed(ig, n_pad, k_pad, t_pad, nh_pad),
+                               seg_bound=())
+
+
+def _embed(ig: IPGCGraph, n_pad: int, k_pad: int, t_pad: int,
+           nh_pad: int) -> IPGCGraph:
+    """``ig`` in a larger static shape by ``pad_prepared``'s inert rules,
+    on host (numpy) or device arrays alike; an array whose shape stays
+    is kept as it is. Static fields other than the sizes carry over."""
+    xp = np if isinstance(ig.tail_src, np.ndarray) else jnp
+    n, k, nh = ig.n_nodes, ig.ell_width, ig.n_hub
+    t = ig.tail_src.shape[0]
     assert n_pad >= n and k_pad >= k and t_pad >= t and nh_pad >= nh
-    ell = jnp.where(ig.ell_idx == n, n_pad, ig.ell_idx)
-    ell = jnp.pad(ell, ((0, n_pad - n), (0, k_pad - k)),
-                  constant_values=n_pad)
-    deg = jnp.pad(ig.degrees, (0, n_pad - n))
-    prio = jnp.concatenate([ig.priority[:n],
-                            jnp.full((n_pad + 1 - n,), -1, jnp.int32)])
-    wins = jnp.pad(ig.ell_wins, ((0, n_pad - n),
-                                 (0, wins_words(k_pad) - wins_words(k))))
-    tail_src = jnp.pad(ig.tail_src, (0, t_pad - t))        # clipped rows
-    tail_dst = jnp.pad(jnp.where(ig.tail_dst == n, n_pad, ig.tail_dst),
-                       (0, t_pad - t), constant_values=n_pad)
-    tail_valid = jnp.pad(ig.tail_valid, (0, t_pad - t))
-    tail_slot = jnp.pad(jnp.where(ig.tail_slot == nh, nh_pad, ig.tail_slot),
-                        (0, t_pad - t), constant_values=nh_pad)
-    hub_slot = jnp.pad(jnp.where(ig.hub_slot == nh, nh_pad, ig.hub_slot),
-                       (0, n_pad - n), constant_values=nh_pad)
-    hub_ids = jnp.pad(ig.hub_ids,
-                      (0, max(nh_pad, 1) - ig.hub_ids.shape[0]))
+    assert ig.layout_kind != "csr-segment" or (n_pad, k_pad) == (n, k), \
+        "csr-segment edge arrays point at the sentinel N"
+
+    def remap(x, old, new):
+        return x if old == new else xp.where(x == old, new, x)
+
+    def grow(x, width, fill=0):
+        return x if width == 0 else xp.pad(x, (0, width),
+                                           constant_values=fill)
+
+    ell, wins, deg, prio = ig.ell_idx, ig.ell_wins, ig.degrees, ig.priority
+    if (n_pad, k_pad) != (n, k):
+        ell = xp.pad(remap(ell, n, n_pad), ((0, n_pad - n), (0, k_pad - k)),
+                     constant_values=n_pad)
+        wins = xp.pad(wins, ((0, n_pad - n),
+                             (0, wins_words(k_pad) - wins_words(k))))
+        deg = grow(deg, n_pad - n)
+        prio = xp.concatenate([prio[:n],
+                               xp.full((n_pad + 1 - n,), -1, xp.int32)])
     # extra hub slots own empty ranges at the end of the real entries
-    tail_start = jnp.pad(ig.tail_start, (0, nh_pad - nh), mode="edge")
-    return IPGCGraph(
-        n_nodes=n_pad, ell_width=k_pad, n_hub=nh_pad, ell_idx=ell,
-        degrees=deg, priority=prio, tail_src=tail_src, tail_dst=tail_dst,
-        tail_valid=tail_valid, tail_slot=tail_slot, hub_slot=hub_slot,
-        hub_ids=hub_ids, tail_start=tail_start, layout_kind=ig.layout_kind,
-        ell_wins=wins)
+    tail_start = (ig.tail_start if nh_pad == nh else
+                  xp.pad(ig.tail_start, (0, nh_pad - nh), mode="edge"))
+    return dataclasses.replace(
+        ig, n_nodes=n_pad, ell_width=k_pad, n_hub=nh_pad, ell_idx=ell,
+        degrees=deg, priority=prio, ell_wins=wins,
+        tail_src=grow(ig.tail_src, t_pad - t),             # clipped rows
+        tail_dst=grow(remap(ig.tail_dst, n, n_pad), t_pad - t, n_pad),
+        tail_valid=grow(ig.tail_valid, t_pad - t),
+        tail_slot=grow(remap(ig.tail_slot, nh, nh_pad), t_pad - t, nh_pad),
+        hub_slot=grow(remap(ig.hub_slot, nh, nh_pad), n_pad - n, nh_pad),
+        hub_ids=grow(ig.hub_ids, max(nh_pad, 1) - ig.hub_ids.shape[0]),
+        tail_start=tail_start)
 
 
 def _has_hubs(ig: IPGCGraph, force_hub: bool | None) -> bool:

@@ -15,7 +15,9 @@ compile cache for every regime (DESIGN.md §9):
     ``lax.while_loop`` until every lane drains — the serving-scale
     workload the unified cache exists for.
   * ``Session.stats`` counts cache hits/misses so warm-vs-cold behaviour
-    is observable.
+    is observable, and the distinct shape classes of the graphs it
+    prepared (``ipgc.signature``): graphs of one class share every
+    compiled step.
 
 Cache-key discipline: an entry is keyed on the spec's static fields plus
 the graph's identity (``id(g)`` + static shape fields — the entry pins
@@ -57,11 +59,18 @@ from repro.obs.report import (RunReport, dense_exchange_bytes,
 
 @dataclasses.dataclass
 class CacheStats:
-    """Hit/miss counters for the session's unified compile cache."""
+    """Hit/miss counters for the session's unified compile cache, and the
+    distinct shape classes of the graphs it prepared."""
 
     hits: int = 0
     misses: int = 0
     evictions: int = 0
+    #: ``ipgc.signature`` of every graph the session prepared
+    classes: set = dataclasses.field(default_factory=set)
+
+    @property
+    def shape_classes(self) -> int:
+        return len(self.classes)
 
     @property
     def hit_rate(self) -> float:
@@ -71,12 +80,14 @@ class CacheStats:
     def as_dict(self) -> dict:
         return {"hits": self.hits, "misses": self.misses,
                 "evictions": self.evictions,
-                "hit_rate": round(self.hit_rate, 4)}
+                "hit_rate": round(self.hit_rate, 4),
+                "shape_classes": self.shape_classes}
 
     def reset(self) -> None:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        self.classes = set()
 
 
 @dataclasses.dataclass
@@ -110,6 +121,19 @@ def _graph_key(g) -> tuple:
     if isinstance(g, Graph):
         return ("graph", id(g), g.name, g.n_nodes, g.n_edges)
     return ("ig", id(g), g.n_nodes, g.ell_width, g.n_hub, g.layout_kind)
+
+
+def _class_attrs(g, ig) -> dict:
+    """``session.prepare``'s attributes: the seed-dependent sizes of the
+    prepared graph on its shape class, and, where ``g`` is the host Graph
+    it came from, the slots the class added over those it had before
+    (``pad_share``, a fraction)."""
+    sizes = ipgc.class_sizes(ig)
+    attrs = {"shape_class": sizes}
+    if isinstance(g, Graph):
+        exact = ipgc.exact_sizes(g, ig.layout_kind)
+        attrs["pad_share"] = sum(sizes.values()) / sum(exact.values()) - 1
+    return attrs
 
 
 def _csr_checksum(g: Graph) -> int:
@@ -202,6 +226,12 @@ class Session:
                 self._pinned.add(key)
             self.stats.hits += 1
             return entry
+
+    def _note_class(self, ig) -> None:
+        """Count ``ig``'s shape class among those the session prepared."""
+        sig = ipgc.signature(ig)
+        with self._lock:
+            self.stats.classes.add(sig)
 
     # -- public API ----------------------------------------------------------
 
@@ -457,6 +487,7 @@ class Session:
             else:
                 window = spec.window
             ig = alg.prepare(g, priority=spec.priority, plan=plan)
+            self._note_class(ig)
             return g, ig, window
 
         return self.cached(key, build)
@@ -467,8 +498,10 @@ class Session:
                   meter=None) -> ColoringResult:
         alg = spec.resolved_algo()
         fused = alg.resolve_fused(spec.fused, default=False)
-        with obs_trace.maybe_span("session.prepare"):
+        with obs_trace.maybe_span("session.prepare") as sp:
             _, ig, window = self._prepare(spec, g, alg)
+            if sp is not None:
+                sp.attrs.update(_class_attrs(g, ig))
         n = ig.n_nodes
         pol = policy or make_policy(spec.mode, spec.h)
         caps = bucket_capacities(n, ratio=spec.bucket_ratio)
@@ -550,8 +583,10 @@ class Session:
         alg = spec.resolved_algo()
         fused = alg.resolve_fused(spec.fused,
                                   default=jax.default_backend() == "tpu")
-        with obs_trace.maybe_span("session.prepare"):
+        with obs_trace.maybe_span("session.prepare") as sp:
             _, ig, window = self._prepare(spec, g, alg)
+            if sp is not None:
+                sp.attrs.update(_class_attrs(g, ig))
         n = ig.n_nodes
         pol = policy or make_policy(spec.mode, spec.h)
         caps = bucket_capacities(n, ratio=spec.bucket_ratio)
@@ -678,6 +713,7 @@ class Session:
             else:
                 window = spec.window
             ig = alg.prepare(g2, priority=spec.priority, plan=plan)
+            self._note_class(ig)
             binfo = thresh = None
             if spec.exchange != "dense":
                 binfo = boundary_info(g2, n_shards)
@@ -690,9 +726,11 @@ class Session:
             return (g, g2, new_of_old, ig, window, dense_fn, sparse_fn,
                     resize_fn, binfo, thresh)
 
-        with obs_trace.maybe_span("session.prepare"):
+        with obs_trace.maybe_span("session.prepare") as sp:
             (_, g2, new_of_old, ig, window, dense_fn, sparse_fn,
              resize_fn, binfo, thresh) = self.cached(key, build)
+            if sp is not None:
+                sp.attrs.update(_class_attrs(g2, ig))
         n = ig.n_nodes
         if meter is not None:
             meter.statics = dict(kind="dist", alg=alg, ig=ig, mesh=mesh,

@@ -19,7 +19,9 @@ name. With the profiler off an annotation costs about a microsecond.
 Span naming scheme (the contract DESIGN.md §12 documents):
 
   session.run / session.prepare / session.chunk — the engine drivers;
-      ``session.chunk`` carries ``branch``/``count``/``cap`` attrs
+      ``session.chunk`` carries ``branch``/``count``/``cap`` attrs,
+      ``session.prepare`` the prepared graph's ``shape_class`` and
+      ``pad_share`` (exec/session.py)
   session.iter — one iteration of the host loop or the sharded Pipe
       (``mode``/``count`` attrs); in the host loop the parent of
       session.resize (the worklist moves to a smaller capacity bucket),

@@ -117,7 +117,9 @@ def test_warm_session_hits_and_stats(graphs):
     assert s.stats.hits >= 1
     assert 0.0 < s.stats.hit_rate <= 1.0
     d = s.stats.as_dict()
-    assert set(d) == {"hits", "misses", "evictions", "hit_rate"}
+    assert set(d) == {"hits", "misses", "evictions", "hit_rate",
+                      "shape_classes"}
+    assert d["shape_classes"] == 1      # one graph, prepared once
 
 
 def test_default_session_backs_the_legacy_entry_points(graphs):

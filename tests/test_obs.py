@@ -239,7 +239,8 @@ def test_host_report_matches_scattered_sources(g):
     assert rep.gathers["total"]["neighbor_colors"] == 2 * (nd + ns)
     # cache section IS the session's CacheStats snapshot
     assert {k: rep.cache[k] for k in ("hits", "misses", "evictions",
-                                      "hit_rate")} == s.stats.as_dict()
+                                      "hit_rate", "shape_classes")
+            } == s.stats.as_dict()
     # timing split invariants
     t = rep.timing
     assert t["dispatches"] == plain.host_dispatches
@@ -542,7 +543,8 @@ def test_evictions_under_pin_with_tracing(g):
     # the report's cache section snapshots the same CacheStats object
     rep = s.run(spec, graphs[0], trace=True)
     assert {k: rep.cache[k] for k in ("hits", "misses", "evictions",
-                                      "hit_rate")} == s.stats.as_dict()
+                                      "hit_rate", "shape_classes")
+            } == s.stats.as_dict()
     assert rep.cache["run_delta"]["evictions"] >= 0
     for r in reports:
         assert isinstance(r, RunReport) and r.n_colors > 0
